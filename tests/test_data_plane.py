@@ -25,6 +25,7 @@ import numpy as np
 import pytest
 
 import xgboost_tpu as xgb
+from conftest import require_native
 from xgboost_tpu import dispatch
 from xgboost_tpu.data.quantile import (
     BinnedMatrix, _ensure_sketch_ffi, bin_matrix, compute_cuts,
@@ -64,8 +65,7 @@ def _adversarial(n=3000, F=7, seed=0):
 
 @pytest.mark.parametrize("max_bin", [16, 64, 300])
 def test_native_sketch_and_bins_bit_identical_to_xla(monkeypatch, max_bin):
-    if not _ensure_sketch_ffi():
-        pytest.skip("native sketch toolchain unavailable")
+    require_native(_ensure_sketch_ffi(), "native sketch kernel")
     X, w = _adversarial()
     c_nat = compute_cuts(X, max_bin, weights=w)
     b_nat = np.asarray(bin_matrix(X, c_nat))
@@ -110,8 +110,7 @@ def test_trained_model_identical_across_ingest_routes(monkeypatch):
     """End to end: a model trained on natively-ingested data is byte-equal
     to one trained on XLA-ingested data (cuts and bins are bit-identical,
     so everything downstream must be too)."""
-    if not _ensure_sketch_ffi():
-        pytest.skip("native sketch toolchain unavailable")
+    require_native(_ensure_sketch_ffi(), "native sketch kernel")
     X, y = _data()
     b1 = xgb.train(PARAMS, xgb.DMatrix(X, label=y), 3, verbose_eval=False)
     monkeypatch.setenv("XGBTPU_DISPATCH", "sketch_cuts=xla,bin_matrix=xla")

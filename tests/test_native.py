@@ -6,11 +6,17 @@ import os
 import numpy as np
 import pytest
 
+from conftest import require_native
 from xgboost_tpu.native import get_lib, load_csv_native, load_svmlight_native
 
 AGARICUS = "/root/reference/demo/data/agaricus.txt.train"
 
-pytestmark = pytest.mark.skipif(get_lib() is None, reason="native lib unavailable")
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _native_or_fail():
+    require_native(get_lib() is not None, "native parser library")
+
 
 # the reference checkout (and its demo data) is not part of this
 # container image: parity-vs-demo-data tests skip rather than fail

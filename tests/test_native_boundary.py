@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 import xgboost_tpu as xgb
+from conftest import require_native
 from xgboost_tpu import dispatch, native
 from xgboost_tpu.native import boundary, canary
 from xgboost_tpu.observability import REGISTRY
@@ -41,8 +42,7 @@ def test_mid_train_native_fault_degrades_and_completes(monkeypatch):
     XLA fallback route, training completes all rounds — and on
     count-valued gradients the hybrid model equals a pure-fallback run
     EXACTLY."""
-    if native.get_tree_lib() is None:
-        pytest.skip("native tree kernel unavailable")
+    require_native(native.get_tree_lib() is not None, "native tree kernel")
     # pin the whole-tree kernel bit-identical to the per-level path so
     # route equality is byte-exact, not just statistical
     monkeypatch.setenv("XGBTPU_DISPATCH",
@@ -133,11 +133,9 @@ def test_cap_snapshot_is_read_only():
 
 
 def _healthy_hist_so():
-    if native.get_hist_lib() is None:
-        pytest.skip("native hist kernel unavailable")
+    require_native(native.get_hist_lib() is not None, "native hist kernel")
     so = native._lib_variant(native._HB_LIB)
-    if not os.path.exists(so):
-        pytest.skip("hist .so not on disk")
+    assert os.path.exists(so), so
     return so
 
 
@@ -198,8 +196,8 @@ def test_canary_crash_verdict_degrades_and_caches(tmp_path, monkeypatch):
 def test_canary_refuses_missing_symbols(tmp_path, monkeypatch):
     """The NB604 nm -D probe promoted to load time: a library missing a
     registered handler symbol is refused with NO subprocess at all."""
-    if native.get_serving_lib() is None:
-        pytest.skip("native serving kernel unavailable")
+    require_native(native.get_serving_lib() is not None,
+                   "native serving kernel")
     sv = native._lib_variant(native._SV_LIB)
     so = str(tmp_path / "libhistbuild.so")
     shutil.copy(sv, so)  # a real .so, but the wrong one
@@ -232,8 +230,7 @@ def test_guard_mode_catches_oob_feature(monkeypatch):
     the wild bins[i*F+f] read it would otherwise drive."""
     from xgboost_tpu.tree import hist_kernel
 
-    if not hist_kernel._ensure_ffi():
-        pytest.skip("native hist kernel unavailable")
+    require_native(hist_kernel._ensure_ffi(), "native hist kernel")
     import jax
     import jax.numpy as jnp
 
@@ -261,8 +258,7 @@ def test_contract_drift_refused(monkeypatch):
     a typed error BEFORE the handler runs, and the library degrades."""
     from xgboost_tpu.tree import hist_kernel
 
-    if not hist_kernel._ensure_ffi():
-        pytest.skip("native hist kernel unavailable")
+    require_native(hist_kernel._ensure_ffi(), "native hist kernel")
     import jax
     import jax.numpy as jnp
 
@@ -309,3 +305,51 @@ def test_build_failure_degrades_instead_of_raising(monkeypatch):
     assert native.get_hist_lib() is None
     assert _counter("native_build_failures_total", lib="hist_build") > f0
     assert degrade.worst("native_hist") != HEALTHY
+
+
+# ---------------------------------------- builds travel with the checkout
+
+
+def test_build_and_verdict_are_keyed_on_source_flags_and_host(tmp_path):
+    """The checkout is copied between machines with its ignored build
+    products in it: a library is trusted only while its stamp carries
+    this (source, flags, host) key — never by mtime — and a canary
+    verdict only on the host that proved it."""
+    import json
+
+    if not shutil.which("g++"):
+        pytest.skip("no g++")
+    src = str(tmp_path / "t.cpp")
+    so = str(tmp_path / "libt.so")
+    with open(src, "w") as f:
+        f.write('extern "C" int one() { return 1; }\n')
+
+    def built(flags):
+        before = os.stat(so).st_mtime_ns if os.path.exists(so) else None
+        assert native._compile(src, so, flags)
+        return os.stat(so).st_mtime_ns != before
+
+    assert built(["-O1"])  # first build
+    assert not built(["-O1"])  # same key: trusted
+    assert built(["-O2"])  # other flags: rebuilt
+    with open(src, "a") as f:
+        f.write("// edited\n")
+    assert built(["-O2"])  # other source: rebuilt
+    # a library that arrived from another machine: newer than its source,
+    # stamped with that machine's key
+    with open(so + ".build.json", "w") as f:
+        json.dump({"key": "built-elsewhere"}, f)
+    os.utime(so)
+    assert built(["-O2"])
+    os.remove(so + ".build.json")  # or with no stamp at all
+    assert built(["-O2"])
+
+    st = os.stat(so)
+    entry = {"lib": "hist_build", "host": native.host_key(),
+             "mtime": st.st_mtime, "size": st.st_size,
+             "sha256": canary._sha256(so), "verdict": canary.HEALTHY,
+             "detail": "golden run passed"}
+    canary._write_cache(so, entry)
+    assert canary.cached_verdict(so) == (canary.HEALTHY, "golden run passed")
+    canary._write_cache(so, dict(entry, host="another-machine"))
+    assert canary.cached_verdict(so) is None

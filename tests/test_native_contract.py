@@ -108,7 +108,7 @@ def test_nm_probe_flags_symbol_missing_from_so(tmp_path):
         f.write(textwrap.dedent("""
             import jax
             import jax.numpy as jnp
-            from jax.extend import ffi as jffi
+            from jax import ffi as jffi
 
             _lib = None
 

@@ -1,5 +1,5 @@
 """Every accepted parameter must have a behavioral use site — silent no-ops
-break the validate_parameters contract (reference: learner.cc:351; VERDICT
+break the validate_parameters contract (reference: learner.cc:351; review
 round-2 item 4: 13 accept-and-ignore fields)."""
 
 import os
@@ -127,7 +127,7 @@ def test_gblinear_selector_unknown_raises():
 def test_every_tree_param_has_a_use_site():
     """Source-level guard: each TrainParam/GBTreeParam/GBLinearParam field
     must be consumed somewhere outside params.py (implemented, warned, or
-    validated) — greps the package the way the round-2 VERDICT did."""
+    validated) — greps the package the way the round-2 review did."""
     from xgboost_tpu.params import GBLinearParam, GBTreeParam, TrainParam
 
     pkg = os.path.dirname(xgb.__file__)

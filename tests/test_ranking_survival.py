@@ -131,7 +131,7 @@ def test_cox_orders_risk():
 @pytest.mark.slow  # ~15s of tier-1 budget (1-core box); run with -m slow
 def test_ranking_large_groups_sampled_path():
     """MSLR-WEB30K-shaped: groups of 1000+ docs at ~100k rows must train
-    without materializing the [G, S, S] all-pairs tensor (VERDICT r2 weak
+    without materializing the [G, S, S] all-pairs tensor (review r2 weak
     item 4; reference pair sampling rank_obj.cu:143-198) and NDCG must
     improve over the untrained model."""
     rng = np.random.RandomState(3)

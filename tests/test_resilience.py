@@ -13,14 +13,14 @@ import pytest
 import xgboost_tpu as xgb
 from xgboost_tpu.observability import REGISTRY
 from xgboost_tpu.resilience import (
-    DEGRADED, DISABLED, HEALTHY, OneShot, RetryPolicy, WatchdogTimeout,
+    DEGRADED, DISABLED, HEALTHY, RetryPolicy, WatchdogTimeout,
     chaos, checkpoint, degrade, policy, watchdog,
 )
 
 
 # ---------------------------------------------------------------- policy
 
-def test_classify_taxonomy():
+def test_classify_kinds():
     """Kinds per docs/resilience.md: permanent signatures checked before
     resource (a scoped-VMEM overflow also says 'exhausted'), transient is
     the default, chaos errors carry their scripted kind."""
@@ -33,7 +33,7 @@ def test_classify_taxonomy():
         policy.PERMANENT
     assert policy.classify(NotImplementedError("no lowering")) == \
         policy.PERMANENT
-    assert policy.classify(ConnectionError("relay reset")) == \
+    assert policy.classify(ConnectionError("connection reset")) == \
         policy.TRANSIENT
     assert policy.classify(RuntimeError("anything else")) == policy.TRANSIENT
     assert policy.classify(chaos.ChaosResource("s", 1)) == policy.RESOURCE
@@ -207,21 +207,6 @@ def test_exposition_lists_every_registered_capability():
     exp = REGISTRY.exposition()
     for name in ("vis_cap", "pallas_predict", "onehot_build"):
         assert f'degrade_state{{capability="{name}"}}' in exp, (name, exp)
-
-
-def test_oneshot_runs_once_and_memoizes():
-    shot = OneShot("probe")
-    calls = [0]
-
-    def work():
-        calls[0] += 1
-        return 42
-
-    assert shot.run(work) == 42
-    assert shot.run(work) == 42
-    assert calls[0] == 1 and shot.done
-    shot.reset()
-    assert shot.run(work) == 42 and calls[0] == 2
 
 
 # ----------------------------------------------------------------- chaos

@@ -237,7 +237,7 @@ def test_sklearn_predict_uses_inplace_path():
 
 
 def test_pallas_blacklist_retry_escape():
-    """ISSUE 2 satellite (VERDICT weak #7), now on the resilience layer:
+    """ISSUE 2 satellite (review weak #7), now on the resilience layer:
     a degraded forest shape is skipped for N predicts, then retried
     instead of being poisoned for the life of the process — and the state
     is visible in the metrics exposition (ISSUE 5 tentpole)."""
@@ -262,19 +262,44 @@ def test_pallas_blacklist_retry_escape():
     assert _pallas_health.snapshot()["entries"] == {}
 
 
-def test_hoist_budget_uses_probe_when_stats_missing(monkeypatch):
-    """ISSUE 2 satellite (VERDICT weak #3): when memory_stats is hidden,
-    the hoist budget comes from the one-shot allocation probe instead of
-    the 8 GiB guess."""
-    from xgboost_tpu.tree import hist_kernel as hk
 
-    monkeypatch.delenv("XGBTPU_HOIST_BUDGET_MB", raising=False)
-    monkeypatch.setattr(hk, "device_free_bytes", lambda: None)
-    probed = 4 * 1024 * 1024 * 1024
-    monkeypatch.setattr(hk, "probe_free_bytes", lambda: probed)
-    assert hk.hoist_budget_bytes() == int(probed * 0.6)
-    # probe unavailable (CPU backend): the conservative default survives
-    monkeypatch.setattr(hk, "probe_free_bytes", lambda: None)
-    assert hk.hoist_budget_bytes() == 8192 * 1024 * 1024
-    # on this CPU test runner the real probe must refuse to run
-    assert hk.probe_free_bytes() is None or hk._probe.done
+
+def test_pallas_walk_body_interpreted_matches_gather_walk(monkeypatch):
+    """The pallas walk's kernel body, run through the interpret-mode test
+    hook, against the gather walk on a heap-layout forest; and the table
+    gate it shares with the ``predict_walk`` registry row. (Compiled on
+    the chip by chip_smoke.py at T=512, N=128 — the width whose [T, N, 8]
+    table outgrew the 16 MiB scoped-VMEM default; the table is [T, 8, N]
+    since.)"""
+    import jax.numpy as jnp
+
+    from xgboost_tpu import dispatch, predictor
+
+    X, y = _data(1200, 7, seed=5)
+    # per-round trees stay device-resident (heap layout) until a save
+    bst = xgb.train({"objective": "binary:logistic", "max_depth": 4,
+                     "max_bin": 32, "verbosity": 0},
+                    xgb.DMatrix(X, label=y), 6, verbose_eval=False)
+    forest = bst._gbm.model.stacked()
+    assert forest.heap_layout and forest.left.shape == (8, 32)
+    base = jnp.zeros((len(X), 1), jnp.float32)
+    want = np.asarray(predictor.predict_margin(forest, jnp.asarray(X), base))
+
+    monkeypatch.setattr(predictor, "_INTERPRET", True)
+    tab, _ = predictor._build_pred_tables(
+        forest.left, forest.feature, forest.cond, forest.default_left,
+        forest.tree_group, jnp.ones((8,), jnp.float32), 1)
+    assert tab.shape == (8, 8, 32)  # [T, 8, N]: nodes on the lanes
+    got = np.asarray(predictor.predict_margin(forest, jnp.asarray(X), base))
+    np.testing.assert_allclose(got, want, atol=1e-6)
+    assert predictor._pallas_health.snapshot()["worst"] == "healthy"
+
+    assert predictor.pallas_walk_fits(512, 128)
+    assert not predictor.pallas_walk_fits(4096, 128)
+    ctx = dict(platform="tpu", has_cats=False, heap_layout=True)
+    assert dispatch.resolve(
+        "predict_walk", dispatch.Ctx(trees=512, nodes=128, **ctx)
+    ).impl == "pallas"
+    assert dispatch.resolve(
+        "predict_walk", dispatch.Ctx(trees=4096, nodes=128, **ctx)
+    ).impl == "xla"

@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 import xgboost_tpu as xgb
+from conftest import require_native
 from xgboost_tpu import dispatch
 from xgboost_tpu.tree import tree_kernel
 
@@ -19,9 +20,9 @@ def _ffi_ready() -> bool:
     return tree_kernel.tree_ffi_ready() and hist_kernel._ensure_ffi()
 
 
-pytestmark = pytest.mark.skipif(
-    not _ffi_ready(),
-    reason="native toolchain / FFI headers unavailable")
+@pytest.fixture(autouse=True, scope="module")
+def _native_or_fail():
+    require_native(_ffi_ready(), "native tree/hist kernels")
 
 
 @pytest.fixture(autouse=True)

@@ -8,7 +8,6 @@ with row-sharded data parallelism over TPU meshes via ``jax.lax.psum`` in
 place of rabit/NCCL AllReduce.
 """
 
-from . import _compat  # noqa: F401  (pre-0.5 jax shims; must patch first)
 from .config import config_context, get_config, set_config  # noqa: F401
 from .config import apply_debug_env as _apply_debug_env
 

@@ -15,9 +15,12 @@ contract statically and cross-checks them:
   matching ``ffi::Error Impl(...)`` parameter list, so a binder/impl
   divergence inside one TU is caught without any Python in the picture.
 * **Python side** — an AST walk collects
-  ``jffi.register_ffi_target(name, jffi.pycapsule(lib.Symbol), ...)``
+  ``jax.ffi.register_ffi_target(name, jax.ffi.pycapsule(lib.Symbol), ...)``
   registrations (the target-name -> exported-symbol map) and every
-  ``jffi.ffi_call(target, ret_specs, *operands, **attrs)`` site: result
+  ``ffi_call(target, ret_specs, *operands, **attrs)`` site — the calling
+  convention of ``native.boundary.ffi_call``, the one wrapper over
+  ``jax.ffi.ffi_call(target, ret_specs)(*operands, **attrs)`` that every
+  production and canary call goes through: result
   count + dtypes from the ``ShapeDtypeStruct`` specs, operand count,
   operand dtypes where inferable (``x.astype(jnp.i32)`` / ``jnp.i32(e)``
   / a local assigned from one), and the attr keyword names.

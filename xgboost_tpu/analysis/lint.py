@@ -1057,7 +1057,7 @@ def _pass_concurrency(project: _Project) -> List[Finding]:
 
     # CC403: latch-shaped module-level declarations outside the resilience
     # state machine (name-based — the point is to force new fallback state
-    # through degrade.CapabilityHealth / OneShot, not to prove raciness)
+    # through degrade.CapabilityHealth, not to prove raciness)
     for mod in project.modules:
         if mod.relpath.endswith(_CC403_EXEMPT):
             continue

@@ -164,6 +164,9 @@ def cli_main(argv: List[str]) -> int:
     task = cli.get("task", "train")
 
     if task == "train":
+        from .config import enable_compile_cache
+
+        enable_compile_cache()
         dtrain = DMatrix(cli["data"])
         evals = [(DMatrix(p), name) for name, p in eval_specs]
         evals.append((dtrain, "train"))

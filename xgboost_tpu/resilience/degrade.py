@@ -1,10 +1,9 @@
 """Per-capability health state machine: HEALTHY → DEGRADED → DISABLED.
 
 This module REPLACES the package's scattered fallback latches — the
-pallas-predict shape blacklist (``predictor/__init__.py``), the hoisted
-one-hot build latch (``data/quantile.py``) and the HBM allocation-probe
-one-shot (``tree/hist_kernel.py``) — with one observable, lock-guarded
-policy. The reference encodes the same idea structurally: ``gpu_hist``
+pallas-predict shape blacklist (``predictor/__init__.py``) and the
+hoisted one-hot build latch (``data/quantile.py``) — with one observable,
+lock-guarded policy. The reference encodes the same idea structurally: ``gpu_hist``
 sizes itself to the device instead of crash-looping, and rabit's mock
 engine proves that a failed worker degrades to restart-from-checkpoint
 rather than wedging the ring.
@@ -30,13 +29,13 @@ observable state the ad-hoc latches never had.
 from __future__ import annotations
 
 import threading
-from typing import Any, Callable, Dict, Hashable, List, Optional, Tuple
+from typing import Any, Dict, Hashable, List, Optional, Tuple
 
 from . import policy
 
 __all__ = [
     "HEALTHY", "DEGRADED", "DISABLED", "STATE_NAMES",
-    "CapabilityHealth", "OneShot", "capability", "capabilities",
+    "CapabilityHealth", "capability", "capabilities",
     "snapshot", "worst", "reset",
 ]
 
@@ -208,39 +207,6 @@ class CapabilityHealth:
     def _publish_locked(self) -> None:
         _publish(self.name, max((e[0] for e in self._entries.values()),
                                 default=HEALTHY))
-
-
-class OneShot:
-    """Lock-guarded run-once memo — the resilience-owned replacement for
-    module-level ``_done``/value latch pairs (the hoist allocation probe).
-    The work runs UNDER the lock: a second thread arriving mid-run waits
-    for the result instead of duplicating a multi-second, multi-GB
-    measurement. A raising ``fn`` leaves the memo done with value None
-    (probes carry their own error handling and return None on failure)."""
-
-    def __init__(self, name: str = ""):
-        self.name = name
-        self._lock = threading.Lock()
-        self._done = False
-        self._value: Any = None
-
-    def run(self, fn: Callable[[], Any]) -> Any:
-        with self._lock:
-            if self._done:
-                return self._value
-            self._done = True
-            self._value = fn()
-            return self._value
-
-    @property
-    def done(self) -> bool:
-        with self._lock:
-            return self._done
-
-    def reset(self) -> None:
-        with self._lock:
-            self._done = False
-            self._value = None
 
 
 # ---------------------------------------------------------------------------

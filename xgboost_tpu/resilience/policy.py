@@ -6,7 +6,7 @@ retries transient socket errors (``allreduce_base.h`` ReConnectLinks),
 else kills the worker so the tracker restarts it from the last checkpoint.
 Here the classification is explicit and shared by every fallible path:
 
-- ``TRANSIENT``  — worth retrying in place (relay hiccup, device busy,
+- ``TRANSIENT``  — worth retrying in place (device busy, dropped link,
   injected chaos, interrupted IO). The default for anything unrecognized:
   a misclassified transient costs one wasted retry, a misclassified
   permanent poisons a capability.
@@ -108,7 +108,7 @@ def classify(exc: BaseException) -> str:
     their scripted kind (``chaos.ChaosError``); everything else is
     recognized by type name or message signature, with TRANSIENT as the
     default — XlaRuntimeError/JaxRuntimeError wrap transient runtime
-    failures (device busy, relay hiccup) as well as compile-layer ones, so
+    failures (device busy, dropped link) as well as compile-layer ones, so
     the type alone must never condemn a configuration (ADVICE r4)."""
     scripted = getattr(exc, "chaos_kind", None)
     if scripted in KINDS:

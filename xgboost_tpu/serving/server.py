@@ -788,6 +788,9 @@ def serve_main(argv: List[str], stdin=None, stdout=None) -> int:
         return 1
     stdin = stdin if stdin is not None else sys.stdin
     stdout = stdout if stdout is not None else sys.stdout
+    from ..config import enable_compile_cache
+
+    enable_compile_cache()
     server = ModelServer(
         opts["models"], arena_mb=opts.get("arena_mb"),
         max_queue=opts.get("max_queue"),

@@ -279,9 +279,9 @@ def train(
                         with _trace.span("round", iteration=i):
                             # deadline around the per-round host dispatch
                             # (off unless XGBTPU_WATCHDOG names
-                            # round_dispatch or *): a wedged relay aborts
-                            # cleanly — raise + checkpoint — instead of
-                            # hanging the run
+                            # round_dispatch or *): a wedged dispatch
+                            # aborts cleanly — raise + checkpoint —
+                            # instead of hanging the run
                             _t0 = time.perf_counter()
                             _boundary.tick()
                             _native_retry.run(_contained_update, i)

@@ -49,32 +49,25 @@ _ffi_state = {"registered": None}  # None = not tried, True/False = result
 def tree_ffi_ready() -> bool:
     """Build/load ``libtreebuild.so`` and register its FFI handlers with
     XLA (once per process). The ``tree_grow`` registry impl's availability
-    probe. False when the toolchain or jaxlib FFI headers are missing."""
+    probe. False when the library is unavailable (no toolchain, failed
+    build, canary refusal); a JAX API error propagates."""
     with _ffi_lock:
-        if _ffi_state["registered"] is not None:
-            return _ffi_state["registered"]
-        _ffi_state["registered"] = False
-        try:
-            from jax.extend import ffi as jffi
-
+        if _ffi_state["registered"] is None:
             from ..native import get_tree_lib
 
             lib = get_tree_lib()
-            if lib is None:
-                return False
-            jffi.register_ffi_target(
-                "xgbtpu_tree_grow", jffi.pycapsule(lib.XgbtpuTreeGrow),
-                platform="cpu")
-            jffi.register_ffi_target(
-                "xgbtpu_hb_level_sub", jffi.pycapsule(lib.XgbtpuHbLevelSub),
-                platform="cpu")
-            jffi.register_ffi_target(
-                "xgbtpu_hb_level_quant",
-                jffi.pycapsule(lib.XgbtpuHbLevelQuant), platform="cpu")
-            _ffi_state["registered"] = True
-        except Exception:
-            return False
-        return True
+            if lib is not None:
+                jax.ffi.register_ffi_target(
+                    "xgbtpu_tree_grow", jax.ffi.pycapsule(lib.XgbtpuTreeGrow),
+                    platform="cpu")
+                jax.ffi.register_ffi_target(
+                    "xgbtpu_hb_level_sub",
+                    jax.ffi.pycapsule(lib.XgbtpuHbLevelSub), platform="cpu")
+                jax.ffi.register_ffi_target(
+                    "xgbtpu_hb_level_quant",
+                    jax.ffi.pycapsule(lib.XgbtpuHbLevelQuant), platform="cpu")
+            _ffi_state["registered"] = lib is not None
+        return _ffi_state["registered"]
 
 
 def tree_grow_native(bins, gh, cut_values, tree_mask, G0, H0, *,
