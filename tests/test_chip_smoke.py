@@ -74,18 +74,23 @@ def test_compile_cache_is_placeable_from_outside(monkeypatch):
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
     assert config.compile_cache_dir() == "/some/dir"
 
-    # on a TPU backend: configured in code only when the variable is unset
+    # on a TPU backend: the directory is configured in code only when the
+    # variable is unset; the key takes the ops' metadata in either way, so
+    # that a cached executable never brings back the scope names and source
+    # lines of an older tree (ISSUE 24)
+    in_key = ("jax_compilation_cache_include_metadata_in_key", True)
     updates = []
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(jax.config, "update",
                         lambda k, v: updates.append((k, v)))
     assert config.enable_compile_cache() == "/some/dir"
-    assert updates == [] and \
+    assert updates == [in_key] and \
         os.environ["JAX_COMPILATION_CACHE_DIR"] == "/some/dir"
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    del updates[:]
     assert config.enable_compile_cache() == os.path.join(REPO, ".jax_cache")
     assert updates == [("jax_compilation_cache_dir",
-                        os.path.join(REPO, ".jax_cache"))]
+                        os.path.join(REPO, ".jax_cache")), in_key]
     assert "JAX_COMPILATION_CACHE_DIR" not in os.environ
 
 

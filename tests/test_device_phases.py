@@ -1,0 +1,373 @@
+"""The round's device phases and the chunk's host steps carry the package's
+own names in a profile (ISSUE 24).
+
+Device side: every part of a boosting round sits under a
+``jax.named_scope("xgb.<phase>")``, which becomes a component of each op's
+``op_name`` metadata, in the lowered module and in the compiled one. Host
+side: ``observability.trace.span(name)`` opens a
+``jax.profiler.TraceAnnotation("xgb." + name)`` while a profiler session is
+live, so the chunk's steps land on the profiler's clock. Neither changes
+what a program computes."""
+
+import glob
+import os
+import re
+import time
+
+import jax
+import numpy as np
+import pytest
+
+import xgboost_tpu as xgb
+from xgboost_tpu.gbm import gbtree
+from xgboost_tpu.observability import trace
+from xgboost_tpu.parallel import grow as pgrow
+from xgboost_tpu.parallel import make_mesh, mesh_context
+from xgboost_tpu.tree import hist_kernel as hk
+
+PARAMS = {"objective": "binary:logistic", "max_depth": 3, "max_bin": 16,
+          "eta": 0.5}
+ROUND_SCOPES = ["xgb.gradient", "xgb.root", "xgb.level_hist",
+                "xgb.split_eval", "xgb.partition", "xgb.finalize",
+                "xgb.leaf_delta"]
+
+
+def _data(n=512, F=6, seed=0):
+    rng = np.random.RandomState(seed)
+    X = rng.rand(n, F).astype(np.float32)
+    y = ((X[:, 0] > .5) ^ (X[:, 3] > .5)).astype(np.float32)
+    return X, y
+
+
+def _op_names(lowered):
+    """The scope paths of a program, before and after compilation."""
+    mlir = lowered.as_text(debug_info=True)
+    hlo = lowered.compile().as_text()
+    return mlir, set(re.findall(r'op_name="([^"]+)"', hlo))
+
+
+def _capture(module, attr, run):
+    """Lower the program ``module.attr`` with the arguments ``run()`` calls
+    it with (before the call: the scan donates its margin)."""
+    orig = getattr(module, attr)
+    jitted = getattr(orig, "_guarded_jit", orig)
+    lowered = []
+
+    def capturing(*args, **kwargs):
+        if not lowered:
+            lowered.append(jitted.lower(*args, **kwargs))
+        return orig(*args, **kwargs)
+
+    setattr(module, attr, capturing)
+    try:
+        run()
+    finally:
+        setattr(module, attr, orig)
+    assert lowered, f"{attr} was not called"
+    return _op_names(lowered[0])
+
+
+@pytest.fixture(scope="module")
+def one_chip_scan():
+    """``_scan_rounds_impl`` at a tiny shape on the per-level route the
+    chip takes (the Pallas kernels, their bodies interpreted)."""
+    X, y = _data()
+    d = xgb.DMatrix(X, label=y)
+    bst = xgb.Booster(PARAMS, [d])
+    saved = hk.use_pallas, hk._INTERPRET
+    hk.use_pallas, hk._INTERPRET = (lambda: True), True
+    try:
+        return _capture(gbtree, "_scan_rounds_impl",
+                        lambda: bst.update_many(d, 0, 2, chunk=2))
+    finally:
+        hk.use_pallas, hk._INTERPRET = saved
+
+
+@pytest.fixture(scope="module")
+def mesh_scan():
+    """``_dist_scan_impl`` over four virtual devices."""
+    X, y = _data()
+
+    def run():
+        with mesh_context(make_mesh(4)):
+            d = xgb.DMatrix(X, label=y)
+            xgb.Booster(PARAMS, [d]).update_many(d, 0, 2, chunk=2)
+
+    return _capture(pgrow, "_dist_scan_impl", run)
+
+
+def _assert_scope(texts, scope):
+    mlir, op_names = texts
+    assert scope + "/" in mlir, f"{scope} not in the lowered module"
+    under = [n for n in op_names if f"/{scope}/" in n + "/"]
+    assert under, f"{scope} in no op_name of the compiled program"
+
+
+@pytest.mark.parametrize("scope", ROUND_SCOPES)
+def test_one_chip_scan_carries_scope(one_chip_scan, scope):
+    _assert_scope(one_chip_scan, scope)
+
+
+@pytest.mark.parametrize("scope", ROUND_SCOPES + ["xgb.hist_psum"])
+def test_mesh_scan_carries_scope(mesh_scan, scope):
+    _assert_scope(mesh_scan, scope)
+
+
+def test_one_chip_scan_has_no_psum_scope(one_chip_scan):
+    assert "xgb.hist_psum" not in one_chip_scan[0]
+
+
+def test_level_kernel_keeps_its_name_under_the_scope(one_chip_scan):
+    """The benchmark finds the level kernels by the jitted function's name
+    in the path; the scope goes in front of it and renames nothing."""
+    _, op_names = one_chip_scan
+    assert any(re.search(r"/xgb\.level_hist/jit\(_(hoisted|fused)_level_"
+                         r"pallas\)", n) for n in op_names)
+
+
+@pytest.mark.parametrize("build", ["_build_onehot_xla",
+                                   "_build_onehot_pallas"])
+def test_onehot_build_carries_scope(monkeypatch, build):
+    """Inside the jitted builders, so that a caller outside any program
+    (``BinnedMatrix.fused_onehot``) gets it too."""
+    monkeypatch.setattr(hk, "_INTERPRET", True)
+    bins = jax.ShapeDtypeStruct((256, 4), np.uint8)
+    kwargs = {"B": 16} if build.endswith("xla") else {"B": 16, "tr": 256}
+    lowered = getattr(hk, build)._guarded_jit.lower(bins, **kwargs)
+    _assert_scope(_op_names(lowered), "xgb.onehot_build")
+
+
+def test_predict_walk_carries_scope():
+    X, y = _data()
+    d = xgb.DMatrix(X, label=y)
+    bst = xgb.train(PARAMS, d, 2)
+    from xgboost_tpu import predictor
+
+    f = bst._gbm.model.stacked()
+    lowered = predictor._predict_margin_kernel.lower(
+        jax.numpy.asarray(X), f.left, f.right, f.feature, f.cond,
+        f.default_left, f.split_type, f.cat_bits, f.tree_group,
+        jax.numpy.ones((f.left.shape[0],), np.float32),
+        jax.numpy.zeros((len(X), 1), np.float32),
+        n_groups=1, max_depth=int(f.max_depth), has_cats=False)
+    _assert_scope(_op_names(lowered), "xgb.predict_walk")
+
+
+# ---------------------------------------------------------------------------
+# the kernels' instruction names, as the chip's compiler gives them
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def one_v5e_chip():
+    """A described (not attached) v5e chip: the TPU compiler is installed
+    here and compiles for it. Only in this fixture, in this file (one
+    process holds the TPU library at a time)."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler on this box
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _mosaic_calls(fn, *shapes, **static):
+    """{instruction name: op_name} of the Mosaic calls ``fn`` compiles to."""
+    hlo = fn.lower(*shapes, **static).compile().as_text()
+    calls = {}
+    for line in hlo.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            name = re.match(r"\s*(?:ROOT )?%([\w\-]+?)(?:\.\d+)? = ", line)
+            calls[name.group(1)] = re.search(r'op_name="([^"]+)"',
+                                             line).group(1)
+    return calls
+
+
+@pytest.mark.parametrize("kernel", ["_hoisted_level_pallas",
+                                    "_build_onehot_pallas",
+                                    "_predict_margin_pallas"])
+def test_scope_leaves_the_kernels_instruction_name(one_v5e_chip, kernel):
+    """The TPU compiler names a Mosaic call after the last component of its
+    path before ``pallas_call``. The benchmark finds the level kernels by
+    that name, and the ledger's breakdowns carry the others: a scope must
+    sit in front of the component that names the kernel."""
+    import jax.numpy as jnp
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
+
+    n, F, B, K = 8192, 12, 256, 4
+    if kernel == "_hoisted_level_pallas":
+        def level(bins, onehot, pos, gh, ptab):
+            with jax.named_scope("xgb.level_hist"):  # as grow_fused has it
+                return hk._hoisted_level_pallas(
+                    bins.astype(jnp.int32), onehot, pos, gh, ptab, K=K,
+                    Kp=K >> 1, B=B, d=2, tr=hk._hoist_tr(F * B, K, F, B))
+
+        calls = _mosaic_calls(
+            jax.jit(level), S((n, F), jnp.uint8), S((n, F * B), jnp.int8),
+            S((n, 1), jnp.int32), S((n, 2), jnp.float32),
+            S((K >> 1, 4), jnp.float32))
+        scope = "xgb.level_hist"
+    elif kernel == "_build_onehot_pallas":
+        calls = _mosaic_calls(hk._build_onehot_pallas._guarded_jit,
+                              S((n, F), jnp.uint8), B=B,
+                              tr=hk._build_tr(n, F, B))
+        scope = "xgb.onehot_build"
+    else:
+        from xgboost_tpu import predictor
+
+        calls = _mosaic_calls(predictor._predict_margin_pallas,
+                              S((1024, F), jnp.float32),
+                              S((8, 8, 128), jnp.bfloat16),
+                              S((8, 1), jnp.float32), steps=3)
+        scope = "xgb.predict_walk"
+    assert list(calls) == [kernel]
+    assert f"/{scope}/" in calls[kernel]
+
+
+# ---------------------------------------------------------------------------
+# host steps on the profiler's clock
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture()
+def _no_chrome_trace(monkeypatch):
+    monkeypatch.delenv("XGBTPU_TRACE", raising=False)
+    trace.reset()
+    yield
+    trace.reset()
+
+
+def _profile(tmp_path, fn):
+    """Run ``fn`` under a profiler session set up as the benchmark's
+    (python tracer off) and return {thread: [(name, start, end)]} of the
+    ``xgb.`` events in ``/host:CPU``."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(path)
+    threads = {}
+    for plane in data.planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                   for e in line.events if e.name.startswith("xgb.")]
+            if evs:
+                threads[line.name] = sorted(evs, key=lambda t: t[1])
+    return threads
+
+
+def test_chunk_steps_nest_inside_scan_chunk_on_the_profilers_clock(
+        tmp_path, _no_chrome_trace):
+    X, y = _data()
+    d = xgb.DMatrix(X, label=y)
+    bst = xgb.Booster(PARAMS, [d])
+    bst.update_many(d, 0, 2, chunk=2)  # trace and compile outside
+    threads = _profile(tmp_path, lambda: bst.update_many(d, 2, 2, chunk=2))
+    assert len(threads) == 1, threads  # the caller's thread, no other
+    evs, = threads.values()
+    by_name = {n: (s, e) for n, s, e in evs}
+    assert [n for n, _, _ in evs if n != "xgb.local_rows"] == [
+        "xgb.scan_chunk", "xgb.chunk.prepare", "xgb.chunk.dispatch",
+        "xgb.chunk.commit", "xgb.chunk.admit"]
+    lo, hi = by_name["xgb.scan_chunk"]
+    steps = [by_name["xgb.chunk." + s]
+             for s in ("prepare", "dispatch", "commit")]
+    assert lo <= steps[0][0] and steps[-1][1] <= hi
+    for (_, e0), (s1, _) in zip(steps, steps[1:]):
+        assert e0 <= s1  # in time order, none inside another
+    # the entry layer's own step follows the chunk, outside its span
+    assert by_name["xgb.chunk.admit"][0] >= hi
+    # the three steps are the chunk: what they leave out is span overhead
+    covered = sum(e - s for s, e in steps)
+    assert covered <= hi - lo
+    # no Chrome event was recorded: XGBTPU_TRACE is unset
+    assert trace.flush() is None
+
+
+def test_span_records_on_both_clocks_with_bare_chrome_names(
+        tmp_path, monkeypatch):
+    out = tmp_path / "chrome.json"
+    monkeypatch.setenv("XGBTPU_TRACE", str(out))
+    trace.reset()
+
+    def run():
+        with trace.span("outer", k=1):
+            with trace.span("chunk.inner"):
+                pass
+
+    threads = _profile(tmp_path / "prof", run)
+    evs, = threads.values()
+    assert [n for n, _, _ in evs] == ["xgb.outer", "xgb.chunk.inner"]
+    trace.flush()
+    names = [e["name"] for e in trace.load_trace(str(out))
+             if e.get("ph") == "X"]
+    assert sorted(names) == ["chunk.inner", "outer"]
+    trace.reset()
+
+
+def test_span_is_suppressed_while_jax_is_staging(tmp_path, monkeypatch):
+    """jax 0.9 moved ``trace_state_clean``; the old lookup failed into
+    "always host side" and a span inside a staged function was recorded
+    once per compile."""
+    monkeypatch.setenv("XGBTPU_TRACE", str(tmp_path / "chrome.json"))
+    trace.reset()
+    seen = []
+
+    @jax.jit
+    def staged(x):
+        seen.append(trace.span("inside_jit"))
+        return x + 1
+
+    staged(1.0)
+    assert seen == [trace._NOOP]
+    assert not isinstance(trace.span("outside"), trace._NoopSpan)
+    trace.reset()
+
+
+def test_span_costs_under_5us_when_nothing_listens(_no_chrome_trace):
+    assert not trace.enabled()
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    assert trace.span("chunk.prepare") is trace._NOOP
+
+    def once(n=20000):
+        t0 = time.perf_counter()
+        for _ in range(n):
+            with trace.span("chunk.prepare"):
+                pass
+        return (time.perf_counter() - t0) / n * 1e6
+
+    once(2000)
+    best = min(once() for _ in range(5))
+    print(f"span() enter/exit, no profiler session, XGBTPU_TRACE unset: "
+          f"{best:.2f} us")
+    assert best < 5.0
+
+
+def test_model_bytes_equal_with_and_without_a_profiler_session(
+        tmp_path, _no_chrome_trace):
+    X, y = _data()
+
+    def fit():
+        d = xgb.DMatrix(X, label=y)
+        bst = xgb.Booster(dict(PARAMS, seed=7), [d])
+        bst.update_many(d, 0, 4, chunk=2)
+        return bytes(bst.save_raw("json"))
+
+    plain = fit()
+    profiled = []
+    _profile(tmp_path, lambda: profiled.append(fit()))
+    assert profiled == [plain]

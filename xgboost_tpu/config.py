@@ -131,4 +131,8 @@ def enable_compile_cache() -> Optional[str]:
     path = compile_cache_dir()
     if not os.environ.get(_CACHE_ENV):
         jax.config.update("jax_compilation_cache_dir", path)
+    # the cache's key otherwise leaves out the ops' metadata, and an
+    # executable compiled before an edit comes back with its old scope
+    # names and source lines: a profile would name phases that did not run
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return path

@@ -655,12 +655,14 @@ def _scan_rounds_impl(binsf, label, weight, m_pad, iters, cut_vals, eta,
         return jnp.concatenate([v, jnp.zeros((n_pad - n,), jnp.float32)])
 
     def body(m_pad, i):
-        m = m_pad[:n, 0] if K == 1 else m_pad[:n]
-        g, h = obj.get_gradient(m, label, weight, i)
+        with jax.named_scope("xgb.gradient"):
+            m = m_pad[:n, 0] if K == 1 else m_pad[:n]
+            g, h = obj.get_gradient(m, label, weight, i)
         trees = []
         for k in range(K):
-            gk = pad0(g[:, k] if g.ndim == 2 else g)
-            hk = pad0(h[:, k] if h.ndim == 2 else h)
+            with jax.named_scope("xgb.gradient"):
+                gk = pad0(g[:, k] if g.ndim == 2 else g)
+                hk = pad0(h[:, k] if h.ndim == 2 else h)
             for pt in range(n_parallel):
                 # bit-identical to boost_one_round's python-int key
                 # formula: the 31-bit mask reads only low bits
@@ -669,7 +671,8 @@ def _scan_rounds_impl(binsf, label, weight, m_pad, iters, cut_vals, eta,
                 t = grow_tree_fused(binsf, gk, hk, cut_vals, key, eta,
                                     gamma, cfg, feature_weights=fw,
                                     onehot=onehot)
-                m_pad = m_pad.at[:, k].add(t.delta)
+                with jax.named_scope("xgb.leaf_delta"):
+                    m_pad = m_pad.at[:, k].add(t.delta)
                 trees.append(
                     t._replace(delta=jnp.zeros((0,), jnp.float32)))
         stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *trees)
@@ -1538,65 +1541,79 @@ class GBTree:
             return self._scan_lossguide(binned, obj, label, weight, margin,
                                         start_iteration, num_rounds,
                                         feature_weights)
-        if use_mesh:
-            binsf, n_pad = binned.fused_bins_mesh(mesh)
-        else:
-            binsf, n_pad = binned.fused_bins()
-        cut_vals = jnp.asarray(binned.cuts.values)
-        fw = (jnp.asarray(feature_weights)
-              if feature_weights is not None else None)
-        eta = jnp.float32(tp.eta)
-        gamma = jnp.float32(tp.gamma)
-        label = jnp.asarray(label, jnp.float32)
-        weight_j = jnp.asarray(weight, jnp.float32) if weight is not None else None
-        seed_base = np.uint32((tp.seed * 1000003) & 0xFFFFFFFF)
+        # the chunk's three host steps, children of the caller's
+        # ``scan_chunk`` span: what the host does before the program is
+        # called, the call itself (tracing and compiling, when they happen,
+        # are in it), and what it does with the result
+        with _trace.span("chunk.prepare"):
+            if use_mesh:
+                binsf, n_pad = binned.fused_bins_mesh(mesh)
+            else:
+                binsf, n_pad = binned.fused_bins()
+            cut_vals = jnp.asarray(binned.cuts.values)
+            fw = (jnp.asarray(feature_weights)
+                  if feature_weights is not None else None)
+            eta = jnp.float32(tp.eta)
+            gamma = jnp.float32(tp.gamma)
+            label = jnp.asarray(label, jnp.float32)
+            weight_j = (jnp.asarray(weight, jnp.float32)
+                        if weight is not None else None)
+            seed_base = jnp.uint32(
+                np.uint32((tp.seed * 1000003) & 0xFFFFFFFF))
 
-        K = self.n_groups
-        m_pad = margin
-        if n_pad != n:
-            m_pad = jnp.concatenate(
-                [m_pad, jnp.zeros((n_pad - n, K), jnp.float32)])
-        iters = jnp.arange(start_iteration, start_iteration + num_rounds,
-                           dtype=jnp.int32)
-        if use_mesh:
-            from ..parallel.grow import distributed_boost_rounds_scan
-
-            # the mesh path shards label/weight alongside the padded rows
+            K = self.n_groups
+            m_pad = margin
             if n_pad != n:
-                label = jnp.concatenate(
-                    [label, jnp.zeros((n_pad - n,), jnp.float32)])
+                m_pad = jnp.concatenate(
+                    [m_pad, jnp.zeros((n_pad - n, K), jnp.float32)])
+            iters = jnp.arange(start_iteration, start_iteration + num_rounds,
+                               dtype=jnp.int32)
+            if use_mesh:
+                # the mesh path shards label/weight alongside the padded
+                # rows
+                if n_pad != n:
+                    label = jnp.concatenate(
+                        [label, jnp.zeros((n_pad - n,), jnp.float32)])
+                    if weight_j is not None:
+                        weight_j = jnp.concatenate(
+                            [weight_j, jnp.zeros((n_pad - n,), jnp.float32)])
+                label = shard_rows(label, mesh)
                 if weight_j is not None:
-                    weight_j = jnp.concatenate(
-                        [weight_j, jnp.zeros((n_pad - n,), jnp.float32)])
-            m_pad, stacked = distributed_boost_rounds_scan(
-                mesh, obj, binsf, shard_rows(label, mesh),
-                shard_rows(weight_j, mesh) if weight_j is not None else None,
-                shard_rows(m_pad, mesh), iters, cut_vals, eta, gamma, fw,
-                jnp.uint32(seed_base), n, cfg,
-                onehot=binned.fused_onehot_mesh(mesh, tp.max_depth),
-                fh_plan=binned.hoist_plan_mesh(mesh, tp.max_depth),
-            )
-            from ..parallel.mesh import local_rows
+                    weight_j = shard_rows(weight_j, mesh)
+                m_pad = shard_rows(m_pad, mesh)
+                onehot = binned.fused_onehot_mesh(mesh, tp.max_depth)
+                fh_plan = binned.hoist_plan_mesh(mesh, tp.max_depth)
+                groups = list(range(K))
+            else:
+                onehot = binned.fused_onehot(tp.max_depth)
+                npt = self.gbtree_param.num_parallel_tree
+                groups = [k for k in range(K) for _ in range(npt)]
+        with _trace.span("chunk.dispatch"):
+            if use_mesh:
+                from ..parallel.grow import distributed_boost_rounds_scan
 
-            # back to THIS process's rows (identity single-process): the
-            # margin cache, evals, and predictions are process-local
-            m_pad = local_rows(m_pad)
-        else:
-            npt = self.gbtree_param.num_parallel_tree
-            m_pad, stacked = _scan_rounds_impl(
-                binsf, label, weight_j, m_pad, iters, cut_vals, eta, gamma,
-                fw, jnp.uint32(seed_base), binned.fused_onehot(tp.max_depth),
-                obj=obj,
-                obj_fp=_obj_fingerprint(obj), cfg=cfg, n=n, n_pad=n_pad,
-                n_groups=K, n_parallel=npt,
-            )
-            groups = [k for k in range(K) for _ in range(npt)]
+                m_pad, stacked = distributed_boost_rounds_scan(
+                    mesh, obj, binsf, label, weight_j, m_pad, iters,
+                    cut_vals, eta, gamma, fw, seed_base, n, cfg,
+                    onehot=onehot, fh_plan=fh_plan,
+                )
+            else:
+                m_pad, stacked = _scan_rounds_impl(
+                    binsf, label, weight_j, m_pad, iters, cut_vals, eta,
+                    gamma, fw, seed_base, onehot, obj=obj,
+                    obj_fp=_obj_fingerprint(obj), cfg=cfg, n=n, n_pad=n_pad,
+                    n_groups=K, n_parallel=npt,
+                )
+        with _trace.span("chunk.commit"):
+            if use_mesh:
+                from ..parallel.mesh import local_rows
+
+                # back to THIS process's rows (identity single-process):
+                # the margin cache, evals, and predictions are process-local
+                m_pad = local_rows(m_pad)
             self.model.add_device_chunk(stacked, num_rounds, groups,
                                         tp.eta, tp.max_depth)
             return m_pad[:n]
-        self.model.add_device_chunk(stacked, num_rounds, list(range(K)),
-                                    tp.eta, tp.max_depth)
-        return m_pad[:n]
 
     def _scan_lossguide(self, binned, obj, label, weight, margin,
                         start_iteration, num_rounds, feature_weights):

@@ -309,9 +309,10 @@ class Booster:
                 entry.margin = None
                 info = dtrain.info
                 _t0 = time.perf_counter()
+                # the label goes up inside the chunk's ``chunk.prepare``
+                # step (gbtree), where a profile shows what it costs
                 margin = self._gbm.boost_rounds_scan(
-                    binned, self._obj,
-                    jnp.asarray(info.label), info.weight, margin,
+                    binned, self._obj, info.label, info.weight, margin,
                     start_iteration + done, k,
                     feature_weights=info.feature_weights,
                 )
@@ -327,8 +328,11 @@ class Booster:
                 # watermark. An async fault surfaces here attributed to
                 # the chunk's first round (sync time -> 'sync' stage).
                 try:
-                    self._pipeline.admit(start_iteration + done,
-                                         completion_probe(margin))
+                    # the one place this layer can block
+                    with _trace.span("chunk.admit",
+                                     start=start_iteration + done):
+                        self._pipeline.admit(start_iteration + done,
+                                             completion_probe(margin))
                 except BaseException:
                     self._pipeline.abandon()  # younger chunks are dead too
                     raise

@@ -25,8 +25,10 @@ accumulators, NVTX ranges, ``TrainingObserver`` dumps):
   timeline, coalescing, worst-request exemplars) over a model server's
   ``run_dir/obs/server/`` sink (``serving/obs.py`` — ISSUE 9).
 
-Everything is a no-op costing one branch per call site when disabled, and
-never records from inside ``jit``-traced code (host-side only).
+Everything is a shared no-op per call site when disabled (``trace.py`` says
+what that costs), and never records from inside ``jit``-traced code
+(host-side only). While a ``jax.profiler`` session is live every ``span()``
+is also open on the profiler's clock as ``xgb.<name>``.
 """
 
 from . import comms, metrics, trace  # noqa: F401

@@ -311,19 +311,24 @@ def _dist_scan_impl(bins, label, weight, margin, iters, cut_values, eta,
             onehot_s = (build_onehot(bins_s[:, :fh], B=B, vma=(ROW_AXIS,))
                         if fh else None)
 
+        # the same ``xgb.<phase>`` scopes as the one-chip scan body
+        # (gbtree._scan_rounds_impl); the tree's own are in grow_tree_fused
         def body(m_loc, i):
-            m = m_loc[:, 0] if K == 1 else m_loc
-            g, h = obj.get_gradient(m, label_s, weight_s, i)
+            with jax.named_scope("xgb.gradient"):
+                m = m_loc[:, 0] if K == 1 else m_loc
+                g, h = obj.get_gradient(m, label_s, weight_s, i)
             trees = []
             for k in range(K):
-                gk = (g[:, k] if g.ndim == 2 else g) * validf
-                hk = (h[:, k] if h.ndim == 2 else h) * validf
+                with jax.named_scope("xgb.gradient"):
+                    gk = (g[:, k] if g.ndim == 2 else g) * validf
+                    hk = (h[:, k] if h.ndim == 2 else h) * validf
                 seed = round_seed_traced(seed_base, i, k)
                 key = jax.random.PRNGKey(seed.astype(jnp.int32))
                 t = grow_tree_fused(bins_s, gk, hk, cut_values, key, eta,
                                     gamma, cfg_dist, feature_weights=fw,
                                     onehot=onehot_s)
-                m_loc = m_loc.at[:, k].add(t.delta)
+                with jax.named_scope("xgb.leaf_delta"):
+                    m_loc = m_loc.at[:, k].add(t.delta)
                 trees.append(t._replace(delta=jnp.zeros((0,), jnp.float32)))
             return m_loc, jtu.tree_map(lambda *xs: jnp.stack(xs), *trees)
 

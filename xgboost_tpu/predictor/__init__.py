@@ -166,7 +166,11 @@ def _walk_leaves(
 
         return jax.lax.fori_loop(0, max_depth, body, pos)
 
-    return jax.vmap(one_tree)(left, right, feature, cond, default_left, split_type, cat_bits)
+    # ``xgb.predict_walk`` names the walk in a device profile, whichever
+    # program it is staged into (docs/observability.md)
+    with jax.named_scope("xgb.predict_walk"):
+        return jax.vmap(one_tree)(left, right, feature, cond, default_left,
+                                  split_type, cat_bits)
 
 
 def _predict_margin_impl(
@@ -179,13 +183,17 @@ def _predict_margin_impl(
     """Unjitted margin body — shared by the training-side jit below and the
     serving cache's per-entry programs (``predictor/serving.py``, which fuse
     the output transform and must own their executables for LRU eviction)."""
-    leaves = _walk_leaves(X, left, right, feature, cond, default_left,
-                          split_type, cat_bits, max_depth, has_cats)  # [T, n]
-    leaf_vals = jnp.take_along_axis(cond, leaves, axis=1) * tree_weights[:, None]  # [T, n]
-    # sum per output group (multiclass: one tree per class per round,
-    # reference gbtree.cc:219 gradient slicing)
-    margins = jax.ops.segment_sum(leaf_vals, tree_group, num_segments=n_groups)  # [G, n]
-    return base_margin + margins.T
+    with jax.named_scope("xgb.predict_walk"):
+        leaves = _walk_leaves(X, left, right, feature, cond, default_left,
+                              split_type, cat_bits, max_depth,
+                              has_cats)  # [T, n]
+        leaf_vals = (jnp.take_along_axis(cond, leaves, axis=1)
+                     * tree_weights[:, None])  # [T, n]
+        # sum per output group (multiclass: one tree per class per round,
+        # reference gbtree.cc:219 gradient slicing)
+        margins = jax.ops.segment_sum(leaf_vals, tree_group,
+                                      num_segments=n_groups)  # [G, n]
+        return base_margin + margins.T
 
 
 _predict_margin_kernel = partial(
@@ -309,28 +317,34 @@ def _predict_margin_pallas(X, tab, ohg, steps):
     # for big forests (table bytes scale with T*Np)
     Tr = 256 if T * Np <= 32768 else 128
     n_pad = -(-n // Tr) * Tr
-    if n_pad != n:
-        X = jnp.concatenate(
-            [X, jnp.zeros((n_pad - n, F), X.dtype)], axis=0
-        )
     kern = functools.partial(_pred_kernel, T=T, Np=Np, F=F, G=G, steps=steps)
-    out = pl.pallas_call(
-        kern,
-        grid=(n_pad // Tr,),
-        in_specs=[
-            pl.BlockSpec((Tr, F), lambda c: (c, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((T, 8, Np), lambda c: (0, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((T, G), lambda c: (0, 0), memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((Tr, G), lambda c: (c, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((n_pad, G), jnp.float32),
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=_PRED_VMEM_LIMIT),
-        interpret=_INTERPRET,
-    )(X, tab, ohg)
-    return out[:n]
+    # the TPU compiler names a Mosaic call after the last component of its
+    # path: the second scope keeps the kernel's name in a profile
+    with jax.named_scope("xgb.predict_walk"), \
+            jax.named_scope("_predict_margin_pallas"):
+        if n_pad != n:
+            X = jnp.concatenate(
+                [X, jnp.zeros((n_pad - n, F), X.dtype)], axis=0
+            )
+        out = pl.pallas_call(
+            kern,
+            grid=(n_pad // Tr,),
+            in_specs=[
+                pl.BlockSpec((Tr, F), lambda c: (c, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((T, 8, Np), lambda c: (0, 0, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((T, G), lambda c: (0, 0),
+                             memory_space=pltpu.VMEM),
+            ],
+            out_specs=pl.BlockSpec((Tr, G), lambda c: (c, 0),
+                                   memory_space=pltpu.VMEM),
+            out_shape=jax.ShapeDtypeStruct((n_pad, G), jnp.float32),
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=_PRED_VMEM_LIMIT),
+            interpret=_INTERPRET,
+        )(X, tab, ohg)
+        return out[:n]
 
 
 _MASK_HI_I32 = np.int32(np.uint32(0xFFFF0000).view(np.int32))

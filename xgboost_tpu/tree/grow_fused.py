@@ -375,13 +375,17 @@ def _grow_tree_fused_impl(
     feature_weights: Optional[jax.Array] = None,
     onehot: Optional[jax.Array] = None,
 ) -> GrownTree:
+    # the ``xgb.<phase>`` scopes below name the round's device phases in a
+    # profile: each op's scope path is in the trace (docs/observability.md,
+    # "Reading a device profile"). They change HLO metadata only.
     pallas = _pallas_flag(cfg)
     if pallas:
         # transient in-program widening for the Mosaic kernels; the XLA
         # and native paths read the NARROW storage dtype directly (the
         # int8-packing half of the ISSUE 13 tentpole: no 4x int32 copy of
         # the bin matrix on the CPU path)
-        bins = bins.astype(jnp.int32)
+        with jax.named_scope("xgb.level_hist"):
+            bins = bins.astype(jnp.int32)
     n, F = bins.shape
     B = cut_values.shape[1]
     p = cfg.split
@@ -392,25 +396,26 @@ def _grow_tree_fused_impl(
     if cfg.axis_name is not None:
         k_sub = jax.random.fold_in(k_sub, jax.lax.axis_index(cfg.axis_name))
 
-    grad, hess = apply_row_sampling(cfg, k_sub, grad, hess)
-    gh = jnp.stack([grad, hess], axis=-1)  # [n, 2]
+    with jax.named_scope("xgb.root"):
+        grad, hess = apply_row_sampling(cfg, k_sub, grad, hess)
+        gh = jnp.stack([grad, hess], axis=-1)  # [n, 2]
 
-    if cfg.colsample_bytree < 1.0:
-        tree_mask = _sample_features_exact(
-            k_ctree, F, cfg.colsample_bytree, feature_weights
-        )
-    else:
-        tree_mask = jnp.ones((F,), bool)
+        if cfg.colsample_bytree < 1.0:
+            tree_mask = _sample_features_exact(
+                k_ctree, F, cfg.colsample_bytree, feature_weights
+            )
+        else:
+            tree_mask = jnp.ones((F,), bool)
 
-    # root totals (the InitRoot AllReduce site)
-    G0 = grad.sum()
-    H0 = hess.sum()
-    if cfg.axis_name is not None:
-        G0 = jax.lax.psum(G0, cfg.axis_name)
-        H0 = jax.lax.psum(H0, cfg.axis_name)
-    st = _init_state(cfg, F, G0, H0, B)
+        # root totals (the InitRoot AllReduce site)
+        G0 = grad.sum()
+        H0 = hess.sum()
+        if cfg.axis_name is not None:
+            G0 = jax.lax.psum(G0, cfg.axis_name)
+            H0 = jax.lax.psum(H0, cfg.axis_name)
+        st = _init_state(cfg, F, G0, H0, B)
+        pos = jnp.zeros((n, 1), jnp.int32)  # every row starts at the root
 
-    pos = jnp.zeros((n, 1), jnp.int32)
     tree_grow_native_route = _use_tree_grow(cfg, pallas, max_depth,
                                             str(bins.dtype))
     if tree_grow_native_route:
@@ -473,15 +478,18 @@ def _grow_tree_fused_impl(
             prev_off = jnp.left_shift(
                 jnp.int32(1), jnp.maximum(d - 1, 0)) - 1  # 0 at the root
             off = jnp.left_shift(jnp.int32(1), d) - 1
-            pos, histC = fused_level_scanned(
-                bins, pos, gh, st.ptab, prev_off, off, K=Km, B=B,
-                native=native)
+            with jax.named_scope("xgb.level_hist"):
+                pos, histC = fused_level_scanned(
+                    bins, pos, gh, st.ptab, prev_off, off, K=Km, B=B,
+                    native=native)
             if cfg.axis_name is not None:
                 from .. import collective
 
-                histC = collective.psum(histC, cfg.axis_name)
-            st = _level_update(st, histC, cut_values, tree_mask, k_level,
-                               cfg, d, Kw=Km)
+                with jax.named_scope("xgb.hist_psum"):
+                    histC = collective.psum(histC, cfg.axis_name)
+            with jax.named_scope("xgb.split_eval"):
+                st = _level_update(st, histC, cut_values, tree_mask,
+                                   k_level, cfg, d, Kw=Km)
             return (st, pos), None
 
         (st, pos), _ = jax.lax.scan(
@@ -491,27 +499,33 @@ def _grow_tree_fused_impl(
         for d in range(max_depth):
             K = 1 << d
             Kp = K >> 1  # previous level width (0 at the root)
-            pos, histC = fused_level(
-                bins, pos, gh, st.ptab, K=K, Kp=Kp, B=B, d=d, pallas=pallas,
-                onehot=onehot, axis_name=cfg.axis_name,
-            )  # histC: [F, 2K, B], missing excluded
+            with jax.named_scope("xgb.level_hist"):
+                pos, histC = fused_level(
+                    bins, pos, gh, st.ptab, K=K, Kp=Kp, B=B, d=d,
+                    pallas=pallas, onehot=onehot, axis_name=cfg.axis_name,
+                )  # histC: [F, 2K, B], missing excluded
             if cfg.axis_name is not None:
-                histC = jax.lax.psum(histC, cfg.axis_name)
-            st = _level_update(st, histC, cut_values, tree_mask, k_level,
-                               cfg, d)
+                with jax.named_scope("xgb.hist_psum"):
+                    histC = jax.lax.psum(histC, cfg.axis_name)
+            with jax.named_scope("xgb.split_eval"):
+                st = _level_update(st, histC, cut_values, tree_mask, k_level,
+                                   cfg, d)
 
     # ---- route rows through the last level's splits to their leaves ----
     # (folded into the whole-tree kernel when that route ran: its pos
     # output is already at the leaf level)
     if max_depth > 0 and not tree_grow_native_route:
-        pos = partition_apply(
-            bins, pos, st.ptab, Kp=1 << (max_depth - 1), B=B, d=max_depth,
-            axis_name=cfg.axis_name,
-        )
+        with jax.named_scope("xgb.partition"):
+            pos = partition_apply(
+                bins, pos, st.ptab, Kp=1 << (max_depth - 1), B=B,
+                d=max_depth, axis_name=cfg.axis_name,
+            )
 
-    keep, leaf_value = _finalize(st, eta, gamma, cfg)
+    with jax.named_scope("xgb.finalize"):
+        keep, leaf_value = _finalize(st, eta, gamma, cfg)
     pad_nodes = max(128, 1 << (max_nodes - 1).bit_length())
-    delta = leaf_delta(pos, leaf_value, pad_nodes, pallas=pallas)
+    with jax.named_scope("xgb.leaf_delta"):
+        delta = leaf_delta(pos, leaf_value, pad_nodes, pallas=pallas)
 
     return GrownTree(
         keep=keep, feature=st.feature, split_bin=st.split_bin,

@@ -311,26 +311,33 @@ def _build_onehot_pallas(bins: jax.Array, *, B: int, tr: int,
     from jax.experimental.pallas import tpu as pltpu
 
     n, F = bins.shape
-    return pl.pallas_call(
-        functools.partial(_build_onehot_body, F=F, B=B),
-        grid=(n // tr,),
-        in_specs=[
-            pl.BlockSpec((tr, F), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((tr, F * B), lambda i: (i, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=_vma_struct((n, F * B), jnp.int8, vma),
-        interpret=_INTERPRET,
-    )(bins.astype(jnp.int32))
+    # the scope sits inside the jitted builders: a caller outside any
+    # program (BinnedMatrix.fused_onehot) could not put it in the HLO. The
+    # TPU compiler names a Mosaic call after the last component of its
+    # path, so a second scope keeps the kernel's name in a profile.
+    with jax.named_scope("xgb.onehot_build"), \
+            jax.named_scope("_build_onehot_pallas"):
+        return pl.pallas_call(
+            functools.partial(_build_onehot_body, F=F, B=B),
+            grid=(n // tr,),
+            in_specs=[
+                pl.BlockSpec((tr, F), lambda i: (i, 0),
+                             memory_space=pltpu.VMEM),
+            ],
+            out_specs=pl.BlockSpec((tr, F * B), lambda i: (i, 0),
+                                   memory_space=pltpu.VMEM),
+            out_shape=_vma_struct((n, F * B), jnp.int8, vma),
+            interpret=_INTERPRET,
+        )(bins.astype(jnp.int32))
 
 
 @guard_jit(name="onehot_build_xla", static_argnames=("B",))
 def _build_onehot_xla(bins: jax.Array, *, B: int) -> jax.Array:
     n, F = bins.shape
-    iota = jnp.arange(B, dtype=jnp.int32)
-    oh = (bins.astype(jnp.int32)[:, :, None] == iota[None, None, :])
-    return oh.astype(jnp.int8).reshape(n, F * B)
+    with jax.named_scope("xgb.onehot_build"):
+        iota = jnp.arange(B, dtype=jnp.int32)
+        oh = (bins.astype(jnp.int32)[:, :, None] == iota[None, None, :])
+        return oh.astype(jnp.int8).reshape(n, F * B)
 
 
 def build_onehot(bins: jax.Array, *, B: int, vma=()) -> jax.Array:
