@@ -307,9 +307,22 @@ def stage_kernels(sz: Sizes, X, routes: dict) -> None:
                 say(f"  bin{B} d={d} {name}: cold {cold:.2f}s warm "
                     f"{warm:.4f}s  pos identical, max|dhist| "
                     f"{float(err.max()):.2e} (oracle {t_x:.2f}s)")
+            if d > 0:
+                # the routing kernel alone, as a tree's last level runs it
+                def route():
+                    return hk.partition_apply(bins32, pos, ptab, Kp=Kp, B=B,
+                                              d=d, pallas=True)
+
+                pos_r, cold = _timed(route)
+                _, warm = _timed(route)
+                check(np.array_equal(np.asarray(pos_r), pos_x),
+                      f"bin{B} d={d} route_rows: pos differs from XLA")
+                say(f"  bin{B} d={d} route_rows: cold {cold:.2f}s warm "
+                    f"{warm:.4f}s  pos identical")
         del binned, bins, bins32, onehots
     _check_routes("kernels", before, routes,
-                  must_see=("onehot_build", "sketch_cuts", "bin_matrix"))
+                  must_see=("onehot_build", "level_partition",
+                            "sketch_cuts", "bin_matrix"))
 
 
 # ---------------------------------------------------------------------------
@@ -398,7 +411,8 @@ def stage_train(sz: Sizes, xgb, X, y, routes: dict, rehearse: bool) -> None:
     del dtrain, dtest
     gc.collect()
     _check_routes("train", before, routes,
-                  must_see=("level_hist", "onehot_build", "predict_walk"))
+                  must_see=("level_hist", "level_partition", "onehot_build",
+                            "predict_walk"))
 
 
 # ---------------------------------------------------------------------------
@@ -628,7 +642,8 @@ def stage_four_chips(sz: Sizes, xgb, X, y, routes: dict) -> None:
         placed("hoisted one-hot", binned._onehot_mesh[1])
     placed("margin cache", margin, sharded=False)
     _check_routes("four_chips", before, routes,
-                  must_see=("level_hist", "onehot_build") if routes else ())
+                  must_see=("level_hist", "level_partition", "onehot_build")
+                  if routes else ())
 
     t1, t4 = b1._gbm.model.trees, b4._gbm.model.trees
     check(len(t1) == len(t4) == sz.mesh_rounds, "tree counts differ")
@@ -761,7 +776,8 @@ def main(argv=None) -> int:
     _hook_warnings()
 
     # The expected route of each op on this path — stated, not discovered.
-    routes = {"level_hist": "pallas", "onehot_build": "pallas",
+    routes = {"level_hist": "pallas", "level_partition": "pallas",
+              "onehot_build": "pallas",
               "leaf_delta": "pallas", "predict_walk": "pallas",
               "sketch_cuts": data_plane, "bin_matrix": data_plane}
     # (a model loaded from its file is served by ``loaded_walk`` instead:

@@ -12,6 +12,7 @@ what a program computes."""
 import glob
 import os
 import re
+import sys
 import time
 
 import jax
@@ -175,26 +176,49 @@ def one_v5e_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _mosaic_calls(fn, *shapes, **static):
-    """{instruction name: op_name} of the Mosaic calls ``fn`` compiles to."""
+def _benchmark_summary():
+    """``benchmark/reduce/summary.py``, loaded as the benchmark loads it."""
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "benchmark"))
+    try:
+        from bench_paths import load
+    finally:
+        sys.path.pop(0)
+    return load("reduce/summary.py")
+
+
+def _mosaic_lines(fn, *shapes, **static):
+    """The Mosaic calls ``fn`` compiles to, as the compiled module's text
+    has them (a profile's op line names an op by this text)."""
     hlo = fn.lower(*shapes, **static).compile().as_text()
+    return [line.strip() for line in hlo.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
+def _calls_of(lines):
+    """{instruction name: op_name} of Mosaic calls' lines."""
     calls = {}
-    for line in hlo.splitlines():
-        if 'custom_call_target="tpu_custom_call"' in line:
-            name = re.match(r"\s*(?:ROOT )?%([\w\-]+?)(?:\.\d+)? = ", line)
-            calls[name.group(1)] = re.search(r'op_name="([^"]+)"',
-                                             line).group(1)
+    for line in lines:
+        name = re.match(r"(?:ROOT )?%([\w\-]+?)(?:\.\d+)? = ", line)
+        calls[name.group(1)] = re.search(r'op_name="([^"]+)"',
+                                         line).group(1)
     return calls
+
+
+def _mosaic_calls(fn, *shapes, **static):
+    return _calls_of(_mosaic_lines(fn, *shapes, **static))
 
 
 @pytest.mark.parametrize("kernel", ["_hoisted_level_pallas",
                                     "_build_onehot_pallas",
-                                    "_predict_margin_pallas"])
+                                    "_predict_margin_pallas",
+                                    "_route_rows_pallas"])
 def test_scope_leaves_the_kernels_instruction_name(one_v5e_chip, kernel):
     """The TPU compiler names a Mosaic call after the last component of its
     path before ``pallas_call``. The benchmark finds the level kernels by
     that name, and the ledger's breakdowns carry the others: a scope must
-    sit in front of the component that names the kernel."""
+    sit in front of the component that names the kernel. The routing
+    kernel's name must hold no ``level``: it builds no histogram, and the
+    benchmark's reduction would book it to the level histogram's time."""
     import jax.numpy as jnp
 
     def S(shape, dtype):
@@ -218,6 +242,27 @@ def test_scope_leaves_the_kernels_instruction_name(one_v5e_chip, kernel):
                               S((n, F), jnp.uint8), B=B,
                               tr=hk._build_tr(n, F, B))
         scope = "xgb.onehot_build"
+    elif kernel == "_route_rows_pallas":
+        Kp = 32  # the anchor's last level
+
+        def route(bins, pos, ptab):
+            with jax.named_scope("xgb.partition"):  # as grow_fused has it
+                return hk._route_rows_pallas(bins, pos, ptab, Kp=Kp, B=B,
+                                             d=6)
+
+        lines = _mosaic_lines(
+            jax.jit(route), S((n, 50), jnp.int32), S((n, 1), jnp.int32),
+            S((Kp, 4), jnp.float32))
+        calls = _calls_of(lines)
+        scope = "xgb.partition"
+        # what the benchmark's reduction makes of the instruction
+        summary = _benchmark_summary()
+        text = lines[0].removeprefix("ROOT ")
+        assert "level" not in kernel
+        assert summary.kind_of(text) == "mosaic"
+        assert not summary.is_level_kernel(text)
+        assert summary.is_level_kernel(
+            text.replace(kernel, "_hoisted_level_pallas"))
     else:
         from xgboost_tpu import predictor
 

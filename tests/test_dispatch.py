@@ -67,6 +67,45 @@ def test_default_preference_order():
         "level_hist", _lh_ctx(platform="tpu", pallas=True)).impl == "pallas"
 
 
+def _lp_ctx(**kw):
+    base = dict(platform="cpu", pallas=False, interpret=False, rows=N,
+                features=F, nodes=4, table_width=4, bins_dtype="uint8",
+                sharded=False)
+    base.update(kw)
+    return Ctx(**base)
+
+
+_TPU_LP = dict(platform="tpu", pallas=True, bins_dtype="int32")
+
+
+@pytest.mark.parametrize("ctx,pin,want", [
+    # a TPU-flagged call site whose tile fits: the Mosaic routing kernel
+    (_TPU_LP, "", ("pallas",)),
+    (dict(_TPU_LP, sharded=True), "", ("pallas",)),
+    (dict(_TPU_LP, table_width=5 + 256, nodes=128), "", ("pallas",)),
+    # ragged rows, too many features, no pallas flag: as before this row
+    (dict(_TPU_LP, rows=N + 8), "", ("xla",)),
+    (dict(_TPU_LP, features=513), "", ("xla",)),
+    (dict(_TPU_LP, pallas=False), "", ("xla",)),
+    # off the TPU nothing changes route
+    (dict(), "", ("native", "xla")),
+    (dict(bins_dtype="int32"), "", ("xla",)),
+    (dict(sharded=True), "", ("xla",)),
+    (dict(interpret=True), "", ("xla",)),
+    # the pin that tests and A/B runs use still wins
+    (_TPU_LP, "level_partition=xla", ("xla",)),
+    (_TPU_LP, "level_partition=!pallas", ("xla",)),
+    (dict(), "level_partition=pallas", ("native", "xla")),
+])
+def test_level_partition_preference_matrix(monkeypatch, ctx, pin, want):
+    if pin:
+        monkeypatch.setenv("XGBTPU_DISPATCH", pin)
+    dec = dispatch.resolve("level_partition", _lp_ctx(**ctx))
+    assert dec.impl in want, dec
+    if pin and ctx:
+        assert dec.reason == "pinned"
+
+
 def test_pins_win_over_preference(monkeypatch):
     ds = Ctx(platform="cpu", pallas=False, has_cats=False, sharded=False,
              depth=6)

@@ -18,7 +18,7 @@ op                    implementations (preference order)         capability
 ``hist_acc``          CPU: quant > float (integer histogram      —
                       accumulation inside the whole-tree kernel)
 ``level_hist``        pallas > native (CPU) > xla                native_hist
-``level_partition``   native (CPU) > xla                         native_hist
+``level_partition``   pallas (TPU, fits) > native (CPU) > xla    native_hist
 ``level_update``      xla (single impl: shared split eval)       —
 ``depth_scan``        scanned > unrolled                         —
 ``onehot_build``      pallas > xla                               —
@@ -147,13 +147,28 @@ set_report_ctx("level_hist", lambda: Ctx(
     bins_dtype="uint8", sharded=False, onehot_width=0))
 
 
-register("level_partition", "native", pref=(("*", 0),),
+def _pallas_partition_applicable(ctx: Ctx) -> bool:
+    from ..tree import hist_kernel
+
+    return bool(ctx.get("pallas")) and hist_kernel.pallas_route_fits(
+        int(ctx.get("rows", 0)), int(ctx.get("features", 0)),
+        int(ctx.get("nodes", 1)), int(ctx.get("table_width", 4)))
+
+
+# The standalone routing step (a tree's last level, the paged deltas). On
+# the TPU the gather of the XLA form streams at a few GB/s, so the Mosaic
+# tile the level kernels share leads; on the CPU a gather is the cheap form
+# and a one-hot select over F is F times the work.
+register("level_partition", "pallas", pref=(("*", 0),),
+         applicable=_pallas_partition_applicable)
+register("level_partition", "native", pref=(("*", 1),),
          applicable=_native_level_applicable,
          available=_native_level_available,
          capability="native_hist")
-register("level_partition", "xla", pref=(("*", 1),))
+register("level_partition", "xla", pref=(("*", 2),))
 set_report_ctx("level_partition", lambda: Ctx(
-    platform=_platform(), interpret=False, table_width=4,
+    platform=_platform(), pallas=_platform() == "tpu", interpret=False,
+    rows=8192, features=50, nodes=32, table_width=4,
     bins_dtype="uint8", sharded=False))
 
 

@@ -448,7 +448,8 @@ def grow_tree_fused_profiled(bins, grad, hess, cut_values, key, eta, gamma,
             if max_depth > 0:
                 pos = dispatch.invoke(
                     "level_partition", _hk.partition_apply, bins, pos,
-                    st.ptab, Kp=1 << (max_depth - 1), B=B, d=max_depth)
+                    st.ptab, Kp=1 << (max_depth - 1), B=B, d=max_depth,
+                    pallas=pallas)
             keep, leaf_value = dispatch.invoke(
                 "finalize", _gf._finalize_jit, st, jnp.float32(eta),
                 jnp.float32(gamma), cfg=cfg)

@@ -518,7 +518,7 @@ def _grow_tree_fused_impl(
         with jax.named_scope("xgb.partition"):
             pos = partition_apply(
                 bins, pos, st.ptab, Kp=1 << (max_depth - 1), B=B,
-                d=max_depth, axis_name=cfg.axis_name,
+                d=max_depth, pallas=pallas, axis_name=cfg.axis_name,
             )
 
     with jax.named_scope("xgb.finalize"):
@@ -609,7 +609,7 @@ _finalize_jit = guard_jit(_finalize, name="finalize",
 @guard_jit(name="page_delta", static_argnames=("Kp", "B", "d", "pallas",
                                                "pad_nodes"))
 def _page_delta(bins, pos, ptab, leaf_value, *, Kp, B, d, pallas, pad_nodes):
-    pos = partition_apply(bins, pos, ptab, Kp=Kp, B=B, d=d)
+    pos = partition_apply(bins, pos, ptab, Kp=Kp, B=B, d=d, pallas=pallas)
     return leaf_delta(pos, leaf_value, pad_nodes, pallas=pallas)
 
 

@@ -46,7 +46,7 @@ __all__ = [
     "fused_level", "fused_level_xla", "fused_level_native",
     "partition_apply", "partition_apply_xla", "leaf_delta",
     "TR", "use_pallas", "use_native_hist", "build_onehot",
-    "pallas_level_fits",
+    "pallas_level_fits", "pallas_route_fits",
     "hoist_budget_bytes", "can_hoist", "hoist_plan", "device_free_bytes",
 ]
 
@@ -152,20 +152,31 @@ def fused_level_native(bins, pos, gh, ptab, *, K, Kp, B, d=None,
 
 
 def partition_apply(bins, pos, ptab, *, Kp: int, B: int, d: int,
-                    axis_name=None):
-    """Route rows through level ``d-1``'s decisions: the native FFI kernel
-    when the dispatch registry resolves ``level_partition`` to it (CPU
-    path), XLA everywhere else (identical integer decisions)."""
+                    pallas: bool = False, axis_name=None):
+    """Route rows through level ``d-1``'s decisions, by the impl the
+    dispatch registry resolves ``level_partition`` to: the Mosaic routing
+    kernel where the call site's ``pallas`` flag is set and the tile fits
+    (TPU: a gather streams at a few GB/s there), the native FFI kernel on
+    the CPU path, XLA everywhere else (identical integer decisions)."""
     from ..dispatch import Ctx, resolve
 
+    n, F = bins.shape
     dec = resolve("level_partition", Ctx(
-        platform=jax.default_backend(), interpret=bool(_INTERPRET),
-        table_width=int(ptab.shape[-1]), bins_dtype=str(bins.dtype),
-        sharded=axis_name is not None))
+        platform=jax.default_backend(), pallas=bool(pallas),
+        interpret=bool(_INTERPRET), rows=int(n), features=int(F),
+        nodes=int(Kp), table_width=int(ptab.shape[-1]),
+        bins_dtype=str(bins.dtype), sharded=axis_name is not None))
+    if dec.impl == "pallas":
+        vma = ()
+        if axis_name is not None:
+            # replication-proven table, uniformly varying operands: as in
+            # ``fused_level``
+            vma = (axis_name,)
+            ptab = jax.lax.pcast(ptab, vma, to="varying")
+        return _route_rows_pallas(bins, pos, ptab, Kp=Kp, B=B, d=d, vma=vma)
     if dec.impl == "native":
         from ..native import boundary
 
-        n, F = bins.shape
         prev_offset = (1 << (d - 1)) - 1 if d > 0 else 0
         return boundary.ffi_call(
             "xgbtpu_hb_partition",
@@ -607,6 +618,43 @@ def _hoisted_level_pallas(bins, onehot, pos, gh, ptab, *, K, Kp, B, d,
     return pos_new, hist
 
 
+def _route_kernel(bins_ref, pos_ref, ptab_ref, pos_out, *, Kp: int, F: int,
+                  B: int, prev_offset: int):
+    """One grid step of the tree's last routing: ``Tr`` rows through the
+    deepest level's decisions, and nothing else."""
+    pos_out[:, :] = _partition_tile(pos_ref[:, :], bins_ref[:, :], ptab_ref,
+                                    Kp=Kp, F=F, B=B, prev_offset=prev_offset)
+
+
+# no "level" in this name: the TPU compiler names the Mosaic call after the
+# function, and the benchmark books calls so named to the level histogram
+@guard_jit(name="route_rows_pallas",
+           static_argnames=("Kp", "B", "d", "tr", "vma"))
+def _route_rows_pallas(bins, pos, ptab, *, Kp, B, d, tr=TR, vma=()):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n, F = bins.shape
+    assert n % tr == 0, f"rows {n} not padded to {tr}"
+    prev_offset = (1 << (d - 1)) - 1 if d > 0 else 0
+    W = ptab.shape[1]
+    kern = functools.partial(_route_kernel, Kp=Kp, F=F, B=B,
+                             prev_offset=prev_offset)
+    return pl.pallas_call(
+        kern,
+        grid=(n // tr,),
+        in_specs=[
+            pl.BlockSpec((tr, F), lambda c: (c, 0), memory_space=pltpu.VMEM),
+            pl.BlockSpec((tr, 1), lambda c: (c, 0), memory_space=pltpu.VMEM),
+            pl.BlockSpec((Kp, W), lambda c: (0, 0), memory_space=pltpu.VMEM),
+        ],
+        out_specs=pl.BlockSpec((tr, 1), lambda c: (c, 0),
+                               memory_space=pltpu.VMEM),
+        out_shape=_vma_struct((n, 1), jnp.int32, vma),
+        interpret=_INTERPRET,
+    )(bins, pos, ptab)
+
+
 def partition_apply_xla(bins, pos, ptab, *, Kp: int, B: int, d: int,
                         prev_offset=None):
     """Route rows through level ``d-1``'s decisions (XLA, gather-free where
@@ -734,6 +782,26 @@ def pallas_level_fits(rows: int, F: int, K: int, B: int,
         if tr and rows % tr == 0:
             return True
     return F <= _MAX_KERNEL_FEATURES and F * 2 * K * B * 4 <= _VMEM_ACC_BUDGET
+
+
+def pallas_route_fits(rows: int, F: int, Kp: int, W: int) -> bool:
+    """Whether the routing kernel (``_route_rows_pallas``) fits: rows in
+    whole ``TR`` tiles and one grid step's working set inside the budget
+    the hoisted step has. Minor dimensions count at the 128 lanes they
+    occupy: double-buffered bins, positions in and out and the decision
+    table, plus the tile's f32 intermediates (the node one-hot, the
+    decisions, and three ``[TR, F]`` for the feature select). That bound
+    is tighter than ``_MAX_KERNEL_FEATURES`` (F of 384 at most, with any
+    table), and the kernel unrolls no loop over F. The
+    ``level_partition`` registry predicate is its one caller, as
+    ``pallas_level_fits`` is ``level_hist``'s."""
+    def lanes(w):
+        return -(-w // 128) * 128
+
+    step = 4 * (2 * TR * lanes(F) + 4 * TR * 128 + 2 * Kp * lanes(W)
+                + TR * (lanes(Kp) + lanes(W) + 3 * lanes(F)))
+    return (rows > 0 and rows % TR == 0 and F > 0 and Kp > 0
+            and step <= _VMEM_HOIST_BUDGET)
 
 
 def fused_level(bins, pos, gh, ptab, *, K, Kp, B, d, pallas: bool,
