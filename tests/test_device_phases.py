@@ -275,6 +275,72 @@ def test_scope_leaves_the_kernels_instruction_name(one_v5e_chip, kernel):
     assert f"/{scope}/" in calls[kernel]
 
 
+# MSLR-WEB30K's shape (ISSUE 26): 2,270,296 rows padded to the row tile
+_MSLR_ROWS, _MSLR_F, _MSLR_B, _MSLR_DEPTH = 2_271_232, 136, 256, 6
+
+
+def test_hoist_plan_at_136_features_is_vmem_gated_to_12(monkeypatch):
+    """The ``[2K, F*B]`` accumulator of the deepest level takes 8.9 of the
+    hoisted step's 12 MiB: 12 features stream, at the 128-row tile, and
+    the in-kernel construction alone does not fit."""
+    monkeypatch.setattr(hk, "use_pallas", lambda: True)
+    monkeypatch.setenv("XGBTPU_HOIST_BUDGET_MB", "8192")
+    n, F, B = _MSLR_ROWS, _MSLR_F, _MSLR_B
+    fh = hk.hoist_plan(n, F, B, _MSLR_DEPTH)
+    assert fh == 12
+    tiles = [hk._hoist_tr(fh * B, 1 << d, F, B) for d in range(_MSLR_DEPTH)]
+    assert tiles == [512, 512, 512, 512, 512, 128]
+    assert all(n % tr == 0 for tr in tiles)
+    assert hk._hoist_tr((fh + 1) * B, 32, F, B) == 0
+    assert not hk.pallas_level_fits(n, F, 32, B)
+    assert hk.pallas_level_fits(n, F, 32, B, onehot_width=fh * B)
+    assert hk.pallas_route_fits(n, F, 32, 4)
+
+
+@pytest.mark.parametrize("kernel,d", [("_hoisted_level_pallas", 0),
+                                      ("_hoisted_level_pallas", 5),
+                                      ("_route_rows_pallas", 6)])
+def test_kernels_compile_at_136_features_under_their_names(one_v5e_chip,
+                                                           kernel, d):
+    """The first and the deepest level (512- and 128-row tiles, 12 of 136
+    features hoisted) and the tree's last routing at F 136, compiled for a
+    described v5e: the chip's compiler takes them (VMEM, tiling) and names
+    them as the benchmark's reduction expects."""
+    import jax.numpy as jnp
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
+
+    n, F, B, Fh = 8192, _MSLR_F, _MSLR_B, 12
+    K, Kp = 1 << min(d, 5), (1 << d) >> 1
+    if kernel == "_hoisted_level_pallas":
+        tr = hk._hoist_tr(Fh * B, K, F, B)
+        assert tr == (512 if d == 0 else 128)
+
+        def level(bins, onehot, pos, gh, ptab):
+            with jax.named_scope("xgb.level_hist"):
+                return hk._hoisted_level_pallas(
+                    bins.astype(jnp.int32), onehot, pos, gh, ptab, K=K,
+                    Kp=Kp, B=B, d=d, tr=tr)
+
+        calls = _mosaic_calls(
+            jax.jit(level), S((n, F), jnp.uint8), S((n, Fh * B), jnp.int8),
+            S((n, 1), jnp.int32), S((n, 2), jnp.float32),
+            S((max(Kp, 1), 4), jnp.float32))
+        scope = "xgb.level_hist"
+    else:
+        def route(bins, pos, ptab):
+            with jax.named_scope("xgb.partition"):
+                return hk._route_rows_pallas(bins, pos, ptab, Kp=Kp, B=B,
+                                             d=d)
+
+        calls = _mosaic_calls(jax.jit(route), S((n, F), jnp.int32),
+                              S((n, 1), jnp.int32), S((Kp, 4), jnp.float32))
+        scope = "xgb.partition"
+    assert list(calls) == [kernel]
+    assert f"/{scope}/" in calls[kernel]
+
+
 # ---------------------------------------------------------------------------
 # host steps on the profiler's clock
 # ---------------------------------------------------------------------------

@@ -272,13 +272,12 @@ def test_rank_map_deltas_match_reference_oracle():
     # sampled path: the estimator now carries the reference-expectation
     # weights internally, so many draws must recover the oracle DIRECTLY
     # (no rescaling)
-    starts = np.asarray(gptr[:-1], np.int32)
+    from xgboost_tpu.objective.ranking import _build_layout
+
     n_pair = 256
     g_s, _ = _lambda_grad_sampled(
-        jnp.asarray(p), jnp.asarray(y), jnp.asarray(group_of),
-        jnp.asarray(starts[group_of]),
-        jnp.asarray(np.asarray(sizes, np.int32)[group_of]),
-        jax.random.PRNGKey(0), 3, n_pair, "map")
+        jnp.asarray(p), _build_layout(y, gptr, None).arrays,
+        jax.random.PRNGKey(0), jnp.int32(0), n_pair=n_pair, scheme="map")
     gs = np.asarray(g_s)
     corr = np.corrcoef(gs, g_oracle)[0, 1]
     assert corr > 0.98, corr

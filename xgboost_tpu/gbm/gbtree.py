@@ -41,9 +41,21 @@ def _hist_seconds():
 @functools.partial(guard_jit, name="margin_add", static_argnames=("k",),
                    donate_argnames=("m",))
 def _margin_add_jit(m, delta, *, k=None):
-    if k is None:
-        return m + delta
-    return m.at[:, k].add(delta)
+    # the scan bodies book the margin's add to the same phase
+    with jax.named_scope("xgb.leaf_delta"):
+        delta = delta[: m.shape[0]]  # the grower's row padding
+        if k is None:
+            return m + delta
+        return m.at[:, k].add(delta)
+
+
+@functools.partial(guard_jit, name="pad_gh", static_argnames=("n_pad",))
+def _pad_gh(g, h, *, n_pad):
+    """A round's gradient and hessian padded to the grower's row tile:
+    padded rows belong to no query and no leaf, and weigh nothing."""
+    with jax.named_scope("xgb.gradient"):  # as the scan bodies book it
+        pad = jnp.zeros((n_pad - g.shape[0],), jnp.float32)
+        return jnp.concatenate([g, pad]), jnp.concatenate([h, pad])
 
 
 def _margin_add(margin_cache, delta, k):
@@ -1425,9 +1437,7 @@ class GBTree:
 
             def grow_one(g, h, key):
                 if n_pad != n:
-                    pad = jnp.zeros((n_pad - n,), jnp.float32)
-                    g = jnp.concatenate([g, pad])
-                    h = jnp.concatenate([h, pad])
+                    g, h = _pad_gh(g, h, n_pad=n_pad)
                 elif self.gbtree_param.num_parallel_tree > 1:
                     # hess is DONATED into the grow program; parallel trees
                     # re-pass the same slice, so each call needs its own
@@ -1463,8 +1473,7 @@ class GBTree:
                                       cat_mask)
                 new_trees.append(grown)
                 if margin_cache is not None:
-                    margin_cache = _margin_add(margin_cache, grown.delta[:n],
-                                               k)
+                    margin_cache = _margin_add(margin_cache, grown.delta, k)
         return new_trees, margin_cache
 
     def scan_rounds_supported(self, binned, obj, n_groups: int) -> bool:

@@ -223,25 +223,30 @@ class Booster:
             return
         from .utils import observer
 
-        with self.monitor.section("GetGradient"):
+        with self.monitor.section("GetGradient"), \
+                _trace.span("round.gradient", iteration=iteration):
             margin = self._cached_margin(dtrain)
-            m = margin[:, 0] if self.n_groups == 1 else margin
-            info = dtrain.info
-            grad, hess = self._obj.get_gradient(
-                m,
-                jnp.asarray(info.label) if info.label is not None else jnp.zeros(dtrain.num_row()),
-                jnp.asarray(info.weight) if info.weight is not None else None,
-                iteration,
-                group_ptr=info.group_ptr,
-                label_lower=jnp.asarray(info.label_lower_bound) if info.label_lower_bound is not None else None,
-                label_upper=jnp.asarray(info.label_upper_bound) if info.label_upper_bound is not None else None,
-            )
+            grad, hess = self._gradient(dtrain, margin, iteration)
         if observer.enabled():
             observer.observe("margin", margin, iteration)
             observer.observe("grad", grad, iteration)
             observer.observe("hess", hess, iteration)
-        self._do_boost(dtrain, grad, hess, iteration)
+        with _trace.span("round.boost", iteration=iteration):
+            self._do_boost(dtrain, grad, hess, iteration)
         self.monitor.maybe_print()
+
+    def _gradient(self, dtrain: DMatrix, margin, iteration: int):
+        # the scope names what is traced under it; a jitted objective
+        # (ranking) opens ``xgb.gradient`` inside its own program
+        with jax.named_scope("xgb.gradient"):
+            return self._obj.gradient_of(margin, dtrain.info, iteration)
+
+    def gradient(self, dtrain: DMatrix, iteration: int = 0):
+        """``(grad, hess)`` that ``update(dtrain, iteration)`` would boost
+        on, as device arrays: the objective at the cached training margin.
+        Changes nothing."""
+        self._configure()
+        return self._gradient(dtrain, self._cached_margin(dtrain), iteration)
 
     def update_many(self, dtrain: DMatrix, start_iteration: int,
                     num_rounds: int, chunk: int = 25) -> None:

@@ -49,6 +49,29 @@ class ObjFunction:
     ) -> Tuple[jax.Array, jax.Array]:
         raise NotImplementedError
 
+    def gradient_of(self, margin: jax.Array, info, iteration: int = 0
+                    ) -> Tuple[jax.Array, jax.Array]:
+        """The gradient a round boosts on, from the ``[n, K]`` training
+        margin and the matrix's ``MetaInfo``: the per-round entry
+        (``Booster.update``). The default sends up what ``get_gradient``
+        reads; an objective that keeps per-matrix state on the device
+        (ranking) overrides it."""
+        def up(a):
+            return jnp.asarray(a) if a is not None else None
+
+        m = margin[:, 0] if margin.ndim == 2 and margin.shape[1] == 1 \
+            else margin
+        return self.get_gradient(
+            m,
+            up(info.label) if info.label is not None
+            else jnp.zeros(margin.shape[0]),
+            up(info.weight),
+            iteration,
+            group_ptr=info.group_ptr,
+            label_lower=up(info.label_lower_bound),
+            label_upper=up(info.label_upper_bound),
+        )
+
     # margin -> user-facing prediction (reference: PredTransform)
     def pred_transform(self, margin: jax.Array) -> jax.Array:
         return margin
