@@ -206,7 +206,10 @@ def stage_kernels(sz: Sizes, X, routes: dict) -> None:
     """Each level kernel the anchor can route to, compiled, against
     ``fused_level_xla`` on a slice of the anchor: identical ``pos``,
     histograms within the 2^-16-relative class the hi/lo split promises
-    (the comparison tests/test_hoisted.py makes in interpret mode).
+    (the comparison tests/test_hoisted.py makes in interpret mode). Below
+    the root each runs twice: the direct build, and the child every parent
+    marked with its sibling derived from the oracle's parent histogram
+    (``*_sub``, tests/test_sibling_sub.py's comparison).
 
     The oracle is placed on the host CPU device where JAX has one: XLA's
     TPU compile of its scatter-add grows with the row count (67 s a shape
@@ -272,7 +275,9 @@ def stage_kernels(sz: Sizes, X, routes: dict) -> None:
                 pos_np = (prev + rng.randint(0, Kp, size=(n, 1))
                           ).astype(np.int32)
                 ptab_np = np.stack([
-                    (rng.rand(Kp) < 0.85).astype(np.float32),  # is_split
+                    # is_split, and which child a subtracting level builds
+                    ((rng.rand(Kp) < 0.85) * rng.randint(1, 3, Kp)
+                     ).astype(np.float32),
                     rng.randint(0, F, Kp).astype(np.float32),
                     rng.randint(0, B - 1, Kp).astype(np.float32),
                     rng.randint(0, 2, Kp).astype(np.float32),
@@ -284,26 +289,48 @@ def stage_kernels(sz: Sizes, X, routes: dict) -> None:
             t_x = time.perf_counter() - t0
             _, habs = oracle(bins_np, pos_np, np.abs(gh_np), ptab_np, **kw)
             # two bf16 terms carry ~16 significand bits per addend
-            tol = habs * 2.0 ** -15 + 1e-6
-            cands = {"construct": lambda: hk._fused_level_pallas(
-                bins32, pos, gh, ptab, **kw)}
-            for fh in widths:
-                tr = hk._hoist_tr(fh * B, K, F, B)
-                check(tr > 0 and n % tr == 0,
-                      f"bin{B} d={d}: no hoisted row tile for Fh={fh}")
-                name = "hoisted_full" if fh == F else f"hoisted_partial{fh}"
-                cands[name] = (lambda oh=onehots[fh], tr=tr:
-                               hk._hoisted_level_pallas(
-                                   bins32, oh, pos, gh, ptab, tr=tr, **kw))
+            tol = {False: habs * 2.0 ** -15 + 1e-6}
+            if d > 0:
+                # the parents' histogram and, for a derived cell, the
+                # tolerance of the parent's cell it was subtracted from
+                up = dict(K=Kp, Kp=0, B=B, d=d - 1)
+                parent = jnp.asarray(
+                    oracle(bins_np, pos_np, gh_np, ptab_np, **up)[1])
+                pabs = oracle(bins_np, pos_np, np.abs(gh_np), ptab_np,
+                              **up)[1].reshape(F, 2, Kp, 1, B)
+                tol[True] = np.broadcast_to(pabs, (F, 2, Kp, 2, B)).reshape(
+                    F, 2 * K, B) * 2.0 ** -15 + 1e-6
+            cands = {}
+            for sub in ((False, True) if d > 0 else (False,)):
+                Kc, tag = (Kp, "_sub") if sub else (K, "")
+                cands["construct" + tag] = (
+                    lambda sub=sub: hk._fused_level_pallas(
+                        bins32, pos, gh, ptab, sub=sub, **kw))
+                for fh in widths:
+                    tr = hk._hoist_tr(fh * B, Kc, F, B)
+                    check(tr > 0 and n % tr == 0, f"bin{B} d={d}: no "
+                          f"hoisted row tile for Fh={fh} at {Kc} nodes")
+                    name = ("hoisted_full" if fh == F
+                            else f"hoisted_partial{fh}") + tag
+                    cands[name] = (lambda oh=onehots[fh], tr=tr, sub=sub:
+                                   hk._hoisted_level_pallas(
+                                       bins32, oh, pos, gh, ptab, tr=tr,
+                                       sub=sub, **kw))
             for name, fn in cands.items():
                 (pos_p, hist_p), cold = _timed(fn)
                 _, warm = _timed(fn)
                 check(np.array_equal(np.asarray(pos_p), pos_x),
                       f"bin{B} d={d} {name}: pos differs from XLA")
+                sub = name.endswith("_sub")
+                if sub:
+                    check(hist_p.shape == (F, 2 * Kp, B),
+                          f"bin{B} d={d} {name}: not the built half")
+                    hist_p = hk.derive_siblings(parent, hist_p, ptab)
                 err = np.abs(np.asarray(hist_p) - hist_x)
-                check(bool((err <= tol).all()),
+                tol_c = tol[sub]
+                check(bool((err <= tol_c).all()),
                       f"bin{B} d={d} {name}: histogram off by "
-                      f"{float((err - tol).max()):.3e} beyond tolerance")
+                      f"{float((err - tol_c).max()):.3e} beyond tolerance")
                 say(f"  bin{B} d={d} {name}: cold {cold:.2f}s warm "
                     f"{warm:.4f}s  pos identical, max|dhist| "
                     f"{float(err.max()):.2e} (oracle {t_x:.2f}s)")
@@ -411,8 +438,8 @@ def stage_train(sz: Sizes, xgb, X, y, routes: dict, rehearse: bool) -> None:
     del dtrain, dtest
     gc.collect()
     _check_routes("train", before, routes,
-                  must_see=("level_hist", "level_partition", "onehot_build",
-                            "predict_walk"))
+                  must_see=("level_hist", "sibling_sub", "level_partition",
+                            "onehot_build", "predict_walk"))
 
 
 # ---------------------------------------------------------------------------
@@ -777,7 +804,7 @@ def main(argv=None) -> int:
 
     # The expected route of each op on this path — stated, not discovered.
     routes = {"level_hist": "pallas", "level_partition": "pallas",
-              "onehot_build": "pallas",
+              "sibling_sub": "on", "onehot_build": "pallas",
               "leaf_delta": "pallas", "predict_walk": "pallas",
               "sketch_cuts": data_plane, "bin_matrix": data_plane}
     # (a model loaded from its file is served by ``loaded_walk`` instead:

@@ -341,6 +341,54 @@ def test_kernels_compile_at_136_features_under_their_names(one_v5e_chip,
     assert f"/{scope}/" in calls[kernel]
 
 
+@pytest.mark.parametrize("kernel,F,Fh,d", [
+    ("_hoisted_level_pallas", _MSLR_F, 12, 5),  # MSLR's deepest level
+    ("_hoisted_level_pallas", 28, 7, 7),  # HIGGS's
+    ("_fused_level_pallas", 12, 0, 3)])
+def test_sibling_sub_kernels_keep_their_names_and_halve_the_output_rows(
+        one_v5e_chip, kernel, F, Fh, d):
+    """Sibling subtraction (ISSUE 27): a level below the root builds
+    2^(d-1) nodes, so its f32 output has 2^d rows where the direct build
+    has 2^(d+1), at the row tile the level above has; the Mosaic call
+    keeps the name the benchmark's reduction books to the level histogram."""
+    import jax.numpy as jnp
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
+
+    n, B = 8192, _MSLR_B
+    K, Kp = 1 << d, 1 << (d - 1)
+    shapes = [S((n, F), jnp.uint8), S((n, 1), jnp.int32),
+              S((n, 2), jnp.float32), S((Kp, 4), jnp.float32)]
+    if kernel == "_hoisted_level_pallas":
+        tr = hk._hoist_tr(Fh * B, Kp, F, B)
+        assert tr == 512 and hk._hoist_tr(Fh * B, K, F, B) == 128
+
+        def level(bins, pos, gh, ptab, onehot):
+            with jax.named_scope("xgb.level_hist"):
+                return hk._hoisted_level_pallas(
+                    bins.astype(jnp.int32), onehot, pos, gh, ptab, K=K,
+                    Kp=Kp, B=B, d=d, tr=tr, sub=True)
+
+        shapes.append(S((n, Fh * B), jnp.int8))
+        out = f"f32[{2 * Kp},{F * B}]"
+    else:
+        def level(bins, pos, gh, ptab):
+            with jax.named_scope("xgb.level_hist"):
+                return hk._fused_level_pallas(
+                    bins.astype(jnp.int32), pos, gh, ptab, K=K, Kp=Kp, B=B,
+                    d=d, sub=True)
+
+        out = f"f32[{F},{2 * Kp},{B}]"
+    lines = _mosaic_lines(jax.jit(level), *shapes)
+    calls = _calls_of(lines)
+    assert list(calls) == [kernel]
+    assert "/xgb.level_hist/" in calls[kernel]
+    assert out in lines[0].split(" custom-call(")[0], lines[0][:300]
+    summary = _benchmark_summary()
+    assert summary.is_level_kernel(lines[0].removeprefix("ROOT "))
+
+
 # ---------------------------------------------------------------------------
 # host steps on the profiler's clock
 # ---------------------------------------------------------------------------

@@ -266,6 +266,19 @@ def test_format_grow_detail_renders_table():
     assert "route=" not in kernelprof.format_grow_detail(legacy)
 
 
+@pytest.mark.parametrize("impl,note", [("pallas", True), ("native", False),
+                                       ("xla", False)])
+def test_format_grow_detail_says_the_pallas_mirror_builds_every_node(impl,
+                                                                     note):
+    """ISSUE 27: the program's Pallas level loop subtracts siblings, the
+    mirror does not; its report says so on that route and no other."""
+    rec = _fake_record(route="level")
+    rec["ops"][1]["impl"] = impl
+    txt = kernelprof.format_grow_detail(rec)
+    assert ("route=level, sibling_sub=off (mirror)" in txt) is note
+    assert "route=level" in txt
+
+
 def test_grow_report_main_over_torn_sink(tmp_path, capsys):
     """grow-report over a hand-written run dir: sampled records render,
     a torn final line (SIGKILL mid-write) is tolerated, and a sink with

@@ -14,7 +14,8 @@ Op reference (see docs/perf.md, "Choosing a kernel"):
 op                    implementations (preference order)         capability
 ====================  =========================================  =============
 ``tree_grow``         native (CPU, whole-round kernel) > level   native_tree
-``sibling_sub``       on > off (histogram subtraction trick)     —
+``sibling_sub``       on > off (histogram subtraction trick:     —
+                      whole-tree kernel, Pallas level loop)
 ``hist_acc``          CPU: quant > float (integer histogram      —
                       accumulation inside the whole-tree kernel)
 ``level_hist``        pallas > native (CPU) > xla                native_hist
@@ -105,10 +106,16 @@ set_report_ctx("tree_grow", lambda: Ctx(
     colsample_node=1.0, max_delta_step=0.0))
 
 
-# Sibling subtraction inside the whole-tree kernel: build only the smaller
-# child's histogram, derive the other as parent - child. ``off`` pins the
-# kernel bit-identical to the per-level native path (the legacy
-# ``XGBTPU_SIBLING_SUB=0`` kill switch maps here).
+# Sibling subtraction: build only the smaller child's histogram, derive the
+# other as parent - child. Two routes obey the row: the whole-tree kernel
+# (CPU; smaller by row count), and the unrolled level loop where the Mosaic
+# level kernels run (TPU, ``grow_fused``: smaller by hessian sum, resolved
+# once a level below the root; the kernels then run half the gradient
+# channels and a mesh's psum carries half the bytes). ``off`` pins the
+# whole-tree kernel bit-identical to the per-level native path and the
+# level loop to the direct build of every node (the legacy
+# ``XGBTPU_SIBLING_SUB=0`` kill switch maps here). The other routes (paged,
+# depth-scanned, per-level native, lossguide) build every node directly.
 register("sibling_sub", "on", pref=(("*", 0),))
 register("sibling_sub", "off", pref=(("*", 1),))
 set_report_ctx("sibling_sub", lambda: Ctx(platform=_platform()))

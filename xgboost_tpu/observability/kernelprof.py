@@ -48,7 +48,10 @@ calls, so the replayed histograms (and hence the whole round) match the
 fused kernel's output bit-for-bit while every level still lands in its
 own ``level_hist`` bucket. The record carries ``route`` and
 ``sibling_sub`` so a reader knows the numbers describe a per-level
-replay of a one-dispatch round.
+replay of a one-dispatch round. On the Pallas level route the production
+program subtracts siblings too (``grow_fused``, ISSUE 27); the mirror
+does not grow a second implementation of that and builds every node,
+which its report says: ``sibling_sub=off (mirror)``.
 
 The record feeds the flight record as ``grow_detail`` (rendered by
 ``python -m xgboost_tpu grow-report``) and each bracket is emitted as a
@@ -498,6 +501,11 @@ def format_grow_detail(rec: Dict[str, Any],
                 route_note += " (sibling-sub replay)"
             else:
                 route_note += " (per-level replay)"
+        elif any(b.get("op") == "level_hist" and b.get("impl") == "pallas"
+                 for b in rec.get("ops", ())):
+            # the program's Pallas level loop builds one child of every
+            # split (grow_fused); this mirror builds every node
+            route_note += ", sibling_sub=off (mirror)"
     lines = [
         f"round {rec.get('round')}: grow detail "
         f"({rec.get('driver')}, {rec.get('trees')} tree(s){route_note})",

@@ -48,6 +48,7 @@ from .grow import (
 )
 from .hist_kernel import (
     TR,
+    derive_siblings,
     fused_level,
     leaf_delta,
     partition_apply,
@@ -151,10 +152,19 @@ def _level_update(
     cfg: GrowParams,
     d,  # python int (unrolled/paged) or traced scalar (depth scan)
     Kw: Optional[int] = None,
+    mark_built: bool = False,
 ) -> _HeapState:
     """Evaluate level ``d``'s splits from its histogram and write the heap
     arrays + the next partition table. Shared by the in-core single-program
     grower, the depth-scanned driver and the external-memory paged driver.
+
+    ``mark_built`` (the next level subtracts siblings): the table's
+    ``is_split`` column reads 2 at a split whose RIGHT child has the
+    smaller hessian sum, and the next level kernel builds that child alone
+    (1: the left). The derived child is then the larger, so
+    ``parent - built`` never cancels to a small number. (The native core
+    picks by row count, ``tree_build.cpp``; this loop has no counts and
+    needs none.) Routing reads the column as ``> 0.5`` either way.
 
     ``Kw`` is the FIXED node width of the depth-scanned driver (the
     deepest level's ``2^(max_depth-1)``); ``d`` is then a traced scan
@@ -266,9 +276,13 @@ def _level_update(
         used = used.at[lidx].set(child_used, mode="drop")
         used = used.at[ridx].set(child_used, mode="drop")
 
+    if mark_built:
+        split_col = jnp.where(can_split, jnp.where(HLb <= HRb, 1.0, 2.0), 0.0)
+    else:
+        split_col = can_split.astype(jnp.float32)
     ptab = jnp.stack(
         [
-            can_split.astype(jnp.float32),
+            split_col,
             dec.f.astype(jnp.float32),
             dec.b.astype(jnp.float32),
             (dec.dir == 1).astype(jnp.float32),
@@ -496,6 +510,14 @@ def _grow_tree_fused_impl(
             _level_body, (st, pos),
             jnp.arange(max_depth, dtype=jnp.int32))
     else:
+        from ..dispatch import Ctx, resolve
+
+        # Sibling subtraction where the Mosaic level kernels run (the
+        # reference's SubtractionTrick, updater_gpu_hist.cu): below the
+        # root a level builds one child of every split, under a mesh the
+        # psum carries that half, and the sibling is the parent's
+        # histogram, kept for one level, less the built child's.
+        sub = False  # the root has no parent
         for d in range(max_depth):
             K = 1 << d
             Kp = K >> 1  # previous level width (0 at the root)
@@ -503,13 +525,22 @@ def _grow_tree_fused_impl(
                 pos, histC = fused_level(
                     bins, pos, gh, st.ptab, K=K, Kp=Kp, B=B, d=d,
                     pallas=pallas, onehot=onehot, axis_name=cfg.axis_name,
-                )  # histC: [F, 2K, B], missing excluded
+                    sibling_sub=sub,
+                )  # histC: [F, 2K, B], missing excluded; built: [F, 2Kp, B]
             if cfg.axis_name is not None:
                 with jax.named_scope("xgb.hist_psum"):
                     histC = jax.lax.psum(histC, cfg.axis_name)
+            if histC.shape[1] == 2 * Kp:  # the built half: pallas, sub
+                with jax.named_scope("xgb.level_hist"):
+                    histC = derive_siblings(parent_hist, histC, st.ptab)
+            sub = (pallas and d + 1 < max_depth
+                   and resolve("sibling_sub", Ctx(
+                       platform=jax.default_backend(), pallas=True,
+                       depth=d + 1)).impl == "on")
             with jax.named_scope("xgb.split_eval"):
                 st = _level_update(st, histC, cut_values, tree_mask, k_level,
-                                   cfg, d)
+                                   cfg, d, mark_built=sub)
+            parent_hist = histC
 
     # ---- route rows through the last level's splits to their leaves ----
     # (folded into the whole-tree kernel when that route ran: its pos
@@ -600,7 +631,7 @@ def _pallas_flag(cfg: GrowParams) -> bool:
 # The heap state is DONATED: the per-level node-state tensors are updated
 # in place across the level loop instead of re-allocated (ISSUE 13).
 _level_update_jit = guard_jit(_level_update, name="level_update",
-                              static_argnames=("cfg", "d"),
+                              static_argnames=("cfg", "d", "mark_built"),
                               donate_argnames=("st",))
 _finalize_jit = guard_jit(_finalize, name="finalize",
                           static_argnames=("cfg",))

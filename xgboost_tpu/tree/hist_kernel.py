@@ -44,7 +44,8 @@ from ..analysis.retrace import guard_jit
 
 __all__ = [
     "fused_level", "fused_level_xla", "fused_level_native",
-    "partition_apply", "partition_apply_xla", "leaf_delta",
+    "derive_siblings", "partition_apply", "partition_apply_xla",
+    "leaf_delta",
     "TR", "use_pallas", "use_native_hist", "build_onehot",
     "pallas_level_fits", "pallas_route_fits",
     "hoist_budget_bytes", "can_hoist", "hoist_plan", "device_free_bytes",
@@ -387,11 +388,14 @@ def _split_hilo(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
 def _partition_tile(pos, binsb, ptab_ref, *, Kp: int, F: int, B: int,
                     prev_offset: int):
     """Route a tile's rows through the previous level's decision table
-    (shared by both level kernels). ``pos``/``binsb`` are values in VMEM.
-    Table layout: ``[Kp, 4]`` numerical (is_split, feature, bin,
-    default_left), or ``[Kp, 5 + B]`` when categorical features exist —
-    column 4 flags a categorical node and columns 5: carry its RIGHT-going
-    category set (evaluate_splits.h Decision: stored sets go right)."""
+    (shared by the level kernels and the routing kernel). ``pos``/``binsb``
+    are values in VMEM. Table layout: ``[Kp, 4]`` numerical (is_split,
+    feature, bin, default_left), or ``[Kp, 5 + B]`` when categorical
+    features exist — column 4 flags a categorical node and columns 5:
+    carry its RIGHT-going category set (evaluate_splits.h Decision: stored
+    sets go right). ``is_split`` is 0 (no split), 1, or 2: a split whose
+    RIGHT child a sibling-subtracting level builds (1: the left), see
+    ``_level_update``; routing reads it as ``> 0.5``."""
     Tr = binsb.shape[0]
     W = ptab_ref.shape[-1]
     lp = pos - prev_offset
@@ -430,13 +434,12 @@ def _partition_tile(pos, binsb, ptab_ref, *, Kp: int, F: int, B: int,
     return pos + (goes > 0.5).astype(jnp.int32) * (child - pos)
 
 
-def _grad_channels(pos, gh_ref, *, K: int, offset: int):
-    """[Tr, 4K] bf16 per-node gradient channels from heap positions; column
-    order [g_hi | h_hi | g_lo | h_lo] so ``out[:2K] + out[2K:] = [g, h]``."""
-    Tr = pos.shape[0]
-    local = pos - offset
-    iota_k = jax.lax.broadcasted_iota(jnp.int32, (Tr, K), 1)
-    ohseg = (local == iota_k).astype(jnp.float32)  # [Tr, K]
+def _grad_channels(node, ids, gh_ref):
+    """[Tr, 4K] bf16 per-node gradient channels; column order
+    [g_hi | h_hi | g_lo | h_lo] so ``out[:2K] + out[2K:] = [g, h]``. A row
+    contributes to the channel group whose id (``ids``: ``[Tr, K]`` or
+    ``[1, K]`` i32) its ``node`` (``[Tr, 1]`` i32) is, or to none."""
+    ohseg = (node == ids).astype(jnp.float32)  # [Tr, K]
     g = gh_ref[:, 0:1]
     h = gh_ref[:, 1:2]
     g_hi, g_lo = _split_hilo(g)
@@ -446,12 +449,38 @@ def _grad_channels(pos, gh_ref, *, K: int, offset: int):
     ).astype(jnp.bfloat16)  # [Tr, 4K]
 
 
-def _level_kernel(bins_ref, pos_ref, gh_ref, ptab_ref, pos_out, hist_ref,
-                  *, K: int, Kp: int, F: int, B: int,
+def _route_and_channels(pos, binsb, gh_ref, ptab_ref, built_ref, *, K: int,
+                        Kp: int, F: int, B: int, prev_offset: int,
+                        offset: int):
+    """The level kernels' shared head: route the tile's rows through the
+    previous level's decisions, then form the gradient channels. Direct
+    build (``built_ref`` None): one channel group a node of this level,
+    ``[Tr, 4K]``. Sibling subtraction: one a PARENT, ``[Tr, 4Kp]``, for the
+    rows now at the child that parent marked, whose heap index
+    ``built_ref`` ``[1, Kp]`` holds (-1: the parent did not split; a row
+    that stayed above this level is at no child). The sibling is
+    ``parent - built``, taken outside (``derive_siblings``)."""
+    if Kp > 0:
+        pos = _partition_tile(pos, binsb, ptab_ref, Kp=Kp, F=F, B=B,
+                              prev_offset=prev_offset)
+    if built_ref is None:
+        iota_k = jax.lax.broadcasted_iota(jnp.int32, (pos.shape[0], K), 1)
+        return pos, _grad_channels(pos - offset, iota_k, gh_ref)
+    return pos, _grad_channels(pos, built_ref[:, :], gh_ref)
+
+
+def _level_kernel(bins_ref, pos_ref, gh_ref, ptab_ref, *rest,
+                  K: int, Kp: int, F: int, B: int,
                   prev_offset: int, offset: int):
     """One grid step: partition `Tr` rows through the previous level's
-    decisions, then accumulate their (g, h) into this level's histogram."""
+    decisions, then accumulate their (g, h) into this level's histogram.
+    ``rest``: the outputs ``pos_out, hist_ref``, behind ``built_ref`` where
+    siblings are subtracted (the histogram is then the built children's,
+    ``Kc = Kp`` nodes wide)."""
     from jax.experimental import pallas as pl
+
+    *built_ref, pos_out, hist_ref = rest
+    built_ref = built_ref[0] if built_ref else None
 
     c = pl.program_id(0)
 
@@ -459,16 +488,14 @@ def _level_kernel(bins_ref, pos_ref, gh_ref, ptab_ref, pos_out, hist_ref,
     def _():
         hist_ref[...] = jnp.zeros_like(hist_ref)
 
-    pos = pos_ref[:, :]  # [Tr, 1] i32 heap positions
     binsb = bins_ref[:, :]  # [Tr, F] i32
     Tr = binsb.shape[0]
-
-    if Kp > 0:
-        pos = _partition_tile(pos, binsb, ptab_ref, Kp=Kp, F=F, B=B,
-                              prev_offset=prev_offset)
+    Kc = K if built_ref is None else Kp
+    # pos: [Tr, 1] i32 heap positions
+    pos, ghs4 = _route_and_channels(
+        pos_ref[:, :], binsb, gh_ref, ptab_ref, built_ref, K=K, Kp=Kp, F=F,
+        B=B, prev_offset=prev_offset, offset=offset)
     pos_out[:, :] = pos
-
-    ghs4 = _grad_channels(pos, gh_ref, K=K, offset=offset)
 
     for f in range(F):
         col = binsb[:, f:f + 1]
@@ -477,8 +504,8 @@ def _level_kernel(bins_ref, pos_ref, gh_ref, ptab_ref, pos_out, hist_ref,
         out = jax.lax.dot_general(
             ghs4, oh, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )  # [4K, B]
-        hist_ref[f, :, :] += out[:2 * K] + out[2 * K:]
+        )  # [4Kc, B]
+        hist_ref[f, :, :] += out[:2 * Kc] + out[2 * Kc:]
 
 
 def _vma_struct(shape, dtype, axes):
@@ -490,10 +517,29 @@ def _vma_struct(shape, dtype, axes):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
+def _built_children(ptab, *, Kp: int, d: int, sub: bool):
+    """The extra operand of a sibling-subtracting level kernel: ``[1, Kp]``
+    i32, for every parent the heap index of the child its decision table
+    marks to be built (``is_split`` 1: the left, 2: the right), -1 where it
+    does not split. Returns (arrays, block specs): empty for the direct
+    build, whose kernels take no such operand."""
+    if not sub:
+        return [], []
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    assert Kp > 0, "the root has no parent to subtract from"
+    mark = ptab[:, 0].astype(jnp.int32)
+    parent = ((1 << (d - 1)) - 1) + jnp.arange(Kp, dtype=jnp.int32)
+    built = jnp.where(mark > 0, 2 * parent + mark, -1)[None, :]
+    return [built], [pl.BlockSpec((1, Kp), lambda c: (0, 0),
+                                  memory_space=pltpu.VMEM)]
+
+
 @guard_jit(name="fused_level_pallas",
-           static_argnames=("K", "Kp", "B", "d", "tr", "vma"))
+           static_argnames=("K", "Kp", "B", "d", "tr", "vma", "sub"))
 def _fused_level_pallas(bins, pos, gh, ptab, *, K, Kp, B, d, tr=TR,
-                        vma=()):
+                        vma=(), sub=False):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -502,6 +548,8 @@ def _fused_level_pallas(bins, pos, gh, ptab, *, K, Kp, B, d, tr=TR,
     prev_offset = (1 << (d - 1)) - 1 if d > 0 else 0
     offset = (1 << d) - 1
     W = ptab.shape[1]
+    Kc = Kp if sub else K  # nodes built: one child of every parent
+    built, built_specs = _built_children(ptab, Kp=Kp, d=d, sub=sub)
     kern = functools.partial(
         _level_kernel, K=K, Kp=Kp, F=F, B=B,
         prev_offset=prev_offset, offset=offset,
@@ -515,28 +563,32 @@ def _fused_level_pallas(bins, pos, gh, ptab, *, K, Kp, B, d, tr=TR,
             pl.BlockSpec((tr, 2), lambda c: (c, 0), memory_space=pltpu.VMEM),
             pl.BlockSpec((max(Kp, 1), W), lambda c: (0, 0),
                          memory_space=pltpu.VMEM),
-        ],
+        ] + built_specs,
         out_specs=[
             pl.BlockSpec((tr, 1), lambda c: (c, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((F, 2 * K, B), lambda c: (0, 0, 0),
+            pl.BlockSpec((F, 2 * Kc, B), lambda c: (0, 0, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
             _vma_struct((n, 1), jnp.int32, vma),
-            _vma_struct((F, 2 * K, B), jnp.float32, vma),
+            _vma_struct((F, 2 * Kc, B), jnp.float32, vma),
         ],
         interpret=_INTERPRET,
-    )(bins, pos, gh, ptab)
+    )(bins, pos, gh, ptab, *built)
 
 
-def _hoisted_kernel(bins_ref, oh_ref, pos_ref, gh_ref, ptab_ref, pos_out,
-                    hist_ref, *, K: int, Kp: int, F: int, Fh: int, B: int,
+def _hoisted_kernel(bins_ref, oh_ref, pos_ref, gh_ref, ptab_ref, *rest,
+                    K: int, Kp: int, F: int, Fh: int, B: int,
                     prev_offset: int, offset: int):
     """Hoisted-one-hot grid step: partition + grad channels (cheap VPU),
-    ONE [4K, Tr] x [Tr, Fh*B] MXU matmul streaming the resident one-hot
+    ONE [4Kc, Tr] x [Tr, Fh*B] MXU matmul streaming the resident one-hot
     for the first ``Fh`` features, and an in-kernel construct loop for the
-    remaining ``F - Fh`` (empty when the full expansion fit HBM)."""
+    remaining ``F - Fh`` (empty when the full expansion fit HBM). ``rest``
+    and ``Kc`` as in ``_level_kernel``."""
     from jax.experimental import pallas as pl
+
+    *built_ref, pos_out, hist_ref = rest
+    built_ref = built_ref[0] if built_ref else None
 
     c = pl.program_id(0)
 
@@ -544,21 +596,20 @@ def _hoisted_kernel(bins_ref, oh_ref, pos_ref, gh_ref, ptab_ref, pos_out,
     def _():
         hist_ref[...] = jnp.zeros_like(hist_ref)
 
-    pos = pos_ref[:, :]
     binsb = bins_ref[:, :]
     Tr = binsb.shape[0]
-    if Kp > 0:
-        pos = _partition_tile(pos, binsb, ptab_ref, Kp=Kp, F=F, B=B,
-                              prev_offset=prev_offset)
+    Kc = K if built_ref is None else Kp
+    pos, ghs4 = _route_and_channels(  # ghs4: [Tr, 4Kc]
+        pos_ref[:, :], binsb, gh_ref, ptab_ref, built_ref, K=K, Kp=Kp, F=F,
+        B=B, prev_offset=prev_offset, offset=offset)
     pos_out[:, :] = pos
 
-    ghs4 = _grad_channels(pos, gh_ref, K=K, offset=offset)  # [Tr, 4K]
     oh = oh_ref[:, :].astype(jnp.bfloat16)  # [Tr, Fh*B] int8 -> bf16
     out = jax.lax.dot_general(
         ghs4, oh, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
-    )  # [4K, Fh*B]
-    hist_ref[:, : Fh * B] += out[: 2 * K] + out[2 * K:]
+    )  # [4Kc, Fh*B]
+    hist_ref[:, : Fh * B] += out[: 2 * Kc] + out[2 * Kc:]
     for f in range(Fh, F):
         col = binsb[:, f:f + 1]
         iota_b = jax.lax.broadcasted_iota(jnp.int32, (Tr, B), 1)
@@ -566,14 +617,14 @@ def _hoisted_kernel(bins_ref, oh_ref, pos_ref, gh_ref, ptab_ref, pos_out,
         outf = jax.lax.dot_general(
             ghs4, ohf, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )  # [4K, B]
-        hist_ref[:, f * B:(f + 1) * B] += outf[: 2 * K] + outf[2 * K:]
+        )  # [4Kc, B]
+        hist_ref[:, f * B:(f + 1) * B] += outf[: 2 * Kc] + outf[2 * Kc:]
 
 
 @guard_jit(name="hoisted_level_pallas",
-           static_argnames=("K", "Kp", "B", "d", "tr", "vma"))
+           static_argnames=("K", "Kp", "B", "d", "tr", "vma", "sub"))
 def _hoisted_level_pallas(bins, onehot, pos, gh, ptab, *, K, Kp, B, d,
-                          tr=TR_HOIST, vma=()):
+                          tr=TR_HOIST, vma=(), sub=False):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -587,6 +638,8 @@ def _hoisted_level_pallas(bins, onehot, pos, gh, ptab, *, K, Kp, B, d,
     prev_offset = (1 << (d - 1)) - 1 if d > 0 else 0
     offset = (1 << d) - 1
     W = ptab.shape[1]
+    Kc = Kp if sub else K  # nodes built: one child of every parent
+    built, built_specs = _built_children(ptab, Kp=Kp, d=d, sub=sub)
     kern = functools.partial(
         _hoisted_kernel, K=K, Kp=Kp, F=F, Fh=Fh, B=B,
         prev_offset=prev_offset, offset=offset,
@@ -601,20 +654,20 @@ def _hoisted_level_pallas(bins, onehot, pos, gh, ptab, *, K, Kp, B, d,
             pl.BlockSpec((tr, 2), lambda c: (c, 0), memory_space=pltpu.VMEM),
             pl.BlockSpec((max(Kp, 1), W), lambda c: (0, 0),
                          memory_space=pltpu.VMEM),
-        ],
+        ] + built_specs,
         out_specs=[
             pl.BlockSpec((tr, 1), lambda c: (c, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((2 * K, Q), lambda c: (0, 0),
+            pl.BlockSpec((2 * Kc, Q), lambda c: (0, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
             _vma_struct((n, 1), jnp.int32, vma),
-            _vma_struct((2 * K, Q), jnp.float32, vma),
+            _vma_struct((2 * Kc, Q), jnp.float32, vma),
         ],
         interpret=_INTERPRET,
-    )(bins, onehot, pos, gh, ptab)
-    # [2K, F*B] -> the dispatcher contract [F, 2K, B]
-    hist = jnp.transpose(hist2.reshape(2 * K, F, B), (1, 0, 2))
+    )(bins, onehot, pos, gh, ptab, *built)
+    # [2Kc, F*B] -> the dispatcher contract [F, 2Kc, B]
+    hist = jnp.transpose(hist2.reshape(2 * Kc, F, B), (1, 0, 2))
     return pos_new, hist
 
 
@@ -804,9 +857,32 @@ def pallas_route_fits(rows: int, F: int, Kp: int, W: int) -> bool:
             and step <= _VMEM_HOIST_BUDGET)
 
 
+def derive_siblings(parent_hist, built, ptab):
+    """The level's full histogram ``[F, 2K, B]`` from the previous level's
+    ``parent_hist`` ``[F, 2Kp, B]`` and the ``built`` children's
+    ``[F, 2Kp, B]`` (``fused_level`` under ``sibling_sub``): the sibling of
+    a built child is ``parent - built`` in f32, zero where the parent did
+    not split (it has no children and no row at this level; unmasked, its
+    histogram would come back as a child). ``ptab`` is the parents'
+    decision table: column 0 says which child was built
+    (``_partition_tile``). The reference's SubtractionTrick
+    (``updater_gpu_hist.cu``), with the smaller child built."""
+    F, Kp2, B = built.shape
+    Kp = Kp2 // 2
+    mark = ptab[:, 0][None, None, :, None]  # over [F, (g, h), Kp, B]
+    built = built.reshape(F, 2, Kp, B)
+    derived = jnp.where(mark > 0.5,
+                        parent_hist.reshape(F, 2, Kp, B) - built, 0.0)
+    built_right = mark > 1.5
+    children = jnp.stack([jnp.where(built_right, derived, built),
+                          jnp.where(built_right, built, derived)], axis=3)
+    return children.reshape(F, 4 * Kp, B)  # node 2*lp + side, g rows first
+
+
 def fused_level(bins, pos, gh, ptab, *, K, Kp, B, d, pallas: bool,
                 onehot: Optional[jax.Array] = None,
-                axis_name: Optional[str] = None):
+                axis_name: Optional[str] = None,
+                sibling_sub: bool = False):
     """Dispatch: (new pos [n,1] i32, hist [F, 2K, B] f32). ``hist`` excludes
     the missing bin (derive per-feature missing sums as total - sum).
     The impl is resolved through the kernel dispatch registry
@@ -814,14 +890,22 @@ def fused_level(bins, pos, gh, ptab, *, K, Kp, B, d, pallas: bool,
     platform preference in one lookup). ``onehot`` (the HBM-resident
     [n, F*B] int8 expansion) selects the streaming kernel inside the
     pallas impl; deep levels whose accumulators outgrow VMEM fall back to
-    the in-kernel construction, then to native/XLA."""
+    the in-kernel construction, then to native/XLA.
+
+    ``sibling_sub`` (a caller that holds the previous level's histogram and
+    whose ``ptab`` marks a child of every split, ``d >= 1``): the pallas
+    impl builds that child alone and ``hist`` is ``[F, 2Kp, B]``, for
+    ``derive_siblings``; its VMEM gates see ``Kp`` nodes, so a deep level's
+    row tile is the one the level above has. Every other impl builds the
+    level directly, whatever the flag: the caller tells by the shape."""
     from ..dispatch import Ctx, resolve
 
     n, F = bins.shape
+    Kc = Kp if sibling_sub else K  # the nodes a pallas impl would build
     dec = resolve("level_hist", Ctx(
         platform=jax.default_backend(), pallas=bool(pallas),
         interpret=bool(_INTERPRET), rows=int(n), features=int(F),
-        nodes=int(K), bins=int(B), table_width=int(ptab.shape[-1]),
+        nodes=int(Kc), bins=int(B), table_width=int(ptab.shape[-1]),
         bins_dtype=str(bins.dtype), sharded=axis_name is not None,
         onehot_width=0 if onehot is None else int(onehot.shape[1])))
     vma = (axis_name,) if axis_name is not None else ()
@@ -832,15 +916,15 @@ def fused_level(bins, pos, gh, ptab, *, K, Kp, B, d, pallas: bool,
             # uniformly varying, so relax it — a no-op on device
             ptab = jax.lax.pcast(ptab, (axis_name,), to="varying")
         if onehot is not None:
-            tr = _hoist_tr(onehot.shape[1], K, F, B)
+            tr = _hoist_tr(onehot.shape[1], Kc, F, B)
             if tr and n % tr == 0:
                 return _hoisted_level_pallas(bins, onehot, pos, gh, ptab,
                                              K=K, Kp=Kp, B=B, d=d, tr=tr,
-                                             vma=vma)
+                                             vma=vma, sub=sibling_sub)
         # reaching here means pallas_level_fits passed via the in-kernel
         # construction gates, so the plain kernel is safe
         return _fused_level_pallas(bins, pos, gh, ptab, K=K, Kp=Kp, B=B,
-                                   d=d, vma=vma)
+                                   d=d, vma=vma, sub=sibling_sub)
     if dec.impl == "native":
         return fused_level_native(bins, pos, gh, ptab, K=K, Kp=Kp, B=B, d=d)
     return fused_level_xla(bins, pos, gh, ptab, K=K, Kp=Kp, B=B, d=d)
