@@ -4,11 +4,11 @@
 One process drives the main path once through the entry points a user
 calls — ``xgb.train(tree_method="tpu_hist")`` -> ``Booster.predict`` /
 ``inplace_predict`` -> ``ModelServer`` — at the full width of the anchor
-configuration (1M x 50 dense, ``binary:logistic``, depth 6; ``bench.py``
-``_make_data``, seed 42), and checks what comes out by the repo's own
-means: compiled Pallas kernels against ``fused_level_xla``, holdout AUC,
-routed-vs-XLA training, predict/serve parity against the plain gather
-walk. Any exception or failed check ends the run non-zero; there is no
+configuration (1M x 50 dense, ``binary:logistic``, depth 6; the rows of
+``benchmark/generators/linear_logit.py``, seed 42), and checks what comes
+out by the repo's own means: compiled Pallas kernels against
+``fused_level_xla``, holdout AUC, routed-vs-XLA training, predict/serve
+parity against the plain gather walk. Any exception or failed check ends the run non-zero; there is no
 path that runs off the chip. The last line of stdout is one JSON object
 ``{"ok": true, "device": {...}}``.
 
@@ -79,8 +79,9 @@ FULL = Sizes(rows=1_000_000, cols=50, kernel_rows=65_536, rounds=20,
              forest_trees=500, predict_rows=100_000,
              serve_sizes=(1, 16, 256, 4096), serve_requests=8,
              mesh_rounds=10,
-             # floors set from this PR's chip run (CHANGES.md, PR 21):
-             # the AUC observed there, less 0.015
+             # floors set from PR 21's chip run on the older rows (the AUC
+             # observed there, less 0.015); the generator's rows read
+             # 0.8612 and 0.7687 on the chip (CHANGES.md, PR 28)
              auc_floor64=0.84, auc_floor256=0.76)
 TINY = Sizes(rows=4096, cols=8, kernel_rows=1024, rounds=3, rounds256=2,
              sub_rows=2048, forest_rows=1024, forest_trees=12,
@@ -185,6 +186,19 @@ def _timed(fn):
     t0 = time.perf_counter()
     out = _sync(fn())
     return out, time.perf_counter() - t0
+
+
+def anchor_data(rows: int, cols: int, seed: int):
+    """(X, y) from the anchor configuration's generator, the file the
+    benchmark's cells load (its default ``law_seed`` is the anchor's)."""
+    import importlib.util
+
+    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "benchmark", "generators", "linear_logit.py")
+    spec = importlib.util.spec_from_file_location("linear_logit", path)
+    gen = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(gen)
+    return gen.generate(rows=rows, cols=cols, seed=seed)
 
 
 def _auc(bst, dmat) -> float:
@@ -810,11 +824,8 @@ def main(argv=None) -> int:
     # (a model loaded from its file is served by ``loaded_walk`` instead:
     # stage_serve says why)
 
-    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-    from bench import _make_data  # the anchor's generator
-
     t0 = time.perf_counter()
-    X, y = _make_data(sz.rows, sz.cols, 0.0, seed=SEED)
+    X, y = anchor_data(sz.rows, sz.cols, SEED)
     say(f"anchor data {sz.rows}x{sz.cols} (seed {SEED}): "
         f"{time.perf_counter() - t0:.2f}s")
 

@@ -65,7 +65,7 @@ echo "$REPORT_OUT" | grep -E -q "tree_grow\s+->\s+native" || {
   exit 1; }
 # the quantized histogram core (ISSUE 19) must win the accumulation
 # route on CPU — hist_acc falling back to float silently forfeits the
-# BENCH_r19 grow floor the same way a tree_grow fall-back would
+# quantized core's speed on CPU the same way a tree_grow fall-back would
 echo "$REPORT_OUT" | grep -E -q "hist_acc\s+->\s+quant" || {
   echo "hist_acc does not resolve to the quantized core on CPU"
   exit 1; }
@@ -91,15 +91,6 @@ for lib in loaded:
     assert state == 1, f"native_canary_state{{lib={lib!r}}} = {state} != 1"
 print(f"canary OK: {len(loaded)} native libraries proven healthy")
 EOF
-
-echo "=== tier 0.75: perf regression gate (envelope + seeded self-test) ==="
-# A fixed-shape smoke bench vs the checked-in envelope with an explicit
-# 35% noise band (ISSUE 16): the lane fails on a silent rounds/s
-# regression BEFORE the functional tiers spend their minutes, and the
-# seeded 2x-slowdown self-test proves on every run that the gate still
-# has teeth (a gate that cannot trip is a dead rule — same rationale as
-# the tier-0 lint self-check). One process: the model compiles once.
-python scripts/perf_gate.py --check --self-test
 
 echo "=== tier 1: full suite (8-device virtual mesh, traced) ==="
 TRACE_OUT=$(mktemp /tmp/xgbtpu_ci_trace.XXXXXX.json)
@@ -375,81 +366,6 @@ print(f"data-plane chaos OK: {len(plan.fired)} faults absorbed off-thread, "
       f"prefetch_wait={stages['prefetch_wait']*1e3:.1f}ms, "
       f"routes sketch_cuts={sk.impl} "
       f"bin_matrix={routes.get('bin_matrix')}, verified resume bit-identical")
-EOF
-
-# Intra-round grow attribution (ISSUE 16; single-dispatch rounds ISSUE
-# 17): a bench-shaped training (100k x 50, depth 6, bin 64) with the
-# kernel profiler sampling rounds 2 and 4. On CPU the production round
-# is now ONE native tree_grow dispatch; the sampled rounds replay it
-# per-level (sibling-sub FFI entry at d >= 1), so the grow_detail
-# records must still attribute every level to a level_hist bucket, carry
-# the replayed route, and the per-depth x per-op substage walls must sum
-# to within 10% of the round's stages.grow (the measurement contract of
-# docs/perf.md — stages.grow on a sampled round times the replay
-# itself). The records must parse out of the durable flight sink
-# (torn-record tolerant reader), the host-sync count must be on the
-# record, and `grow-report` (and its --diff view) must render from the
-# run dir. Unsampled rounds carry no grow_detail — the profiler is
-# scoped.
-XGBTPU_KERNEL_PROF=rounds=2,4 python - <<'EOF'
-import os, tempfile
-
-import numpy as np
-
-import xgboost_tpu as xgb
-from xgboost_tpu.observability import flight
-from xgboost_tpu.observability.kernelprof import _iter_flight_lines
-
-run_dir = tempfile.mkdtemp(prefix="ci_growprof_")
-flight.configure(run_dir)
-rng = np.random.RandomState(0)
-X = rng.rand(100_000, 50).astype(np.float32)
-y = (X[:, 0] + 0.25 * rng.rand(100_000) > 0.625).astype(np.float32)
-bst = xgb.train({"objective": "binary:logistic", "max_depth": 6,
-                 "max_bin": 64, "verbosity": 0},
-                xgb.DMatrix(X, label=y), 6, verbose_eval=False)
-assert bst.num_boosted_rounds() == 6
-
-path = os.path.join(run_dir, "obs", "rank0", "flight.jsonl")
-rounds = [r for r in _iter_flight_lines(path) if r.get("t") == "round"]
-sampled = {r["round"]: r for r in rounds if "grow_detail" in r}
-assert set(sampled) == {2, 4}, f"sampled rounds wrong: {sorted(sampled)}"
-for i, rec in sorted(sampled.items()):
-    gd = rec["grow_detail"]
-    grow = rec["stages"]["grow"]
-    # coverage = the table's wall column PLUS its gap column: sibling
-    # subtraction shrank the real dispatch walls enough that the
-    # mirror's fixed inter-dispatch Python cost — which the table
-    # records explicitly as gaps — is a visible share of a steady-state
-    # round, so the 10% contract is on everything the table attributes
-    sub = sum(o["wall_s"] for o in gd["ops"]) + gd["gap_s"]
-    assert abs(sub - grow) <= 0.10 * grow, \
-        f"round {i}: substages+gaps {sub:.3f}s vs stages.grow " \
-        f"{grow:.3f}s ({sub / grow:.1%}) — outside the 10% contract"
-    depths = {o["depth"] for o in gd["ops"] if o["op"] == "level_hist"}
-    assert depths == set(range(6)), f"round {i}: levels missing: {depths}"
-    assert gd["host_syncs"] >= len(gd["ops"]), gd
-    assert all(o.get("impl") for o in gd["ops"]), gd["ops"]
-    # ISSUE 17: this shape is inside the whole-tree kernel's envelope on
-    # CPU — the record must say so, and say the replay used subtraction
-    assert gd["route"] == "tree_grow", gd
-    assert gd["sibling_sub"] is True, gd
-    # ISSUE 19: the quant route won on CPU, the record attributes it and
-    # carries the round's quantiser exponents (the replay rescales with
-    # the SAME grid, so a missing/null scale means the mirror ran float)
-    assert gd["hist_acc"] == "quant", gd
-    qs = gd.get("quant_scales")
-    assert qs and set(qs) == {"g_exp", "h_exp"}, gd
-    assert all(isinstance(v, int) for v in qs.values()), qs
-print("grow attribution OK: rounds 2,4 sampled, substage sums within "
-      "10% of stages.grow, all 6 levels attributed, route=tree_grow "
-      "replayed with sibling subtraction on the quant accumulation route")
-
-from xgboost_tpu.cli import cli_main
-rc = cli_main(["grow-report", run_dir])
-assert rc == 0, f"grow-report failed (rc={rc})"
-rc = cli_main(["grow-report", "--diff", run_dir, run_dir, "--round", "2"])
-assert rc == 0, f"grow-report --diff failed (rc={rc})"
 EOF
 
 echo "=== tier 1.6: elastic chaos lane (seeded worker_kill + obs-report) ==="

@@ -27,7 +27,8 @@ except Exception:  # backends already initialized; tests will use what exists
 # XLA:CPU's AOT cache loading is machine-feature-sensitive (observed:
 # "+prefer-no-scatter not supported on the host machine" warnings followed
 # by a SIGSEGV inside backend_compile_and_load when reloading entries).
-# The TPU bench keeps its own cache (bench.py) where this path is safe.
+# TPU entry points keep their own cache (config.enable_compile_cache),
+# where this path is safe.
 
 # NOTE on full-suite stability: running every test file in ONE process
 # occasionally segfaults inside XLA:CPU's backend_compile_and_load (LLVM

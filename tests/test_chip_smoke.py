@@ -99,3 +99,32 @@ def test_compile_cache_stays_off_on_the_cpu_backend(monkeypatch):
 
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     assert config.enable_compile_cache() is None
+
+
+def test_anchor_data_is_the_benchmark_generators_byte_for_byte(monkeypatch):
+    """The smoke trains on the rows the benchmark's anchor cell trains on:
+    ``chip_smoke.anchor_data`` is ``benchmark/generators/linear_logit.py``
+    for the same seed, under the anchor configuration's own
+    ``generator_params`` (ISSUE 28: it used to import ``bench._make_data``)."""
+    import importlib.util
+
+    import numpy as np
+
+    monkeypatch.syspath_prepend(REPO)
+    import chip_smoke as smoke
+
+    spec = importlib.util.spec_from_file_location(
+        "linear_logit_under_test",
+        os.path.join(REPO, "benchmark", "generators", "linear_logit.py"))
+    gen = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(gen)
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "anchor-1mx50.json")) as f:
+        data = json.load(f)["data"]
+    assert data["generator"] == "linear_logit"
+    for seed in (smoke.SEED, 7):
+        X, y = smoke.anchor_data(4096, data["cols"], seed)
+        Xg, yg = gen.generate(rows=4096, cols=data["cols"], seed=seed,
+                              **data["generator_params"])
+        assert X.dtype == np.float32 and X.shape == (4096, data["cols"])
+        assert X.tobytes() == Xg.tobytes() and y.tobytes() == yg.tobytes()

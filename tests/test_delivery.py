@@ -340,6 +340,11 @@ def test_shadow_canary_gate_rejects_bad_model(setup, tmp_path):
             # the (regressed) re-train lands while traffic flows
             ckpt.save_checkpoint(watch, bad, 9)
             assert _wait(lambda: ctl.status()["history"])
+            # the controller records the rejection BEFORE it discards the
+            # candidate and tells the fleet (delivery._deliver): wait for
+            # the last of those steps, not for the first
+            assert _wait(lambda: any(m["op"] == "unload"
+                                     for m in list(fleet_msgs)))
         st = ctl.status()
         assert st["history"][-1]["outcome"] == "rejected"
         assert "auc" in st["history"][-1]["detail"]["reasons"]

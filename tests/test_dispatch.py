@@ -293,3 +293,21 @@ def test_serving_route_forced_vs_default(model_cache=[]):
     finally:
         os.environ.pop("XGBTPU_DISPATCH")
     np.testing.assert_allclose(forced, default, atol=1e-5)
+
+
+def test_public_surface_is_what_the_docstring_lists():
+    """``dispatch.__all__`` is exactly the names the package docstring
+    lists, each of them is there, and the invocation seam that only the
+    mirror grower called (ISSUE 28) is not."""
+    import re
+
+    listed = set(re.findall(r":(?:func|class|data):`(\w+)`",
+                            dispatch.__doc__))
+    assert listed == set(dispatch.__all__), (
+        sorted(listed ^ set(dispatch.__all__)))
+    assert len(dispatch.__all__) == len(set(dispatch.__all__))
+    assert all(hasattr(dispatch, n) for n in dispatch.__all__)
+    from xgboost_tpu.dispatch import core
+
+    assert set(core.__all__) == set(dispatch.__all__)
+    assert not [n for n in dir(dispatch) + dir(core) if "invoke" in n.lower()]

@@ -308,21 +308,32 @@ def test_abandoned_future_skipped_at_dispatch_assembly(model):
     try:
         srv.load("m", bst)
         a0 = _counter("serving_requests_total", outcome="abandoned")
-        # cancel a just-submitted future before the worker claims it (the
-        # ISSUE 15 idle fast-path dispatches a fully-assembled batch
-        # immediately, so the old hold-the-window setup is gone; the GIL
-        # makes an instant cancel win in practice — retry the rare loss)
-        for _ in range(5):
+        # cancel a submitted future before the worker claims it. The
+        # ISSUE 15 idle fast-path dispatches a fully-assembled batch at
+        # once, so the worker is held at the door of ``_run_batch`` (where
+        # assembly claims each future) until the cancel has landed: on a
+        # loaded box a bare cancel lost the claim race five times running
+        gate = threading.Event()
+        run_batch = srv.batcher._run_batch
+
+        def held(batch, gen):
+            assert gate.wait(60), "the test never opened the gate"
+            return run_batch(batch, gen)
+
+        srv.batcher._run_batch = held
+        try:
             f1 = srv.predict_async("m", X[:1])
-            cancelled = f1.cancel()
-            if cancelled:
-                break
-            f1.result(60)  # lost the race: it dispatched — drain, retry
-        assert cancelled, "cancel never won the claim race"
+            assert f1.cancel(), "a pending future refused the cancel"
+        finally:
+            gate.set()
+            srv.batcher._run_batch = run_batch
         f2 = srv.predict_async("m", X[1:3])
         np.testing.assert_array_equal(
             f2.result(60), np.asarray(bst.inplace_predict(X[1:3])))
         assert f1.cancelled()
+        # the outcome is counted by the recorder's writer thread; drain()
+        # is the readers' barrier (serving/obs.py)
+        assert srv.obs.drain()
         assert _counter("serving_requests_total",
                         outcome="abandoned") == a0 + 1
         # the abandoned request's model pin was released

@@ -380,9 +380,8 @@ def test_trace_report_span_categories(tmp_path, capsys):
 
 def test_serving_obs_overhead_at_most_2pct(model, tmp_path, monkeypatch):
     """Acceptance: tracing a request costs ≤ 2% of its latency at the
-    bench concurrent-serving shape (client threads x ragged small
-    batches through the micro-batcher, batch_wait 500us — the
-    ``bench.py _served_bench`` stage scaled down). Measured the PR-6
+    concurrent-serving shape (client threads x ragged small
+    batches through the micro-batcher, batch_wait 500us). Measured the PR-6
     way — the direct cost of one full record cycle (start -> stage
     stamps -> finish with the access log and span emission live)
     against the median request latency of a real served run — instead

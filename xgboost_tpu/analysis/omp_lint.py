@@ -1,8 +1,8 @@
 """OMP7xx: OpenMP float-determinism lint over the native TUs.
 
 The native kernels promise "bit-identical regardless of thread count"
-(tree_build.cpp's contract comment; the sibling-sub pins, kernelprof
-replay and canonical-cuts manifest all assume it). The only OpenMP
+(tree_build.cpp's contract comment; the sibling-sub pins, the
+per-level replay and canonical-cuts manifest all assume it). The only OpenMP
 shapes compatible with that promise are disjoint-slab ``parallel for``
 loops — every float write lands in a slab addressed through the loop
 induction variable (or a body-local derived from it), so the result is

@@ -8,8 +8,6 @@ key=value config parser (``src/common/config.h``). Usage:
     python -m xgboost_tpu trace-report <trace-file|glob> ... [--top N]
     python -m xgboost_tpu obs-report <run_dir> ... [--top-rounds N]
     python -m xgboost_tpu serve-report <run_dir> ... [--top N]
-    python -m xgboost_tpu perf-report [--root DIR] [--json]
-    python -m xgboost_tpu grow-report <flight.jsonl|run-dir> [--round N]
     python -m xgboost_tpu checkpoint-inspect <dir> [--json]
     python -m xgboost_tpu serve (--port N | --stdin) [--model name=path ...]
         [--deliver name=watch_dir ...] [--run-dir D] [--manifest F]
@@ -38,13 +36,6 @@ per-tenant rollup (docs/serving.md "Scaling out"). ``serve-fleet`` runs
 that fleet: N supervised crash-only ``serve`` replicas sharing one
 manifest behind the consistent-hash routing front
 (``serving/fleet/``).
-``perf-report`` renders the banked perf trajectory (every
-``BENCH_r*.json`` at the repo root: rounds/s, stage splits, vs_baseline,
-delta vs banked best — ``observability/ledger.py``, docs/perf.md
-"Banking a round"). ``grow-report`` renders sampled rounds' per-depth ×
-per-op ``grow_detail`` records from a flight sink
-(``observability/kernelprof.py``, docs/observability.md "Inside the
-grow stage").
 ``dispatch-report`` prints the fully-resolved kernel dispatch table
 (op × impl × reason: preferred/pinned/degraded/unavailable) for the
 current platform, including any ``XGBTPU_DISPATCH`` pins and legacy
@@ -128,14 +119,6 @@ def cli_main(argv: List[str]) -> int:
         from .observability.serve_report import main as serve_report_main
 
         return serve_report_main(argv[1:])
-    if argv[0] == "perf-report":
-        from .observability.ledger import main as ledger_main
-
-        return ledger_main(argv[1:])
-    if argv[0] == "grow-report":
-        from .observability.kernelprof import main as kernelprof_main
-
-        return kernelprof_main(argv[1:])
     if argv[0] == "lint":
         from .analysis.cli import main as lint_main
 

@@ -2,13 +2,16 @@
 
 Public surface (see ``core.py`` for the design notes):
 
-- :func:`resolve` / :class:`Ctx` / :class:`Decision` — the lookup.
-- :func:`register` — add an impl (a GPU backend is a table entry).
-- :func:`pinned_off` / :func:`degraded` — compat/admission reads.
-- :func:`invoke` / :func:`set_invoke_hook` — the invocation seam the
-  kernel profiler brackets (``observability/kernelprof.py``).
-- :func:`explain` / :func:`last_decisions` / :func:`table_snapshot` —
-  the report CLI, BENCH sidecar and flight-black-box surfaces.
+- :func:`resolve` / :class:`Ctx` / :class:`Decision` /
+  :class:`DispatchError` — the lookup.
+- :func:`register` / :class:`KernelImpl` — add an impl (a GPU backend is
+  a table entry).
+- :func:`pinned_off` / :func:`degraded` / :data:`LEGACY_ENVS` —
+  compat/admission reads and the old kill-switch grammar.
+- :func:`explain` / :func:`last_decisions` / :func:`table_snapshot` /
+  :func:`op_names` / :func:`set_report_ctx` — the report CLI, the
+  benchmark's printed routes and the flight black box.
+- :func:`reset` — drop cached decisions and route history (tests).
 """
 
 from .core import (  # noqa: F401
@@ -19,21 +22,19 @@ from .core import (  # noqa: F401
     LEGACY_ENVS,
     degraded,
     explain,
-    invoke,
     last_decisions,
     op_names,
     pinned_off,
     register,
     reset,
     resolve,
-    set_invoke_hook,
     set_report_ctx,
     table_snapshot,
 )
 
 __all__ = [
     "Ctx", "Decision", "DispatchError", "KernelImpl", "LEGACY_ENVS",
-    "degraded", "explain", "invoke", "last_decisions", "op_names",
-    "pinned_off", "register", "reset", "resolve", "set_invoke_hook",
-    "set_report_ctx", "table_snapshot",
+    "degraded", "explain", "last_decisions", "op_names",
+    "pinned_off", "register", "reset", "resolve", "set_report_ctx",
+    "table_snapshot",
 ]

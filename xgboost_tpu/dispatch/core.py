@@ -57,7 +57,6 @@ __all__ = [
     "Ctx", "Decision", "DispatchError", "KernelImpl",
     "register", "set_report_ctx", "resolve", "explain", "op_names",
     "pinned_off", "degraded", "last_decisions", "table_snapshot",
-    "invoke", "set_invoke_hook",
     "reset", "LEGACY_ENVS",
 ]
 
@@ -481,47 +480,7 @@ def _announce_route_change(op: str, frm: str, dec: Decision) -> None:
 
 
 # ---------------------------------------------------------------------------
-# invocation seam (kernel profiler)
-# ---------------------------------------------------------------------------
-
-#: per-thread invocation hook — thread-local so an armed profiler on the
-#: training thread never observes a serving thread's dispatches (and
-#: vice versa), and clearing is just restoring the previous value
-_INVOKE_TLS = threading.local()
-
-
-def set_invoke_hook(
-        hook: Optional[Callable[[str, Callable[..., Any], tuple, dict],
-                                Any]]) -> Optional[Callable]:
-    """Install THIS THREAD's invocation hook (``None`` clears) and return
-    the previous one, so callers can restore it in a ``finally``. The
-    hook receives ``(op, fn, args, kwargs)`` and must call
-    ``fn(*args, **kwargs)`` itself — it owns the bracket around the
-    dispatch, which is exactly what the kernel profiler needs to time
-    host-blocked vs in-flight work and count deliberate completion syncs
-    (``host_syncs_total{site=op}``) at ONE seam for every impl
-    (pallas / XLA / native) instead of per call site."""
-    prev = getattr(_INVOKE_TLS, "hook", None)
-    _INVOKE_TLS.hook = hook
-    return prev
-
-
-def invoke(op: str, fn: Callable[..., Any], *args: Any, **kwargs: Any) -> Any:
-    """Run ``fn(*args, **kwargs)`` — the resolved implementation of
-    ``op`` — through the invocation seam. With no hook installed this is
-    a plain call (one thread-local read of overhead); with a hook (a
-    kernel-profiled round) the hook brackets the call. The sync points a
-    hook may add live HERE, outside the round-loop files the RH204 lint
-    statically walks — the lint stays sound for production rounds
-    because unprofiled rounds never reach a hook."""
-    hook = getattr(_INVOKE_TLS, "hook", None)
-    if hook is None:
-        return fn(*args, **kwargs)
-    return hook(op, fn, args, kwargs)
-
-
-# ---------------------------------------------------------------------------
-# introspection (report CLI, flight black box, BENCH sidecar)
+# introspection (report CLI, flight black box, the benchmark's printed routes)
 # ---------------------------------------------------------------------------
 
 
@@ -565,8 +524,8 @@ def explain(op: str, ctx: Optional[Ctx] = None) -> List[Dict[str, str]]:
 
 
 def last_decisions() -> Dict[str, str]:
-    """op -> most recently chosen impl (this process). The BENCH JSONL
-    line embeds this so perf deltas are attributable to routing."""
+    """op -> most recently chosen impl (this process). The benchmark
+    prints this so perf deltas are attributable to routing."""
     with _STATE.lock:
         return {op: dec.impl for op, dec in sorted(_STATE.last.items())}
 

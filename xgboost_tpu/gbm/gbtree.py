@@ -19,7 +19,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..observability import REGISTRY as _REGISTRY, trace as _trace
-from ..observability import kernelprof as _kernelprof
 from ..params import GBTreeParam, TrainParam
 from ..predictor import StackedForest, predict_leaf, predict_margin, stack_forest
 from ..registry import BOOSTERS
@@ -1443,13 +1442,6 @@ class GBTree:
                     # re-pass the same slice, so each call needs its own
                     # buffer to give up
                     h = jnp.copy(h)
-                if _kernelprof.active():
-                    # sampled round: the host-driven instrumented mirror
-                    # (bit-identical — pinned by tests/test_kernelprof.py)
-                    return _kernelprof.grow_tree_fused_profiled(
-                        binsf, g, h, cut_vals, key,
-                        float(tp.eta), float(tp.gamma), cfg, fw, onehot,
-                    )
                 return grow_tree_fused(
                     binsf, g, h, cut_vals, key,
                     float(tp.eta), float(tp.gamma), cfg, fw, onehot,

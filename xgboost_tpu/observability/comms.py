@@ -20,8 +20,8 @@ Metric families (in ``observability.metrics.REGISTRY``):
 - ``collective_ops_total{op=...}``   — logical collective operations
 - ``collective_bytes_total{op=...}`` — payload bytes reduced / gathered
 
-``snapshot()`` returns ``{op: {"ops": n, "bytes": b}}`` for BENCH /
-MULTICHIP result files.
+``snapshot()`` returns ``{op: {"ops": n, "bytes": b}}`` for result
+files.
 """
 
 from __future__ import annotations

@@ -238,19 +238,6 @@ class FlightRecorder:
                 st = self._open["stages"]
                 st[stage] = st.get(stage, 0.0) + seconds
 
-    def annotate(self, key: str, value: Any) -> None:
-        """Attach a structured sub-record to the OPEN round record under
-        ``key`` — e.g. the kernel profiler's ``grow_detail`` (per-depth ×
-        per-op attribution for a sampled round). ``value`` must be
-        JSON-serializable; a repeat annotation of the same key within one
-        round overwrites; with no open round the call is dropped (the
-        profiler can outlive a round aborted mid-update)."""
-        if not _enabled():
-            return
-        with self._lock:
-            if self._open is not None:
-                self._open[key] = value
-
     def end_round(self) -> Optional[Dict[str, Any]]:
         if not _enabled():
             return None

@@ -13,8 +13,8 @@ adapter), collective-comms volume (``collective_bytes_total`` — see
 
 - ``REGISTRY.exposition()`` — Prometheus text exposition format, ready to
   serve from a ``/metrics`` endpoint or drop into a textfile collector;
-- ``REGISTRY.snapshot()`` — a JSON-able dict for BENCH/MULTICHIP result
-  files and programmatic assertions.
+- ``REGISTRY.snapshot()`` — a JSON-able dict for result files and
+  programmatic assertions.
 
 Family/child creation is lock-guarded; value updates are plain float ops
 (a counter bump may race across threads at worst by one sample — the
@@ -263,7 +263,7 @@ class MetricsRegistry:
         return out
 
     def reset(self) -> None:
-        """Drop every family (tests / between BENCH repetitions)."""
+        """Drop every family (tests / between repetitions of a run)."""
         with self._lock:
             self._families.clear()
 

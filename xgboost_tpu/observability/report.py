@@ -110,23 +110,12 @@ def summarize(events: Iterable[Dict[str, Any]]) -> Dict[str, Any]:
     inst_counts: Dict[str, int] = defaultdict(int)
     for ev in instants:
         inst_counts[ev["name"]] += 1
-    # grow breakdown: the kernel profiler's cat="grow" substage spans
-    # (observability/kernelprof.py), keyed per op name — the per-category
-    # line says how much grow detail exists, this says where it went
-    per_grow: Dict[str, Dict[str, float]] = {}
-    for ev in complete:
-        if _category(ev) != "grow":
-            continue
-        g = per_grow.setdefault(ev["name"], {"count": 0, "total_us": 0.0})
-        g["count"] += 1
-        g["total_us"] += ev["dur"]
     return {
         "n_events": len(events),
         "n_spans": len(complete),
         "spans": per_name,
         "ranks": per_rank,
         "categories": per_cat,
-        "grow": per_grow,
         "instants": dict(inst_counts),
     }
 
@@ -147,13 +136,6 @@ def format_report(summary: Dict[str, Any], top: int = 20) -> str:
                 f"{cat} {_ms(c['total_us'])} ({c['count']} spans)"
                 for cat, c in sorted(
                     cats.items(), key=lambda kv: -kv[1]["total_us"])))
-    grow = summary.get("grow") or {}
-    if grow:
-        lines.append("grow breakdown (kernel-profiled substages):")
-        for name, g in sorted(grow.items(),
-                              key=lambda kv: -kv[1]["total_us"]):
-            lines.append(f"  {name:<28} {g['count']:>7} "
-                         f"{_ms(g['total_us']):>12}")
     lines += [
         "",
         f"top spans by self time (top {top}):",

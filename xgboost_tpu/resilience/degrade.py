@@ -244,7 +244,7 @@ def capabilities() -> Dict[str, CapabilityHealth]:
 
 
 def snapshot() -> Dict[str, Any]:
-    """JSON-able view of every capability (BENCH/MULTICHIP sidecars)."""
+    """JSON-able view of every capability (result-file sidecars)."""
     return {name: cap.snapshot() for name, cap in capabilities().items()}
 
 

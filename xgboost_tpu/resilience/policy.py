@@ -12,7 +12,7 @@ Here the classification is explicit and shared by every fallible path:
   permanent poisons a capability.
 - ``RESOURCE``   — the attempt was too big for the machine (HBM OOM,
   ``RESOURCE_EXHAUSTED``). Retrying the same shape is futile; callers
-  shrink (bench ladder) or degrade the capability.
+  shrink the job or degrade the capability.
 - ``PERMANENT``  — this configuration can never work on this runtime
   (Mosaic rejects, scoped-vmem overflow, ``NotImplementedError``).
 
@@ -22,8 +22,8 @@ schedules), an optional wall-clock deadline, and per-site budgets from
 ``XGBTPU_RETRY`` (a bare int, or ``site=N,*=M`` — the same grammar as
 ``XGBTPU_RETRACE_BUDGET``, ``analysis/retrace.py``). Every failure is
 recorded as ``faults_total{site,kind}`` in the metrics registry and every
-retry as ``retries_total{site}``, so BENCH/MULTICHIP snapshots carry the
-full fault history of a run.
+retry as ``retries_total{site}``, so a run's metrics snapshot carries its
+full fault history.
 """
 
 from __future__ import annotations

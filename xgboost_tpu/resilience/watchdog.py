@@ -13,9 +13,9 @@ letting ``train()`` commit a checkpoint and exit with a real error.
 Honest limitation: ``_thread.interrupt_main`` is delivered between Python
 bytecodes. A dispatch wedged inside a C extension that never returns to
 the interpreter cannot be interrupted this way — for that terminal case
-a process-level deadline (``bench.py``'s emit-and-``os._exit`` thread, the
-caller's ``timeout``) remains the backstop. Everything short of that (polling loops, host-side
-retries, collective setup written in Python) aborts cleanly.
+a process-level deadline (the caller's ``timeout``) remains the backstop.
+Everything short of that (polling loops, host-side retries, collective
+setup written in Python) aborts cleanly.
 
 Deadlines come from ``XGBTPU_WATCHDOG`` (bare seconds, or
 ``site=S,*=S`` — the shared env grammar) or the call site's default;

@@ -34,8 +34,7 @@ shipped:
   error rate no worse than the incumbent's (the per-version
   error-budget-burn analog: both arms see the same traffic window, so
   comparing miss rates compares burn), AND a quality gate — held-out
-  AUC through the bench parity-gate machinery
-  (``metric.create_metric("auc")``), candidate no worse than the
+  AUC (``metric.create_metric("auc")``), candidate no worse than the
   incumbent by more than ``XGBTPU_PROMOTE_DAUC`` (improvements always
   pass).
 - **promote** — the existing warm hot-swap (``swap.promote_live``: the
@@ -660,8 +659,8 @@ class DeliveryController:
         return not reasons, detail
 
     def _auc(self, version: int) -> float:
-        """Held-out AUC of one resident version — the bench parity-gate
-        machinery (``create_metric("auc")``) against the controller's
+        """Held-out AUC of one resident version
+        (``create_metric("auc")``) against the controller's
         eval slice, through the same inplace fast path traffic uses."""
         from ..metric import create_metric
 

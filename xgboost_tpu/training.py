@@ -166,7 +166,6 @@ def train(
 
     from .native import boundary as _boundary
     from .observability import flight as _flight
-    from .observability import kernelprof as _kernelprof
     from .observability import trace as _trace
     from .pipeline import RoundPipeline, completion_probe
     from .resilience.policy import RetryPolicy as _RetryPolicy
@@ -269,12 +268,6 @@ def train(
                         break
                     _flight.profile_tick(i)
                     _flight.RECORDER.begin_round(i)
-                    # sampled rounds (XGBTPU_KERNEL_PROF; off by default)
-                    # run the grow dispatch through the instrumented
-                    # driver — per-depth × per-op attribution lands on
-                    # the round record as grow_detail
-                    _kp = (_kernelprof.arm(i)
-                           if _kernelprof.should_sample(i) else None)
                     try:
                         with _trace.span("round", iteration=i):
                             # deadline around the per-round host dispatch
@@ -301,11 +294,6 @@ def train(
                             stop = container.after_iteration(
                                 bst, i, dtrain, evals, feval=feval)
                     finally:
-                        if _kp is not None:
-                            _gd = _kernelprof.disarm()
-                            if _gd is not None:
-                                _flight.RECORDER.annotate("grow_detail",
-                                                          _gd)
                         _flight.RECORDER.end_round()
                     if stop:
                         break

@@ -12,12 +12,12 @@ so their histograms are bit-identical by construction):
 
 * ``xgbtpu_tree_grow`` — the whole-tree kernel (``tree_grow_native``).
 * ``xgbtpu_hb_level_sub`` — ONE level of the same partition + sibling-
-  subtraction machinery (``fused_level_sub_native``), used by the
-  kernelprof mirror so sampled rounds can replay the round per-level for
-  attribution while staying bit-identical to the fused kernel's output.
+  subtraction machinery (``fused_level_sub_native``): one level of the
+  round replayed on its own, bit-identical to the fused kernel's output;
+  ``tests/test_tree_grow.py`` holds the subtraction core to it.
 * ``xgbtpu_hb_level_quant`` — ONE level of the quantized-gradient engine
-  (``fused_level_quant_native``, ISSUE 19): the mirror's level step when
-  the round ran with ``hist_acc=quant``, carrying the previous level's
+  (``fused_level_quant_native``, ISSUE 19): the same replay for a
+  round run with ``hist_acc=quant``, carrying the previous level's
   int64 histogram across calls as packed int32 word pairs (x64 stays
   off; an f32 carry would drop bits past 24-bit sums).
 
@@ -120,10 +120,10 @@ def fused_level_sub_native(bins, pos, gh, ptab, prev_hist, *, K: int,
     """Same contract as ``fused_level_native`` — (new pos [n,1] i32, hist
     [F, 2K, B] f32) — but building only the smaller child of each sibling
     pair and deriving the other as parent − child from ``prev_hist`` (the
-    previous level's [F, 2Kp, B]). Only valid at ``d >= 1``. This is the
-    kernelprof mirror's level step when the round ran the whole-tree
-    kernel with subtraction on: it shares tree_build.cpp's core loops, so
-    the mirrored histogram matches the in-kernel one bit-for-bit."""
+    previous level's [F, 2Kp, B]). Only valid at ``d >= 1``. This is one
+    level of the whole-tree kernel with subtraction on, replayed alone:
+    it shares tree_build.cpp's core loops, so its histogram matches the
+    in-kernel one bit-for-bit (the tests' reference for that core)."""
     from ..native import boundary
 
     n, F = bins.shape
@@ -140,7 +140,7 @@ def fused_level_sub_native(bins, pos, gh, ptab, prev_hist, *, K: int,
 def fused_level_quant_native(bins, pos, gh, ptab, prev_hist_q, *, K: int,
                              Kp: int, B: int, d: int, sibling_sub: bool):
     """ONE level of the quantized-gradient histogram engine (hist_acc =
-    quant), for the kernelprof mirror: quantiser recomputed from the full
+    quant), replayed alone: quantiser recomputed from the full
     ``gh`` (identical to the whole-tree kernel's per-round computation),
     partition, per-node row lists, packed-integer accumulation and (with
     ``sibling_sub``) EXACT integer sibling derivation from
