@@ -350,6 +350,15 @@ def run_cell(cell: dict, *, seed: int, seconds: float, trace: bool,
         out["device"] = device
         if rehearsal:
             out["rehearsal"] = "CPU stand-in: no number here is a chip result"
+        # every number that decided ``correct`` beside its limit: the last
+        # lines of standard error, and the last key of the result line (the
+        # driver's record of a run that is not correct keeps the end of each)
+        out["compared"] = result.get("compared", {})
+        for name, c in out["compared"].items():
+            print(f"{ctx.tag}compared {name}: " + "  ".join(
+                f"{k} {v}" for k, v in c.items()), file=sys.stderr)
+        print(f"{ctx.tag}correct: {out['correct']}", file=sys.stderr,
+              flush=True)
         print(ctx.tag + json.dumps(out), flush=True)
         return 0
     except BenchFailure as e:

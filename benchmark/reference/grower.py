@@ -28,6 +28,17 @@ carries the worse of the two down the tree, so the bound holds whichever
 child the system subtracts; plain bf16 accumulation (2^-8) would be two
 orders outside it.
 
+``min_child_weight`` is a threshold, and a sum a rounding away from it falls
+on either side of it by the precision it was summed in: four rows of
+``h = 0.25 - 4e-7`` (the second round of ``binary:logistic``) hold 0.9999986
+in float64 and may read 1.0 from a float32 histogram taken as parent minus
+sibling. The replay therefore holds the system's split to the threshold
+within ``MCW_RTOL`` of it: a child of the system's split may fall short of
+``min_child_weight`` by that share and no more (the largest shortfall seen
+is reported as ``mcw_short``), and the system need not have found a split
+of the reference's whose child clears the threshold by less than that.
+PERF.md, section 6 (PR 30), has the two readings the limit sits between.
+
 No missing values are handled: the benchmark's generators make none, and
 the replay raises on a NaN rather than guess a default direction.
 """
@@ -63,6 +74,13 @@ U = 2.0 ** -15  # two bf16 terms carry about 16 significand bits an addend
 
 RT_EPS = 1e-6  # XGBoost's kRtEps: a split needs more gain than this
 
+# the share of ``min_child_weight`` by which a child of the system's split
+# may fall short of it. Between its two readings (PERF.md section 6, PR 30):
+# sound runs read 1.5e-6 at most (float32 sums a rounding under the
+# threshold); a grower that drops the threshold, or sums in plain bfloat16,
+# reads 0.2 or more (a whole row short)
+MCW_RTOL = 1e-3
+
 
 def _gain(G, H, lam):
     return G * G / (H + lam)
@@ -97,11 +115,16 @@ def replay_tree(bins, cuts, g, h, tree, *, eta, max_depth, lam=1.0,
     Returns ``(leaf_delta [n], report)``: the margin update the reference
     gives every row, and counts of nodes that matched exactly, tied, or
     mismatched, the leaves above ``max_depth`` it would have split
-    (``ungrown``), and the largest leaf-value difference seen."""
+    (``ungrown``), the largest leaf-value difference seen, the largest share
+    of ``min_child_weight`` by which a child of a system split fell short of
+    it (``mcw_short``) and the ties that only the slack on that threshold
+    allows (``mcw_decided``)."""
     n, F = bins.shape
     B = cuts.shape[1]
     rep = {"nodes": 0, "same": 0, "tie": 0, "mismatch": [], "ungrown": [],
-           "leaves_checked": 0, "leaf_err": 0.0, "leaf_tol_exceeded": []}
+           "leaves_checked": 0, "leaf_err": 0.0, "leaf_tol_exceeded": [],
+           "mcw_short": 0.0, "mcw_decided": 0}
+    mcw_slack = MCW_RTOL * min_child_weight
     delta = np.zeros(n, np.float64)
     all_rows = np.arange(n)
     abs_g = np.abs(g)
@@ -162,20 +185,37 @@ def replay_tree(bins, cuts, g, h, tree, *, eta, max_depth, lam=1.0,
         else:
             b_sys = int(hit[0])
         gain, G, H, GL, HL = _split_gains(bins, rows, g, h, B, lam)
-        gain[(HL < min_child_weight) | (H - HL < min_child_weight)] = -np.inf
-        best = float(gain.max())
-        g_sys = float(gain[f_sys, b_sys])
-        f_ref, b_ref = np.unravel_index(int(gain.argmax()), gain.shape)
-        same_partition = (f_ref == f_sys and np.array_equal(
-            bins[rows, f_sys] <= b_sys, bins[rows, f_sys] <= b_ref))
+        light = np.minimum(HL, H - HL)  # the lighter child of every split
+        strict = np.where(light < min_child_weight, -np.inf, gain)
+        # what no rounding of the sums can take away from the system
+        sure = np.where(light < min_child_weight + mcw_slack, -np.inf, gain)
+        best, best_sure = float(strict.max()), float(sure.max())
+        short = max(0.0, float(min_child_weight - light[f_sys, b_sys]))
+        if min_child_weight > 0:
+            rep["mcw_short"] = max(rep["mcw_short"], short / min_child_weight)
+        g_sys = float(gain[f_sys, b_sys]) if short <= mcw_slack else -np.inf
+        f_ref, b_ref = np.unravel_index(int(strict.argmax()), strict.shape)
+        same_partition = (np.isfinite(best) and f_ref == f_sys
+                          and np.array_equal(bins[rows, f_sys] <= b_sys,
+                                             bins[rows, f_sys] <= b_ref))
         if same_partition:
             rep["same"] += 1
-        elif np.isfinite(g_sys) and best - g_sys <= gain_rtol * abs(best):
+        elif np.isfinite(g_sys) and (not np.isfinite(best_sure) or best_sure
+                                     - g_sys <= gain_rtol * abs(best_sure)):
             rep["tie"] += 1
+            # a tie that only the slack on the threshold allows
+            g_strict = float(strict[f_sys, b_sys])
+            if not (np.isfinite(g_strict)
+                    and best - g_strict <= gain_rtol * abs(best)):
+                rep["mcw_decided"] += 1
         else:
             rep["mismatch"].append(
-                (node, f"system split f={f_sys} b={b_sys} gain {g_sys:.6g}; "
-                       f"reference f={f_ref} b={b_ref} gain {best:.6g}"))
+                (node, f"system split f={f_sys} b={b_sys} gain "
+                       f"{gain[f_sys, b_sys]:.6g}, lighter child "
+                       f"{short:.3g} under min_child_weight (slack "
+                       f"{mcw_slack:.3g}); reference f={f_ref} b={b_ref} "
+                       f"gain {best:.6g} (clear of the threshold: "
+                       f"{best_sure:.6g})"))
         go_left = bins[rows, f_sys] <= b_sys
         rl, rr = rows[go_left], rows[~go_left]
         sl = (U * abs_g[rl].sum(), U * h[rl].sum())
@@ -199,7 +239,8 @@ def replay_forest(X, y, cuts, forest, *, objective, eta, rounds, max_depth,
     groups = forest.num_class
     margin = np.full((len(X), groups), forest.base_margin(), np.float64)
     total = {"nodes": 0, "same": 0, "tie": 0, "mismatch": [], "ungrown": [],
-             "leaves_checked": 0, "leaf_err": 0.0, "leaf_tol_exceeded": []}
+             "leaves_checked": 0, "leaf_err": 0.0, "leaf_tol_exceeded": [],
+             "mcw_short": 0.0, "mcw_decided": 0}
     t = 0
     for _ in range(rounds):
         g, h = gradients(objective, margin, y, groups)
@@ -214,9 +255,11 @@ def replay_forest(X, y, cuts, forest, *, objective, eta, rounds, max_depth,
                                      min_child_weight=min_child_weight,
                                      gamma=gamma)
             new[:, k] += delta
-            for key in ("nodes", "same", "tie", "leaves_checked"):
+            for key in ("nodes", "same", "tie", "leaves_checked",
+                        "mcw_decided"):
                 total[key] += rep[key]
-            total["leaf_err"] = max(total["leaf_err"], rep["leaf_err"])
+            for key in ("leaf_err", "mcw_short"):
+                total[key] = max(total[key], rep[key])
             for key in ("mismatch", "ungrown", "leaf_tol_exceeded"):
                 total[key] += [(t,) + m for m in rep[key]]
             t += 1

@@ -164,7 +164,8 @@ def replay_rounds(X, label, group_ptr, cuts, forest, *, objective, seed,
     bins = grower.bin_rows(X, cuts)
     margin = np.full(len(X), forest.base_margin(), np.float64)
     total = {"nodes": 0, "same": 0, "tie": 0, "mismatch": [], "ungrown": [],
-             "leaves_checked": 0, "leaf_err": 0.0, "leaf_tol_exceeded": []}
+             "leaves_checked": 0, "leaf_err": 0.0, "leaf_tol_exceeded": [],
+             "mcw_short": 0.0, "mcw_decided": 0}
     for t in range(rounds):
         g, h = gradient(objective, margin, label, group_ptr, seed=seed,
                         iteration=t, n_pair=n_pair)
@@ -172,9 +173,10 @@ def replay_rounds(X, label, group_ptr, cuts, forest, *, objective, seed,
             bins, cuts, g, h, forest.trees[t], eta=eta, max_depth=max_depth,
             lam=lam, min_child_weight=min_child_weight, gamma=gamma)
         margin = margin + delta
-        for key in ("nodes", "same", "tie", "leaves_checked"):
+        for key in ("nodes", "same", "tie", "leaves_checked", "mcw_decided"):
             total[key] += rep[key]
-        total["leaf_err"] = max(total["leaf_err"], rep["leaf_err"])
+        for key in ("leaf_err", "mcw_short"):
+            total[key] = max(total[key], rep[key])
         for key in ("mismatch", "ungrown", "leaf_tol_exceeded"):
             total[key] += [(t,) + m for m in rep[key]]
     return margin, total

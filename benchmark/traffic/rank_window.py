@@ -16,7 +16,9 @@ the gradient's and the metric's reference, ``grower.py`` the trees'):
     are taken only up to 2^25 elements of ``queries x largest^2``) are
     replayed by the numpy grower on the system's cuts with the reference's
     gradient: every split the reference's best or a tie, no leaf it would
-    have split, leaf values in the bf16 hi/lo class, margins to 1e-3. A
+    have split, no child short of ``min_child_weight`` by more than
+    ``grower.MCW_RTOL`` of it, leaf values in the bf16 hi/lo class, margins
+    to 1e-3. A
     split's gain grows with the rows under it, so the sample's ``gamma`` is
     the configuration's times the sample's share of the rows: the pruning
     stays live and in proportion, and the replay must cover
@@ -171,7 +173,8 @@ def check_against_grower(ctx, xgb, Xs, ys, sub) -> dict:
            "leaves_checked": rep["leaves_checked"],
            "ungrown": len(rep["ungrown"]), "leaf_err": float(rep["leaf_err"]),
            "leaf_tol_exceeded": len(rep["leaf_tol_exceeded"]),
-           "margin_err": margin_err}
+           "margin_err": margin_err, "mcw_short": float(rep["mcw_short"]),
+           "mcw_decided": rep["mcw_decided"]}
     ctx.say("oracle (numpy grower, same cuts, reference gradient): "
             + str(out))
     for m in (rep["mismatch"][:5] + rep["ungrown"][:5]
@@ -180,8 +183,8 @@ def check_against_grower(ctx, xgb, Xs, ys, sub) -> dict:
     out["ok"] = (not rep["mismatch"] and not rep["ungrown"]
                  and not rep["leaf_tol_exceeded"]
                  and rep["nodes"] >= MIN_NODE_SHARE * full
-                 and rep["tie"] <= 0.05 * rep["nodes"]
-                 and margin_err <= 1e-3)
+                 and rep["tie"] <= train_window.TIE_SHARE_LIMIT * rep["nodes"]
+                 and margin_err <= train_window.MARGIN_LIMIT)
     return out
 
 
@@ -322,6 +325,16 @@ def run(ctx) -> dict:
                and gap["dg"] <= GRAD_LIMIT and gap["dh"] <= GRAD_LIMIT
                and lo <= q <= hi and q0 < lo and ndcg_last > ndcg_first
                and record["rank_layout_builds_in_window"] == 0)
+    compared = dict(
+        train_window.compared_of_oracle(
+            oracle, MIN_NODE_SHARE * oracle["nodes_of_full_trees"]),
+        gradient_dg={"value": gap["dg"], "limit": GRAD_LIMIT},
+        gradient_dh={"value": gap["dh"], "limit": GRAD_LIMIT},
+        holdout_ndcg={"value": q, "limit": [lo, hi]},
+        untrained_ndcg={"value": q0, "limit": lo},
+        train_ndcg_first={"value": ndcg_first, "limit": ndcg_last},
+        layouts_built_in_window={
+            "value": record["rank_layout_builds_in_window"], "limit": 0})
     return {"end_to_end": {"train_rounds_per_s": done / t_last},
             "attempted": done, "failed": 0, "correct": correct,
-            "record": record}
+            "compared": compared, "record": record}

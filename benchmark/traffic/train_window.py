@@ -41,6 +41,8 @@ WARMUP_CHUNKS = 2
 TRACE_CHUNKS = 1
 ORACLE_ROWS = 16_384
 ORACLE_ROUNDS = 3
+TIE_SHARE_LIMIT = 0.05
+MARGIN_LIMIT = 1e-3
 LOSS_ROWS = 65_536
 HOLDOUT_ROWS = 250_000
 
@@ -69,8 +71,9 @@ def check_against_grower(ctx, xgb, X, y) -> dict:
     to the numpy grower given the same cuts: bins equal to
     ``np.searchsorted``; every split the reference's best, or a tie within
     1e-3 of its gain; no leaf above ``max_depth`` that the reference would
-    have split; leaf values inside the bf16 hi/lo class (2^-15 of the sum
-    of |g| they accumulate); margins to 1e-3."""
+    have split; a child of a split short of ``min_child_weight`` by no more
+    than ``grower.MCW_RTOL`` of it; leaf values inside the bf16 hi/lo class
+    (2^-15 of the sum of |g| they accumulate); margins to 1e-3."""
     cfg = ctx.config
     rng = np.random.default_rng(ctx.seed + 1)
     rows = np.sort(rng.choice(len(X), size=min(ORACLE_ROWS, len(X)),
@@ -103,16 +106,34 @@ def check_against_grower(ctx, xgb, X, y) -> dict:
            "leaves_checked": rep["leaves_checked"],
            "ungrown": len(rep["ungrown"]), "leaf_err": float(rep["leaf_err"]),
            "leaf_tol_exceeded": len(rep["leaf_tol_exceeded"]),
-           "margin_err": margin_err}
+           "margin_err": margin_err, "mcw_short": float(rep["mcw_short"]),
+           "mcw_decided": rep["mcw_decided"]}
     ctx.say("oracle (numpy grower, same cuts): " + str(out))
     for m in (rep["mismatch"][:5] + rep["ungrown"][:5]
               + rep["leaf_tol_exceeded"][:5]):
         ctx.say(f"  oracle disagreement: {m}")
     out["ok"] = (not rep["mismatch"] and not rep["ungrown"]
                  and not rep["leaf_tol_exceeded"]
-                 and rep["nodes"] > 0 and rep["tie"] <= 0.05 * rep["nodes"]
-                 and margin_err <= 1e-3)
+                 and rep["nodes"] > 0
+                 and rep["tie"] <= TIE_SHARE_LIMIT * rep["nodes"]
+                 and margin_err <= MARGIN_LIMIT)
     return out
+
+
+def compared_of_oracle(oracle: dict, min_nodes: float = 1) -> dict:
+    """The replay's numbers, each beside its limit, for the result line."""
+    return {
+        "oracle_mismatches": {"value": oracle["mismatches"], "limit": 0},
+        "oracle_ungrown": {"value": oracle["ungrown"], "limit": 0},
+        "oracle_leaf_tol_exceeded": {"value": oracle["leaf_tol_exceeded"],
+                                     "limit": 0},
+        "oracle_mcw_short": {"value": oracle["mcw_short"],
+                             "limit": grower.MCW_RTOL},
+        "oracle_nodes": {"value": oracle["nodes"], "limit_low": min_nodes},
+        "oracle_tie_share": {"value": oracle["ties"] / max(oracle["nodes"], 1),
+                             "limit": TIE_SHARE_LIMIT},
+        "oracle_margin_err": {"value": oracle["margin_err"],
+                              "limit": MARGIN_LIMIT}}
 
 
 def _holdout_quality(ctx, bst, Xh, yh, rounds: int) -> float:
@@ -241,6 +262,10 @@ def run(ctx) -> dict:
             f"{loss_last:.5f};  {done} rounds in {t_last:.3f}s")
     correct = (oracle["ok"] and lo <= q <= hi
                and loss_last < loss_first)
+    compared = dict(compared_of_oracle(oracle), **{
+        "holdout_" + cfg["quality"]["metric"]: {"value": q,
+                                                "limit": [lo, hi]},
+        "train_loss_last": {"value": loss_last, "limit": loss_first}})
     return {"end_to_end": {"train_rounds_per_s": done / t_last},
             "attempted": done, "failed": 0, "correct": correct,
-            "record": record}
+            "compared": compared, "record": record}

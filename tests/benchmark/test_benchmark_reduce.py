@@ -124,15 +124,50 @@ def test_roofline_reads_the_level_kernels_alone():
     got = load("layer_metrics/level_hist_roofline.py").read(out, record, {})
     shapes = load("shapes.py")
     peaks = shapes.load_peaks("TPU v5 lite")
+    # depth 2: the root, and one child of its split (the level has two)
     least = sum(shapes.level_hist_min_seconds(1000, 4, 16, k, peaks)[0]
-                for k in (1, 2))
+                for k in (1, 1))
     assert got == pytest.approx(100.0 * least / 150e-9)
-    assert "level 1:" in record["level_hist_bound"]
+    level0, level1 = record["level_hist_bound"].split("; ")
+    assert level0.startswith("level 0:") and level0.endswith(", 1 built)")
+    assert level1.startswith("level 1:") and level1.endswith(", 1 built)")
+    assert "level_hist_over_floor" not in record
     # no level kernel in the trace (renamed, or another route): no reading
     for chip in table["devices"].values():
         chip["ops"][1] = [other, 100.0, 300.0]
     assert load("layer_metrics/level_hist_roofline.py").read(
         summary.summarize(table), record, {}) is None
+
+
+def test_roofline_over_the_limit_is_returned_and_said(capsys):
+    """A made-up summary whose level kernels take a tenth of the floor: the
+    reading comes back as read, with a line on stderr and a mark in the
+    record (the driver refuses it as ``impossible_gain``); at the floor's
+    own time it reads 100 and says nothing."""
+    reader = load("layer_metrics/level_hist_roofline.py")
+    shapes = load("shapes.py")
+    peaks = shapes.load_peaks("TPU v5 lite")
+    least = sum(shapes.level_hist_min_seconds(
+        1000, 4, 16, shapes.built_nodes(d), peaks)[0] for d in range(3))
+
+    def record():
+        return {"traced_rounds": 1, "chips": 1, "rows_train": 1000,
+                "cols": 4, "max_bin": 16, "max_depth": 3,
+                "device_kind": "TPU v5 lite"}
+
+    at_floor = record()
+    assert reader.read({"level_hist_s": least}, at_floor, {}) == \
+        pytest.approx(100.0)
+    assert "level_hist_over_floor" not in at_floor
+    assert capsys.readouterr().err == ""
+    over = record()
+    assert reader.read({"level_hist_s": least / 10}, over, {}) == \
+        pytest.approx(1000.0)
+    assert over["level_hist_over_floor"] is True
+    err = capsys.readouterr().err
+    assert "level_hist_roofline 1000.000% > 105%" in err
+    assert "benchmark/shapes.py" in err and "impossible_gain" in err
+    assert over["level_hist_bound"].endswith(", 2 built)")
 
 
 def test_window_defaults_to_the_extent_of_device_events():

@@ -150,6 +150,99 @@ def test_grower_passes_leaves_the_parameters_stop():
     assert rep["leaves_checked"] > 0 and not rep["ungrown"], rep["ungrown"][:3]
 
 
+def _lightest_split(bins, cuts, h, tree):
+    """(node, float64 hessian sum of its lighter child) of the split of
+    ``tree`` whose lighter child is the lightest."""
+    found, stack = (None, np.inf), [(0, np.arange(len(bins)))]
+    while stack:
+        node, rows = stack.pop()
+        if int(tree["left_children"][node]) < 0:
+            continue
+        f = int(tree["split_indices"][node])
+        b = int(np.flatnonzero(
+            cuts[f] == np.float32(tree["split_conditions"][node]))[0])
+        left = bins[rows, f] <= b
+        light = min(h[rows[left]].sum(), h[rows[~left]].sum())
+        if light < found[1]:
+            found = (node, light)
+        stack.append((int(tree["left_children"][node]), rows[left]))
+        stack.append((int(tree["right_children"][node]), rows[~left]))
+    return found
+
+
+@pytest.mark.parametrize("short,passes", [
+    (0.0, True),        # on the threshold: the reference's own rule
+    (1.5e-6, True),     # the chip's reading (PERF.md section 6, PR 30)
+    (0.5 * 1e-3, True),
+    (2.0 * 1e-3, False),
+    (0.2, False)])      # a whole row short: what a dropped threshold reads
+def test_grower_holds_min_child_weight_within_its_slack(short, passes):
+    """A child of a system split may fall short of ``min_child_weight`` by
+    ``MCW_RTOL`` of it and no more: the replay of one tree under a threshold
+    set that far above its lightest child."""
+    assert grower.MCW_RTOL == 1e-3
+    X, y, cuts, forest = _grown(4)
+    tree = forest.trees[0]
+    bins = grower.bin_rows(X, cuts)
+    g, h = grower.gradients("binary:logistic", np.full(
+        (len(X), 1), forest.base_margin()), y, 1)
+    node, light = _lightest_split(bins, cuts, h[:, 0], tree)
+    mcw = light / (1.0 - short)
+    _, rep = grower.replay_tree(bins, cuts, g[:, 0], h[:, 0], tree, eta=0.3,
+                                max_depth=4, min_child_weight=mcw)
+    assert rep["mcw_short"] == pytest.approx(short, rel=1e-6, abs=1e-12)
+    if passes:
+        assert not rep["mismatch"], rep["mismatch"][:3]
+        assert rep["same"] + rep["tie"] == rep["nodes"]
+        # only a shortfall makes a tie that the slack alone allows
+        assert rep["mcw_decided"] == (1 if short > 0 else 0)
+    else:
+        assert [m[0] for m in rep["mismatch"]] == [node]
+        assert "under min_child_weight" in rep["mismatch"][0][1]
+
+
+def test_grower_does_not_ask_for_a_split_a_rounding_clear_of_the_threshold():
+    """The other side of the threshold: where the reference's best split
+    clears ``min_child_weight`` by less than the slack, a system that took
+    the next best (it read the child a rounding short) is a tie, and one
+    that took a worse split than that is still a mismatch."""
+    X, y, cuts, forest = _grown(1)
+    tree = {k: v.copy() for k, v in forest.trees[0].items()}
+    bins = grower.bin_rows(X, cuts)
+    g, h = grower.gradients("binary:logistic", np.full(
+        (len(X), 1), forest.base_margin()), y, 1)
+    g, h = g[:, 0], h[:, 0]
+    rows = np.arange(len(X))
+    gain, G, H, GL, HL = grower._split_gains(bins, rows, g, h,
+                                             cuts.shape[1], 1.0)
+    f0 = int(tree["split_indices"][0])
+    b0 = int(np.flatnonzero(
+        cuts[f0] == np.float32(tree["split_conditions"][0]))[0])
+    light = min(HL[f0, b0], H - HL[f0, b0])
+    # the threshold a rounding under the best split's lighter child: the
+    # reference takes that split, a system a rounding off may not see it
+    mcw = light * (1.0 - 1e-6)
+    lighter = np.minimum(HL, H - HL)
+    second = np.where(lighter < mcw * (1 + grower.MCW_RTOL), -np.inf, gain)
+    f2, b2 = np.unravel_index(int(second.argmax()), second.shape)
+    assert (f2, b2) != (f0, b0) and second[f2, b2] < gain[f0, b0] * 0.999
+    for f, b, verdict in ((f0, b0, "same"), (f2, b2, "tie")):
+        t = {k: v.copy() for k, v in tree.items()}
+        t["split_indices"][0], t["split_conditions"][0] = f, cuts[f][b]
+        _, rep = grower.replay_tree(bins, cuts, g, h, t, eta=0.3,
+                                    max_depth=1, min_child_weight=mcw)
+        assert not [m for m in rep["mismatch"] if m[0] == 0], rep["mismatch"]
+        assert rep[verdict] >= 1
+    third = second.copy()
+    third[second > second[f2, b2] * 0.99] = -np.inf
+    f3, b3 = np.unravel_index(int(third.argmax()), third.shape)
+    t = {k: v.copy() for k, v in tree.items()}
+    t["split_indices"][0], t["split_conditions"][0] = f3, cuts[f3][b3]
+    _, rep = grower.replay_tree(bins, cuts, g, h, t, eta=0.3, max_depth=1,
+                                min_child_weight=mcw)
+    assert [m for m in rep["mismatch"] if m[0] == 0]
+
+
 def test_seed_draws_the_rows_and_law_seed_the_task():
     Xa, ya = generate(rows=4000, cols=5, seed=1)
     Xb, yb = generate(rows=4000, cols=5, seed=2)

@@ -1,8 +1,12 @@
-"""The from-shapes arithmetic reproduces the ROADMAP's hand figures."""
+"""The from-shapes arithmetic reproduces the ROADMAP's hand figures, and the
+level histogram's floor counts the nodes a level has to build."""
+
+import json
+import os
 
 import pytest
 
-from bench_paths import load
+from bench_paths import BENCH, load
 
 shapes = load("shapes.py")
 
@@ -24,8 +28,11 @@ def test_unpadded_flops_scale_with_channels_and_terms():
     a = shapes.level_hist_flops(1000, 4, 16, K=4)
     assert a == 2.0 * 1000 * 4 * 16 * (2 * 4 * 2)
     assert shapes.level_hist_flops(1000, 4, 16, K=4, bf16_terms=1) == a / 2
+    # a depth-3 tree builds the root, one child of its split, two of the next
     assert shapes.round_hist_flops(1000, 4, 16, depth=3) == sum(
-        shapes.level_hist_flops(1000, 4, 16, K=k) for k in (1, 2, 4))
+        shapes.level_hist_flops(1000, 4, 16, K=k) for k in (1, 1, 2))
+    assert shapes.round_hist_flops(1000, 4, 16, depth=3, bf16_terms=1) == \
+        shapes.round_hist_flops(1000, 4, 16, depth=3) / 2
 
 
 def test_level_bytes_are_the_algorithms_not_the_programs():
@@ -34,8 +41,8 @@ def test_level_bytes_are_the_algorithms_not_the_programs():
         1000 * 10 + 12 * 1000 + 4 * 10 * 256 * 4
     assert shapes.level_hist_bytes(1000, 10, 512, K=2) == \
         2 * 1000 * 10 + 12 * 1000 + 4 * 10 * 512 * 4
-    assert shapes.round_hist_bytes(1000, 10, 256, depth=2) == \
-        shapes.level_hist_bytes(1000, 10, 256, K=1) \
+    assert shapes.round_hist_bytes(1000, 10, 256, depth=3) == \
+        2 * shapes.level_hist_bytes(1000, 10, 256, K=1) \
         + shapes.level_hist_bytes(1000, 10, 256, K=2)
 
 
@@ -53,11 +60,74 @@ def test_unknown_device_is_an_error():
 def test_level_roofline_picks_the_larger_bound():
     peaks = shapes.load_peaks("TPU v5 lite")
     # the anchor: the matmul's flops bound every level (bins are 38 MB a
-    # level, 0.06 ms), 0.39 ms at the root, 12.5 ms at 32 nodes
+    # level, 0.06 ms), 0.39 ms at the root, 6.24 ms at the deepest level's
+    # 16 built nodes (the level has 32)
     t0, b0 = shapes.level_hist_min_seconds(750_592, 50, 256, 1, peaks)
-    t5, b5 = shapes.level_hist_min_seconds(750_592, 50, 256, 32, peaks)
+    t5, b5 = shapes.level_hist_min_seconds(
+        750_592, 50, 256, shapes.built_nodes(5), peaks)
     assert (b0, b5) == ("flops", "flops")
     assert t0 == pytest.approx(0.39e-3, rel=0.02)
-    assert t5 == pytest.approx(12.5e-3, rel=0.02)
+    assert t5 == pytest.approx(6.24e-3, rel=0.02)
     # one bin and one node: the reads bound it
     assert shapes.level_hist_min_seconds(10**6, 50, 1, 1, peaks)[1] == "bytes"
+
+
+@pytest.mark.parametrize("d,built", list(enumerate(
+    (1, 1, 2, 4, 8, 16, 32, 64, 128))))
+def test_built_nodes_is_one_child_of_every_split(d, built):
+    assert shapes.built_nodes(d) == built
+
+
+@pytest.mark.parametrize("depth,built", [(6, 32), (8, 128)])
+def test_a_tree_builds_half_its_nodes_and_the_root(depth, built):
+    assert sum(shapes.built_nodes(d) for d in range(depth)) == built
+    assert sum(1 << d for d in range(depth)) == 2 * built - 1
+
+
+def test_no_level_above_the_root():
+    with pytest.raises(ValueError):
+        shapes.built_nodes(-1)
+
+
+def _floor_pct(config: str, chips: int, level_ms: float) -> float:
+    """What the reader returns for a round of ``config`` on a v5e whose
+    level kernels take ``level_ms``: the sizes from the configuration's own
+    file, as the traffic kinds put them in the run's record."""
+    with open(os.path.join(BENCH, "configs", f"{config}.json")) as f:
+        cfg = json.load(f)
+    record = {"traced_rounds": 1, "chips": chips,
+              "rows_train": int(cfg["data"]["rows_train"]),
+              "cols": int(cfg["data"]["cols"]),
+              "max_bin": int(cfg["params"]["max_bin"]),
+              "max_depth": int(cfg["params"]["max_depth"]),
+              "device_kind": "TPU v5 lite"}
+    return load("layer_metrics/level_hist_roofline.py").read(
+        {"level_hist_s": level_ms * 1e-3}, record, {})
+
+
+CELLS = [("anchor-1mx50", 1), ("higgs-11mx28-d8-x4", 4),
+         ("mslr-web30k-ndcg-d6", 1)]
+
+
+# level-kernel ms a round = pallas_ms_per_round - partition_mosaic_ms_per_round
+# of PERF_LEDGER.jsonl's PR 28 lines (the tree that stands): 69.63 - 2.330,
+# 252.19 - 8.148, 451.44 - 7.369. Against 2^d nodes a level those lines read
+# 36.49, 79.84 and 45.54%.
+@pytest.mark.parametrize("cell,level_ms,pct", [
+    (CELLS[0], 67.30, 18.54), (CELLS[1], 244.04, 40.08),
+    (CELLS[2], 444.04, 23.13)])
+def test_cells_floor_at_the_ledgers_level_kernel_times(cell, level_ms, pct):
+    assert _floor_pct(*cell, level_ms) == pytest.approx(pct, abs=0.05)
+
+
+# PR 29's refused times (PERF_LEDGER.jsonl, PR 29: 56.44 - 0.541,
+# 167.35 - 2.090, 401.13 - 3.073), at which the 2^d floor read 43.94, 117.90
+# (verdict impossible_gain, higgs_train_x4) and 50.80%: a kernel 1.48x faster
+# than PR 28's is inside what a chip can do.
+@pytest.mark.parametrize("cell,level_ms,pct", [
+    (CELLS[0], 55.90, 22.3), (CELLS[1], 165.26, 59.2),
+    (CELLS[2], 398.06, 25.8)])
+def test_pr29s_refused_times_read_under_the_roofline(cell, level_ms, pct):
+    got = _floor_pct(*cell, level_ms)
+    assert got < 100.0
+    assert got == pytest.approx(pct, abs=0.1)
