@@ -251,8 +251,8 @@ def stage_kernels(sz: Sizes, X, routes: dict) -> None:
     n, F = sz.kernel_rows, sz.cols
     n_anchor = -(-int(sz.rows * 0.75) // hk.TR) * hk.TR
     rng = np.random.RandomState(SEED)
-    gh_np = rng.randn(n, 2).astype(np.float32)
-    gh_np[:, 1] = np.abs(gh_np[:, 1]) + 0.05
+    gh_np = rng.randn(2, n).astype(np.float32)  # row 0 g, row 1 h
+    gh_np[1] = np.abs(gh_np[1]) + 0.05
     gh = jnp.asarray(gh_np)
     before = _decisions()
     for B in (64, 256):
@@ -282,11 +282,11 @@ def stage_kernels(sz: Sizes, X, routes: dict) -> None:
         for d in (0, 3, 5):
             K, Kp = 1 << d, (1 << d) >> 1
             if d == 0:
-                pos_np = np.zeros((n, 1), np.int32)
+                pos_np = np.zeros((1, n), np.int32)
                 ptab_np = np.zeros((1, 4), np.float32)
             else:
                 prev = (1 << (d - 1)) - 1
-                pos_np = (prev + rng.randint(0, Kp, size=(n, 1))
+                pos_np = (prev + rng.randint(0, Kp, size=(1, n))
                           ).astype(np.int32)
                 ptab_np = np.stack([
                     # is_split, and which child a subtracting level builds

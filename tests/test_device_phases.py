@@ -234,7 +234,7 @@ def test_scope_leaves_the_kernels_instruction_name(one_v5e_chip, kernel):
 
         calls = _mosaic_calls(
             jax.jit(level), S((n, F), jnp.uint8), S((n, F * B), jnp.int8),
-            S((n, 1), jnp.int32), S((n, 2), jnp.float32),
+            S((1, n), jnp.int32), S((2, n), jnp.float32),
             S((K >> 1, 4), jnp.float32))
         scope = "xgb.level_hist"
     elif kernel == "_build_onehot_pallas":
@@ -251,7 +251,7 @@ def test_scope_leaves_the_kernels_instruction_name(one_v5e_chip, kernel):
                                              d=6)
 
         lines = _mosaic_lines(
-            jax.jit(route), S((n, 50), jnp.int32), S((n, 1), jnp.int32),
+            jax.jit(route), S((n, 50), jnp.int32), S((1, n), jnp.int32),
             S((Kp, 4), jnp.float32))
         calls = _calls_of(lines)
         scope = "xgb.partition"
@@ -325,7 +325,7 @@ def test_kernels_compile_at_136_features_under_their_names(one_v5e_chip,
 
         calls = _mosaic_calls(
             jax.jit(level), S((n, F), jnp.uint8), S((n, Fh * B), jnp.int8),
-            S((n, 1), jnp.int32), S((n, 2), jnp.float32),
+            S((1, n), jnp.int32), S((2, n), jnp.float32),
             S((max(Kp, 1), 4), jnp.float32))
         scope = "xgb.level_hist"
     else:
@@ -335,7 +335,7 @@ def test_kernels_compile_at_136_features_under_their_names(one_v5e_chip,
                                              d=d)
 
         calls = _mosaic_calls(jax.jit(route), S((n, F), jnp.int32),
-                              S((n, 1), jnp.int32), S((Kp, 4), jnp.float32))
+                              S((1, n), jnp.int32), S((Kp, 4), jnp.float32))
         scope = "xgb.partition"
     assert list(calls) == [kernel]
     assert f"/{scope}/" in calls[kernel]
@@ -358,8 +358,8 @@ def test_sibling_sub_kernels_keep_their_names_and_halve_the_output_rows(
 
     n, B = 8192, _MSLR_B
     K, Kp = 1 << d, 1 << (d - 1)
-    shapes = [S((n, F), jnp.uint8), S((n, 1), jnp.int32),
-              S((n, 2), jnp.float32), S((Kp, 4), jnp.float32)]
+    shapes = [S((n, F), jnp.uint8), S((1, n), jnp.int32),
+              S((2, n), jnp.float32), S((Kp, 4), jnp.float32)]
     if kernel == "_hoisted_level_pallas":
         tr = hk._hoist_tr(Fh * B, Kp, F, B)
         assert tr == 512 and hk._hoist_tr(Fh * B, K, F, B) == 128
@@ -387,6 +387,62 @@ def test_sibling_sub_kernels_keep_their_names_and_halve_the_output_rows(
     assert out in lines[0].split(" custom-call(")[0], lines[0][:300]
     summary = _benchmark_summary()
     assert summary.is_level_kernel(lines[0].removeprefix("ROOT "))
+
+
+# rows on the lanes (ISSUE 31): what the chip's compiler is handed
+@pytest.mark.parametrize("kernel,F,Kp,W,B", [
+    ("_hoisted_level_pallas", 50, 16, 4, 256),  # the anchor's level 5
+    ("_fused_level_pallas", 12, 4, 5 + 64, 64),  # a categorical table
+    ("_route_rows_pallas", 50, 32, 4, 256),  # the anchor's last routing
+    ("_route_rows_pallas", 28, 128, 4, 256),  # HIGGS's
+    ("_route_rows_pallas", hk._MAX_KERNEL_FEATURES, 128, 4, 256),
+    ("_route_rows_pallas", 50, 128, 5 + 256, 256),  # categorical, bin256
+    ("_route_rows_pallas", 136, 32, 4, 512)])  # bins past bf16's integers
+def test_row_arrays_reach_the_kernels_lane_dense(one_v5e_chip, kernel, F, Kp,
+                                                 W, B):
+    """Positions go in and come out as ``s32[1, n]`` and gradients go in
+    as ``f32[2, n]``: the compiler lays them out one and two sublanes deep
+    (``T(1,128)``, ``T(2,128)``: 4 and 8 bytes a row in HBM) where
+    ``[n, 1]`` and ``[n, 2]`` were padded to 128 lanes (512 bytes a row).
+    The widest routing step ``pallas_route_fits`` admits, a categorical
+    table and the f32 feature pick (``B > 256``) compile too."""
+    import jax.numpy as jnp
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
+
+    n, d = 8192, Kp.bit_length()
+    shapes = [S((n, F), jnp.int32), S((1, n), jnp.int32),
+              S((2, n), jnp.float32), S((Kp, W), jnp.float32)]
+    if kernel == "_route_rows_pallas":
+        assert hk.pallas_route_fits(n, F, Kp, W)
+        del shapes[2]
+
+        def fn(bins, pos, ptab):
+            return hk._route_rows_pallas(bins, pos, ptab, Kp=Kp, B=B, d=d)
+    elif kernel == "_fused_level_pallas":
+        def fn(bins, pos, gh, ptab):
+            return hk._fused_level_pallas(bins, pos, gh, ptab, K=2 * Kp,
+                                          Kp=Kp, B=B, d=d, sub=True)
+    else:
+        Fh = 34
+        shapes.append(S((n, Fh * B), jnp.int8))
+
+        def fn(bins, pos, gh, ptab, onehot):
+            return hk._hoisted_level_pallas(
+                bins, onehot, pos, gh, ptab, K=2 * Kp, Kp=Kp, B=B, d=d,
+                tr=hk._hoist_tr(Fh * B, Kp, F, B), sub=True)
+
+    line, = _mosaic_lines(jax.jit(fn), *shapes)
+    assert list(_calls_of([line])) == [kernel]
+    result = line.split(" custom-call(")[0]
+    assert f"s32[1,{n}]{{1,0:T(1,128)}}" in result, result
+    operands = re.search(r"operand_layout_constraints=\{(.*?)\}, \w+=",
+                         line).group(1)
+    assert f"s32[1,{n}]{{1,0}}" in operands, operands
+    if kernel != "_route_rows_pallas":
+        assert f"f32[2,{n}]{{1,0}}" in operands, operands
+    assert f"[{n},1]" not in line and f"[{n},2]" not in line
 
 
 # ---------------------------------------------------------------------------

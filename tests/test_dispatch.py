@@ -233,9 +233,8 @@ def test_dispatch_report_cli(capsys):
 def _level_inputs():
     rng = np.random.RandomState(7)
     bins = rng.randint(0, B + 1, size=(N, F)).astype(np.uint8)  # B=missing
-    gh = np.stack([rng.randn(N), rng.rand(N) + 0.5],
-                  axis=-1).astype(np.float32)
-    pos = np.zeros((N, 1), np.int32)
+    gh = np.stack([rng.randn(N), rng.rand(N) + 0.5]).astype(np.float32)
+    pos = np.zeros((1, N), np.int32)  # rows on the lanes, every impl
     ptab = np.zeros((1, 4), np.float32)
     return (jnp.asarray(bins), jnp.asarray(pos), jnp.asarray(gh),
             jnp.asarray(ptab))

@@ -35,21 +35,22 @@ def _split_hilo_xla(x):
 
 def _hoisted_emulated(bins, pos, gh, onehot, *, K, B, d):
     """Pure-XLA twin of ``_hoisted_kernel``'s histogram half (post-
-    partition): same grad-channel layout, same bf16 operands, same
+    partition): same grad-channel layout (rows on the lanes: ``pos``
+    [1, n], ``gh`` [2, n], channels [4K, n]), same bf16 operands, same
     [2K, F*B] -> [F, 2K, B] reshape."""
     n, F = bins.shape
     offset = (1 << d) - 1
-    local = pos[:, 0] - offset
+    local = pos[0] - offset
     ohseg = jax.nn.one_hot(jnp.where((local >= 0) & (local < K), local, K),
-                           K + 1, dtype=jnp.float32)[:, :K]
-    g, h = gh[:, 0:1], gh[:, 1:2]
+                           K + 1, dtype=jnp.float32, axis=0)[:K]  # [K, n]
+    g, h = gh[0:1], gh[1:2]
     g_hi, g_lo = _split_hilo_xla(g)
     h_hi, h_lo = _split_hilo_xla(h)
     ghs4 = jnp.concatenate(
-        [ohseg * g_hi, ohseg * h_hi, ohseg * g_lo, ohseg * h_lo], axis=1
-    ).astype(jnp.bfloat16)  # [n, 4K]
+        [ohseg * g_hi, ohseg * h_hi, ohseg * g_lo, ohseg * h_lo], axis=0
+    ).astype(jnp.bfloat16)  # [4K, n]
     out = jax.lax.dot_general(
-        ghs4, onehot.astype(jnp.bfloat16), (((0,), (0,)), ((), ())),
+        ghs4, onehot.astype(jnp.bfloat16), (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )  # [4K, F*B]
     hist2 = out[: 2 * K] + out[2 * K:]
@@ -61,8 +62,8 @@ def _case(n=512, F=5, B=16, seed=0, missing_frac=0.1):
     bins = rng.randint(0, B, size=(n, F)).astype(np.int32)
     miss = rng.rand(n, F) < missing_frac
     bins[miss] = B  # missing sentinel
-    gh = rng.randn(n, 2).astype(np.float32)
-    gh[:, 1] = np.abs(gh[:, 1])
+    gh = rng.randn(2, n).astype(np.float32)  # row 0 g, row 1 h
+    gh[1] = np.abs(gh[1])
     return jnp.asarray(bins), jnp.asarray(gh)
 
 
@@ -86,7 +87,7 @@ def test_hoisted_contraction_matches_segment_sum(d, K):
     rng = np.random.RandomState(7)
     offset = (1 << d) - 1
     pos = jnp.asarray(
-        rng.randint(offset, offset + K, size=(n, 1)).astype(np.int32))
+        rng.randint(offset, offset + K, size=(1, n)).astype(np.int32))
     onehot = build_onehot(bins, B=32)
     got = _hoisted_emulated(bins, pos, gh, onehot, K=K, B=32, d=d)
     ptab = jnp.zeros((max(K >> 1, 1), 4), jnp.float32)  # Kp=0: no partition
@@ -106,7 +107,7 @@ def test_hoisted_kernel_interpret_mode():
     import functools
 
     bins, gh = _case(n=512, F=4, B=16, seed=5)
-    pos = jnp.zeros((512, 1), jnp.int32)
+    pos = jnp.zeros((1, 512), jnp.int32)
     onehot = build_onehot(bins, B=16)
     ptab = jnp.zeros((1, 4), jnp.float32)
     kern = functools.partial(hk._hoisted_kernel, K=1, Kp=0, F=4, Fh=4, B=16,
@@ -117,16 +118,16 @@ def test_hoisted_kernel_interpret_mode():
         in_specs=[
             pl.BlockSpec((256, 4), lambda c: (c, 0)),
             pl.BlockSpec((256, 64), lambda c: (c, 0)),
-            pl.BlockSpec((256, 1), lambda c: (c, 0)),
-            pl.BlockSpec((256, 2), lambda c: (c, 0)),
+            pl.BlockSpec((1, 256), lambda c: (0, c)),
+            pl.BlockSpec((2, 256), lambda c: (0, c)),
             pl.BlockSpec((1, 4), lambda c: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((256, 1), lambda c: (c, 0)),
+            pl.BlockSpec((1, 256), lambda c: (0, c)),
             pl.BlockSpec((2, 64), lambda c: (0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((512, 1), jnp.int32),
+            jax.ShapeDtypeStruct((1, 512), jnp.int32),
             jax.ShapeDtypeStruct((2, 64), jnp.float32),
         ],
         interpret=True,
@@ -183,7 +184,7 @@ def test_partial_hoist_kernel_interpret_mode():
     from xgboost_tpu.tree import hist_kernel as hk
 
     bins, gh = _case(n=512, F=4, B=16, seed=11)
-    pos = jnp.zeros((512, 1), jnp.int32)
+    pos = jnp.zeros((1, 512), jnp.int32)
     Fh = 2
     onehot = build_onehot(bins[:, :Fh], B=16)  # [n, 32]
     ptab = jnp.zeros((1, 4), jnp.float32)
@@ -195,16 +196,16 @@ def test_partial_hoist_kernel_interpret_mode():
         in_specs=[
             pl.BlockSpec((256, 4), lambda c: (c, 0)),
             pl.BlockSpec((256, 32), lambda c: (c, 0)),
-            pl.BlockSpec((256, 1), lambda c: (c, 0)),
-            pl.BlockSpec((256, 2), lambda c: (c, 0)),
+            pl.BlockSpec((1, 256), lambda c: (0, c)),
+            pl.BlockSpec((2, 256), lambda c: (0, c)),
             pl.BlockSpec((1, 4), lambda c: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((256, 1), lambda c: (c, 0)),
+            pl.BlockSpec((1, 256), lambda c: (0, c)),
             pl.BlockSpec((2, 64), lambda c: (0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((512, 1), jnp.int32),
+            jax.ShapeDtypeStruct((1, 512), jnp.int32),
             jax.ShapeDtypeStruct((2, 64), jnp.float32),
         ],
         interpret=True,
@@ -280,10 +281,10 @@ def test_kernel_categorical_partition_interpret_mode():
     n, F, B = 512, 4, 16
     Kp, K, d = 2, 4, 2
     bins = jnp.asarray(rng.randint(0, B + 1, size=(n, F)).astype(np.int32))
-    gh = jnp.asarray(rng.randn(n, 2).astype(np.float32))
+    gh = jnp.asarray(rng.randn(2, n).astype(np.float32))
     prev_off = (1 << (d - 1)) - 1
     pos = jnp.asarray(rng.randint(prev_off, prev_off + Kp,
-                                  size=(n, 1)).astype(np.int32))
+                                  size=(1, n)).astype(np.int32))
     # two split nodes: one numerical, one categorical with a random set
     sets = rng.rand(Kp, B) < 0.4
     ptab = np.zeros((Kp, 5 + B), np.float32)
@@ -304,16 +305,16 @@ def test_kernel_categorical_partition_interpret_mode():
         grid=(2,),
         in_specs=[
             pl.BlockSpec((256, F), lambda c: (c, 0)),
-            pl.BlockSpec((256, 1), lambda c: (c, 0)),
-            pl.BlockSpec((256, 2), lambda c: (c, 0)),
+            pl.BlockSpec((1, 256), lambda c: (0, c)),
+            pl.BlockSpec((2, 256), lambda c: (0, c)),
             pl.BlockSpec((Kp, 5 + B), lambda c: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((256, 1), lambda c: (c, 0)),
+            pl.BlockSpec((1, 256), lambda c: (0, c)),
             pl.BlockSpec((F, 2 * K, B), lambda c: (0, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n, 1), jnp.int32),
+            jax.ShapeDtypeStruct((1, n), jnp.int32),
             jax.ShapeDtypeStruct((F, 2 * K, B), jnp.float32),
         ],
         interpret=True,

@@ -165,8 +165,8 @@ def test_native_hist_matches_xla(monkeypatch):
     B, K, d = 16, 4, 2
     bins = jnp.asarray(rng.randint(0, B + 1, (1024, F)).astype(np.uint8))
     pos = jnp.asarray(
-        (1 + rng.randint(0, 2, 1024))[:, None].astype(np.int32))
-    gh = jnp.asarray(rng.randn(1024, 2).astype(np.float32))
+        (1 + rng.randint(0, 2, 1024))[None, :].astype(np.int32))
+    gh = jnp.asarray(rng.randn(2, 1024).astype(np.float32))
     ptab = np.zeros((2, 4), np.float32)
     ptab[:, 0] = 1
     ptab[:, 1] = rng.randint(0, F, 2)

@@ -5,14 +5,15 @@ share, over row tiles and nothing else; ``partition_apply`` reaches it through
 the ``level_partition`` row of the dispatch table where the call site's
 ``pallas`` flag is set. Here the real kernel body runs in interpret mode on the
 CPU against ``partition_apply_xla``: the decisions are integers, so equality is
-exact. The chip's compiler is held to it in ``tests/test_device_phases.py``.
+exact. Positions are ``[1, n]`` (rows on the lanes, ISSUE 31), in and out. The
+chip's compiler is held to it in ``tests/test_device_phases.py``.
 """
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 import xgboost_tpu as xgb
 from xgboost_tpu import dispatch
@@ -38,7 +39,7 @@ def _case(Kp, *, default_left=None, cats=False, seed=0, n=N):
     bins = rng.randint(0, B, size=(n, F)).astype(np.int32)
     bins[rng.rand(n, F) < 0.1] = B
     pos = rng.randint(max(prev_offset - 3, 0), prev_offset + Kp + 3,
-                      size=(n, 1)).astype(np.int32)
+                      size=(1, n)).astype(np.int32)
     ptab = np.zeros((Kp, 5 + B if cats else 4), np.float32)
     ptab[:, 0] = rng.rand(Kp) < 0.7 if Kp > 1 else 1.0
     ptab[:, 1] = rng.randint(0, F, Kp)
@@ -65,18 +66,18 @@ def test_route_rows_equals_the_xla_partition(interpret, Kp, default_left):
     bins, pos, ptab, d = _case(Kp, default_left=default_left)
     want, got = _both(bins, pos, ptab, Kp, d)
     np.testing.assert_array_equal(got, want)
-    assert got.dtype == np.int32 and got.shape == (N, 1)
+    assert got.dtype == np.int32 and got.shape == (1, N)
     # the case holds what it says it holds
     before = np.asarray(pos)
-    lp = before[:, 0] - ((1 << (d - 1)) - 1)
+    lp = before[0] - ((1 << (d - 1)) - 1)
     outside = (lp < 0) | (lp >= Kp)
     unsplit = ~outside & (np.asarray(ptab)[np.clip(lp, 0, Kp - 1), 0] == 0)
     assert outside.any() and (Kp == 1 or unsplit.any())
     assert (np.asarray(bins) == B).any()
-    np.testing.assert_array_equal(got[outside | unsplit],
-                                  before[outside | unsplit])
-    moved = got[~(outside | unsplit), 0]
-    assert set(np.unique(moved - 2 * before[~(outside | unsplit), 0])) == {1, 2}
+    np.testing.assert_array_equal(got[0, outside | unsplit],
+                                  before[0, outside | unsplit])
+    moved = got[0, ~(outside | unsplit)]
+    assert set(np.unique(moved - 2 * before[0, ~(outside | unsplit)])) == {1, 2}
 
 
 @pytest.mark.parametrize("Kp", [2, 32])
@@ -104,10 +105,11 @@ def test_route_rows_under_a_two_device_shard_map(monkeypatch):
     def sharded(check_vma):
         return jax.shard_map(
             route, mesh=mesh,
-            in_specs=(P(ROW_AXIS, None), P(ROW_AXIS, None), P(None, None)),
-            out_specs=P(ROW_AXIS, None), check_vma=check_vma)
+            in_specs=(P(ROW_AXIS, None), P(None, ROW_AXIS), P(None, None)),
+            out_specs=P(None, ROW_AXIS), check_vma=check_vma)
 
-    args = shard_rows(bins, mesh), shard_rows(pos, mesh), ptab
+    args = (shard_rows(bins, mesh),
+            jax.device_put(pos, NamedSharding(mesh, P(None, ROW_AXIS))), ptab)
     typed = jax.make_jaxpr(sharded(True))(*args)
     assert "pallas_call" in str(typed) and "{V:%s}" % ROW_AXIS in str(typed)
     assert dispatch.last_decisions()["level_partition"] == "pallas"
@@ -124,9 +126,11 @@ def test_route_rows_under_a_two_device_shard_map(monkeypatch):
     (hk.TR, 50, 128, 5 + 256, True),  # categorical, bin256
     (hk.TR + 8, 50, 32, 4, False),  # ragged rows
     (0, 50, 32, 4, False),
-    # the working set bounds F before ``_MAX_KERNEL_FEATURES`` does
+    # lane-dense positions (ISSUE 31) left the working set under the budget
+    # at every width the level kernels take: the width's own cap bounds F
     (hk.TR, hk._MAX_KERNEL_FEATURES + 1, 32, 4, False),
-    (hk.TR, hk._MAX_KERNEL_FEATURES, 32, 4, False),
+    (hk.TR, hk._MAX_KERNEL_FEATURES, 32, 4, True),
+    (hk.TR, hk._MAX_KERNEL_FEATURES, 128, 5 + 256, False),  # the set lookup
     (hk.TR, 384, 32, 4, True),
     (hk.TR, 300, 128, 4, True),
 ])

@@ -338,12 +338,12 @@ def test_tsan_training_round_trip(tmp_path):
             binsq = jnp.asarray(
                 rq.randint(0, Bq + 1, (nq, Fq)).astype(np.uint8))
             ghq = jnp.asarray(
-                rq.randn(nq, 2).astype(np.float32) ** 2 + 0.1)
+                rq.randn(2, nq).astype(np.float32) ** 2 + 0.1)
             cutsq = jnp.asarray(
                 np.sort(rq.randn(Fq, Bq).astype(np.float32), axis=1))
             maskq = jnp.ones((Fq,), bool)
-            G0 = jnp.float32(np.asarray(ghq)[:, 0].sum())
-            H0 = jnp.float32(np.asarray(ghq)[:, 1].sum())
+            G0 = jnp.float32(np.asarray(ghq)[0].sum())
+            H0 = jnp.float32(np.asarray(ghq)[1].sum())
             splitq = SimpleNamespace(reg_lambda=1.0, reg_alpha=0.0,
                                      max_delta_step=0.0,
                                      min_child_weight=1.0)

@@ -46,12 +46,12 @@ def _level_case(d, cat, seed):
     prev_off = Kp - 1
     bins = rng.randint(0, B, size=(n, F)).astype(np.int32)
     bins[rng.rand(n, F) < 0.1] = B
-    gh = rng.randn(n, 2).astype(np.float32)
-    gh[:, 1] = np.abs(gh[:, 1])
-    pos = rng.randint(prev_off, prev_off + Kp, size=(n, 1)).astype(np.int32)
+    gh = rng.randn(2, n).astype(np.float32)  # rows on the lanes (ISSUE 31)
+    gh[1] = np.abs(gh[1])
+    pos = rng.randint(prev_off, prev_off + Kp, size=(1, n)).astype(np.int32)
     if d >= 2:
-        pos[rng.rand(n) < 0.1] = 0  # a leaf above the level
-    bins[-64:], gh[-64:] = B, 0.0  # padding rows
+        pos[0, rng.rand(n) < 0.1] = 0  # a leaf above the level
+    bins[-64:], gh[:, -64:] = B, 0.0  # padding rows
     ptab = np.zeros((Kp, 5 + B if cat else 4), np.float32)
     ptab[:, 0] = rng.randint(1, 3, Kp)  # split; 2: the right child is built
     ptab[:, 1] = rng.randint(0, F, Kp)
@@ -296,8 +296,8 @@ def test_fused_level_dispatches_the_built_widths_tile(monkeypatch, cell,
             lambda *a: hk.fused_level(*a[:4], K=K, Kp=Kp, B=BINS, d=d,
                                       pallas=True, onehot=a[4],
                                       sibling_sub=d >= 1),
-            S((n, F), jnp.int32), S((n, 1), jnp.int32),
-            S((n, 2), jnp.float32), S((max(Kp, 1), 4), jnp.float32),
+            S((n, F), jnp.int32), S((1, n), jnp.int32),
+            S((2, n), jnp.float32), S((max(Kp, 1), 4), jnp.float32),
             S((n, Fh * BINS), jnp.int8))
         assert hist.shape == (F, 2 * max(Kp, 1), BINS)
         assert calls[-1] == (d, hk._hoist_tr(Fh * BINS, max(Kp, 1), F, BINS),

@@ -60,9 +60,9 @@ def test_parent_minus_child_exact_on_counts():
     n, F, B = 5000, 8, 16
     bins = jnp.asarray(rng.randint(0, B + 1, (n, F)).astype(np.uint8))
     gh = jnp.asarray(np.stack(
-        [rng.randint(-3, 4, n), rng.randint(1, 5, n)], axis=-1)
-        .astype(np.float32))
-    pos = jnp.zeros((n, 1), jnp.int32)
+        [rng.randint(-3, 4, n), rng.randint(1, 5, n)])
+        .astype(np.float32))  # [2, n]: rows on the lanes
+    pos = jnp.zeros((1, n), jnp.int32)
 
     # level 0: root histogram (the parent of the first sibling pair)
     _, hist0 = fused_level_native(bins, pos, gh, jnp.zeros((1, 4),
@@ -92,9 +92,9 @@ def test_unsplit_pair_stays_zero():
     n, F, B = 1000, 4, 8
     bins = jnp.asarray(rng.randint(0, B + 1, (n, F)).astype(np.uint8))
     gh = jnp.asarray(np.stack(
-        [rng.randint(-2, 3, n), rng.randint(1, 3, n)], axis=-1)
+        [rng.randint(-2, 3, n), rng.randint(1, 3, n)])
         .astype(np.float32))
-    pos = jnp.zeros((n, 1), jnp.int32)
+    pos = jnp.zeros((1, n), jnp.int32)
     _, hist0 = fused_level_native(bins, pos, gh, jnp.zeros((1, 4),
                                   jnp.float32), K=1, Kp=0, B=B, d=0)
     ptab = jnp.zeros((1, 4), jnp.float32)  # is_split = 0
@@ -237,13 +237,13 @@ def test_quant_bitwise_on_count_valued_gradients():
     n, F, B, depth = 6000, 8, 16, 4
     bins = jnp.asarray(rng.randint(0, B + 1, (n, F)).astype(np.uint8))
     gh = jnp.asarray(np.stack(
-        [rng.randint(-3, 4, n), rng.randint(1, 5, n)], axis=-1)
+        [rng.randint(-3, 4, n), rng.randint(1, 5, n)])
         .astype(np.float32))
     cut_values = jnp.asarray(
         np.sort(rng.randn(F, B).astype(np.float32), axis=1))
     tree_mask = jnp.ones((F,), bool)
-    G0 = jnp.float32(np.asarray(gh)[:, 0].sum())
-    H0 = jnp.float32(np.asarray(gh)[:, 1].sum())
+    G0 = jnp.float32(np.asarray(gh)[0].sum())
+    H0 = jnp.float32(np.asarray(gh)[1].sum())
     split = SimpleNamespace(reg_lambda=1.0, reg_alpha=0.0,
                             max_delta_step=0.0, min_child_weight=1.0)
 
@@ -271,9 +271,9 @@ def test_quant_level_entry_matches_float_on_counts():
     n, F, B = 5000, 8, 16
     bins = jnp.asarray(rng.randint(0, B + 1, (n, F)).astype(np.uint8))
     gh = jnp.asarray(np.stack(
-        [rng.randint(-3, 4, n), rng.randint(1, 5, n)], axis=-1)
-        .astype(np.float32))
-    pos = jnp.zeros((n, 1), jnp.int32)
+        [rng.randint(-3, 4, n), rng.randint(1, 5, n)])
+        .astype(np.float32))  # [2, n]: rows on the lanes
+    pos = jnp.zeros((1, n), jnp.int32)
     ptab0 = jnp.zeros((1, 4), jnp.float32)
 
     _, hist_f = fused_level_native(bins, pos, gh, ptab0, K=1, Kp=0, B=B,

@@ -412,7 +412,9 @@ def _grow_tree_fused_impl(
 
     with jax.named_scope("xgb.root"):
         grad, hess = apply_row_sampling(cfg, k_sub, grad, hess)
-        gh = jnp.stack([grad, hess], axis=-1)  # [n, 2]
+        # per-row arrays of the tree have the rows on the LANE axis from
+        # here to ``leaf_delta`` (hist_kernel's module docstring)
+        gh = jnp.stack([grad, hess])  # [2, n]
 
         if cfg.colsample_bytree < 1.0:
             tree_mask = _sample_features_exact(
@@ -428,7 +430,7 @@ def _grow_tree_fused_impl(
             G0 = jax.lax.psum(G0, cfg.axis_name)
             H0 = jax.lax.psum(H0, cfg.axis_name)
         st = _init_state(cfg, F, G0, H0, B)
-        pos = jnp.zeros((n, 1), jnp.int32)  # every row starts at the root
+        pos = jnp.zeros((1, n), jnp.int32)  # every row starts at the root
 
     tree_grow_native_route = _use_tree_grow(cfg, pallas, max_depth,
                                             str(bins.dtype))
@@ -714,7 +716,7 @@ def _grow_tree_fused_paged(
             pad = jnp.zeros((pr_pad - r,), jnp.float32)
             g = jnp.concatenate([g, pad])
             h = jnp.concatenate([h, pad])
-        gh_pages.append(jnp.stack([g, h], axis=-1))
+        gh_pages.append(jnp.stack([g, h]))  # [2, pr_pad]
 
     if cfg.colsample_bytree < 1.0:
         tree_mask = _sample_features_exact(
@@ -723,10 +725,10 @@ def _grow_tree_fused_paged(
     else:
         tree_mask = jnp.ones((F,), bool)
 
-    G0 = sum(gh[:, 0].sum() for gh in gh_pages)
-    H0 = sum(gh[:, 1].sum() for gh in gh_pages)
+    G0 = sum(gh[0].sum() for gh in gh_pages)
+    H0 = sum(gh[1].sum() for gh in gh_pages)
     st = _init_state(cfg, F, G0, H0)
-    pos_pages = [jnp.zeros((pr_pad, 1), jnp.int32) for _ in range(P)]
+    pos_pages = [jnp.zeros((1, pr_pad), jnp.int32) for _ in range(P)]
 
     def page_bins(k: int) -> jax.Array:
         arr = paged.read_page(k)

@@ -120,7 +120,7 @@ def finalize_alloc(alloc: AllocTree, eta, gamma):
     from .hist_kernel import leaf_delta, use_pallas
 
     pad = max(128, 1 << (M - 1).bit_length())
-    delta = leaf_delta(alloc.positions[:, None], lv, pad,
+    delta = leaf_delta(alloc.positions[None, :], lv, pad,
                        pallas=use_pallas())
     return keep, lv, delta
 

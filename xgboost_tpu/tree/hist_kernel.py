@@ -17,8 +17,19 @@ differently than the f32 segment_sum fallback used on non-TPU backends.
 The partition step (route every row through its node's split decision) is
 fused into the same kernel: node decision tables are tiny, so the lookup is
 a one-hot matmul against a ``[nodes, 4]`` table, and the per-row feature
-value is selected with a one-hot dot over the feature axis — no gathers
-anywhere (XLA/Mosaic gathers serialize on TPU).
+value is selected by a second small matmul (every node's split feature out
+of the bins tile) and the row's node one-hot — no gathers anywhere
+(XLA/Mosaic gathers serialize on TPU).
+
+Per-row scalars have the ROWS ON THE LANE AXIS everywhere in this module:
+positions are ``[1, n]`` int32 and gradients ``[2, n]`` float32 (row 0 g,
+row 1 h), from the grower's root to ``leaf_delta``. A ``[n, 1]`` or
+``[n, 2]`` array is padded to 128 lanes in HBM and VMEM on the TPU (512
+bytes a row for 4 or 8) and every VPU op on it works one lane in 128; the
+lane-dense form is laid out one and two sublanes deep in HBM (``T(1,128)``,
+``T(2,128)``: 4 and 8 bytes a row), a ``(k, tr)`` block of it takes at most
+8 sublanes of VMEM (32 bytes a row) and is whole vregs. The XLA and native impls take the
+same form and reshape at their own edge (the C ABI keeps rows major).
 
 Missing values: the quantized matrix encodes missing as bin id ``B``; the
 one-hot over ``[0, B)`` is then all-zero, so missing rows simply drop out of
@@ -132,33 +143,38 @@ def use_native_hist() -> bool:
 
 def fused_level_native(bins, pos, gh, ptab, *, K, Kp, B, d=None,
                        prev_offset=None, offset=None):
-    """Same contract as ``fused_level_xla`` — (new pos [n,1] i32, hist
-    [F, 2K, B] f32, missing excluded) — via the native FFI kernel. Only
-    valid for numerical decision tables (W == 4) on narrow-int bins. The
-    heap offsets derive from static ``d``, or arrive as traced scalars
-    from the depth-scanned driver (one call site for the kernel ABI)."""
+    """Same contract as ``fused_level_xla`` — ``pos`` [1, n] i32 and
+    ``gh`` [2, n] f32 in, (new pos [1, n] i32, hist [F, 2K, B] f32, missing
+    excluded) out — via the native FFI kernel, whose C ABI keeps the rows
+    major (``[n, 1]``, ``[n, 2]``: the same bytes for ``pos``, one small
+    transpose for ``gh``). Only valid for numerical decision tables
+    (W == 4) on narrow-int bins. The heap offsets derive from static
+    ``d``, or arrive as traced scalars from the depth-scanned driver (one
+    call site for the kernel ABI)."""
     from ..native import boundary
 
     n, F = bins.shape
     if prev_offset is None:
         prev_offset = jnp.int32((1 << (d - 1)) - 1 if d > 0 else 0)
         offset = jnp.int32((1 << d) - 1)
-    return boundary.ffi_call(
+    pos_new, hist = boundary.ffi_call(
         "xgbtpu_hb_level",
         (jax.ShapeDtypeStruct((n, 1), jnp.int32),
          jax.ShapeDtypeStruct((F, 2 * K, B), jnp.float32)),
-        bins, pos, gh, ptab,
+        bins, pos.reshape(n, 1), gh.T, ptab,
         prev_offset.astype(jnp.int32), offset.astype(jnp.int32),
         K=K, Kp=Kp, B=B)
+    return pos_new.reshape(1, n), hist
 
 
 def partition_apply(bins, pos, ptab, *, Kp: int, B: int, d: int,
                     pallas: bool = False, axis_name=None):
-    """Route rows through level ``d-1``'s decisions, by the impl the
-    dispatch registry resolves ``level_partition`` to: the Mosaic routing
-    kernel where the call site's ``pallas`` flag is set and the tile fits
-    (TPU: a gather streams at a few GB/s there), the native FFI kernel on
-    the CPU path, XLA everywhere else (identical integer decisions)."""
+    """Route rows (``pos`` [1, n] i32 in, [1, n] i32 out) through level
+    ``d-1``'s decisions, by the impl the dispatch registry resolves
+    ``level_partition`` to: the Mosaic routing kernel where the call
+    site's ``pallas`` flag is set and the tile fits (TPU: a gather streams
+    at a few GB/s there), the native FFI kernel on the CPU path, XLA
+    everywhere else (identical integer decisions)."""
     from ..dispatch import Ctx, resolve
 
     n, F = bins.shape
@@ -179,10 +195,11 @@ def partition_apply(bins, pos, ptab, *, Kp: int, B: int, d: int,
         from ..native import boundary
 
         prev_offset = (1 << (d - 1)) - 1 if d > 0 else 0
-        return boundary.ffi_call(
+        return boundary.ffi_call(  # the C ABI's [n, 1]: the same bytes
             "xgbtpu_hb_partition",
             jax.ShapeDtypeStruct((n, 1), jnp.int32),
-            bins, pos, ptab, Kp=Kp, B=B, prev_offset=prev_offset)
+            bins, pos.reshape(n, 1), ptab, Kp=Kp, B=B,
+            prev_offset=prev_offset).reshape(1, n)
     return partition_apply_xla(bins, pos, ptab, Kp=Kp, B=B, d=d)
 
 
@@ -388,42 +405,63 @@ def _split_hilo(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
 def _partition_tile(pos, binsb, ptab_ref, *, Kp: int, F: int, B: int,
                     prev_offset: int):
     """Route a tile's rows through the previous level's decision table
-    (shared by the level kernels and the routing kernel). ``pos``/``binsb``
-    are values in VMEM. Table layout: ``[Kp, 4]`` numerical (is_split,
-    feature, bin, default_left), or ``[Kp, 5 + B]`` when categorical
-    features exist — column 4 flags a categorical node and columns 5:
-    carry its RIGHT-going category set (evaluate_splits.h Decision: stored
-    sets go right). ``is_split`` is 0 (no split), 1, or 2: a split whose
-    RIGHT child a sibling-subtracting level builds (1: the left), see
-    ``_level_update``; routing reads it as ``> 0.5``."""
-    Tr = binsb.shape[0]
+    (shared by the level kernels and the routing kernel). ``pos`` is
+    ``[1, Tr]`` i32 (rows on the lanes) and so is the result; ``binsb`` is
+    the ``[Tr, F]`` i32 bins tile (rows on the sublanes, as the histogram's
+    one-hot wants it); both are values in VMEM. Table layout: ``[Kp, 4]``
+    numerical (is_split, feature, bin, default_left), or ``[Kp, 5 + B]``
+    when categorical features exist — column 4 flags a categorical node and
+    columns 5: carry its RIGHT-going category set (evaluate_splits.h
+    Decision: stored sets go right). ``is_split`` is 0 (no split), 1, or 2:
+    a split whose RIGHT child a sibling-subtracting level builds (1: the
+    left), see ``_level_update``; routing reads it as ``> 0.5``.
+
+    Every per-row quantity is a ``[1, Tr]`` row or a ``[k, Tr]`` stack of
+    them: the node one-hot is ``[Kp, Tr]``, the decisions ``ptab^T [W, Kp]
+    @ [Kp, Tr]``. The row's bin of its node's split feature comes without
+    a transpose of the bins tile: ``[Kp, F] @ binsb^T`` (contraction on
+    both minor dimensions, the attention ``q @ k^T`` form) gives every
+    node's split feature for every row, ``[Kp, Tr]``, and the node one-hot
+    picks the row's own."""
     W = ptab_ref.shape[-1]
-    lp = pos - prev_offset
-    iota_kp = jax.lax.broadcasted_iota(jnp.int32, (Tr, Kp), 1)
-    ohp = (lp == iota_kp).astype(jnp.float32)
+    Tr = binsb.shape[0]
+    ptab = ptab_ref[:, :]  # [Kp, W]
+    lp = pos - prev_offset  # [1, Tr]
+    iota_kp = jax.lax.broadcasted_iota(jnp.int32, (Kp, Tr), 0)
+    ohp = (lp == iota_kp).astype(jnp.float32)  # [Kp, Tr]
     # f32 table matmul: exact for feature ids / bin ids up to 2^24
     dec = jax.lax.dot_general(
-        ohp, ptab_ref[:, :], (((1,), (0,)), ((), ())),
+        ptab, ohp, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
         precision=jax.lax.Precision.HIGHEST,
-    )  # [Tr, W]
-    isp_of = dec[:, 0:1]
-    f_of = dec[:, 1:2].astype(jnp.int32)
-    b_of = dec[:, 2:3]
-    dl_of = dec[:, 3:4]
-    iota_f = jax.lax.broadcasted_iota(jnp.int32, (Tr, F), 1)
-    ohf = (f_of == iota_f).astype(jnp.float32)
-    bv = jnp.sum(ohf * binsb.astype(jnp.float32), axis=1, keepdims=True)
-    # arithmetic (not boolean) masks: Mosaic rejects i1 vectors at lane 1
+    )  # [W, Tr]
+    isp_of = dec[0:1, :]
+    b_of = dec[2:3, :]
+    dl_of = dec[3:4, :]
+    iota_f = jax.lax.broadcasted_iota(jnp.int32, (Kp, F), 1)
+    ohf = ptab[:, 1:2].astype(jnp.int32) == iota_f  # [Kp, F]
+    # bins 0..B and a 0/1 one-hot are exact in bf16 up to B = 256: one MXU
+    # pass there, f32 at full precision beyond
+    narrow = B <= 256
+    dt = jnp.bfloat16 if narrow else jnp.float32
+    node_bv = jax.lax.dot_general(
+        ohf.astype(jnp.float32).astype(dt),
+        binsb.astype(jnp.float32).astype(dt), (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=None if narrow else jax.lax.Precision.HIGHEST,
+    )  # [Kp, Tr]: bins[row, feature of node]
+    bv = jnp.sum(ohp * node_bv, axis=0, keepdims=True)  # [1, Tr]
+    # arithmetic (not boolean) masks: the numerical and the categorical
+    # decision are one expression, with no select between i1 vectors
     missing = (bv >= B).astype(jnp.float32)
     leq = (bv <= b_of).astype(jnp.float32)
     if W > 4:
-        isc_of = dec[:, 4:5]
-        setrow = dec[:, 5:]  # [Tr, B] the node's right-going set
-        iota_b = jax.lax.broadcasted_iota(jnp.int32, (Tr, W - 5), 1)
+        isc_of = dec[4:5, :]
+        setrow = dec[5:, :]  # [B, Tr] the node's right-going set
+        iota_b = jax.lax.broadcasted_iota(jnp.int32, (W - 5, Tr), 0)
         member = jnp.sum(
             (bv == iota_b.astype(jnp.float32)).astype(jnp.float32) * setrow,
-            axis=1, keepdims=True)
+            axis=0, keepdims=True)
         present_left = isc_of * (1.0 - member) + (1.0 - isc_of) * leq
     else:
         present_left = leq
@@ -435,36 +473,37 @@ def _partition_tile(pos, binsb, ptab_ref, *, Kp: int, F: int, B: int,
 
 
 def _grad_channels(node, ids, gh_ref):
-    """[Tr, 4K] bf16 per-node gradient channels; column order
+    """[4K, Tr] bf16 per-node gradient channels; row order
     [g_hi | h_hi | g_lo | h_lo] so ``out[:2K] + out[2K:] = [g, h]``. A row
-    contributes to the channel group whose id (``ids``: ``[Tr, K]`` or
-    ``[1, K]`` i32) its ``node`` (``[Tr, 1]`` i32) is, or to none."""
-    ohseg = (node == ids).astype(jnp.float32)  # [Tr, K]
-    g = gh_ref[:, 0:1]
-    h = gh_ref[:, 1:2]
+    of the data (a lane here) contributes to the channel group whose id
+    (``ids``: ``[K, Tr]`` or ``[K, 1]`` i32) its ``node`` (``[1, Tr]`` i32)
+    is, or to none. ``gh_ref`` is the ``(2, Tr)`` block: g above h."""
+    ohseg = (node == ids).astype(jnp.float32)  # [K, Tr]
+    g = gh_ref[0:1, :]
+    h = gh_ref[1:2, :]
     g_hi, g_lo = _split_hilo(g)
     h_hi, h_lo = _split_hilo(h)
     return jnp.concatenate(
-        [ohseg * g_hi, ohseg * h_hi, ohseg * g_lo, ohseg * h_lo], axis=1
-    ).astype(jnp.bfloat16)  # [Tr, 4K]
+        [ohseg * g_hi, ohseg * h_hi, ohseg * g_lo, ohseg * h_lo], axis=0
+    ).astype(jnp.bfloat16)  # [4K, Tr]
 
 
 def _route_and_channels(pos, binsb, gh_ref, ptab_ref, built_ref, *, K: int,
                         Kp: int, F: int, B: int, prev_offset: int,
                         offset: int):
-    """The level kernels' shared head: route the tile's rows through the
-    previous level's decisions, then form the gradient channels. Direct
-    build (``built_ref`` None): one channel group a node of this level,
-    ``[Tr, 4K]``. Sibling subtraction: one a PARENT, ``[Tr, 4Kp]``, for the
-    rows now at the child that parent marked, whose heap index
-    ``built_ref`` ``[1, Kp]`` holds (-1: the parent did not split; a row
-    that stayed above this level is at no child). The sibling is
-    ``parent - built``, taken outside (``derive_siblings``)."""
+    """The level kernels' shared head: route the tile's rows (``pos``
+    ``[1, Tr]``) through the previous level's decisions, then form the
+    gradient channels. Direct build (``built_ref`` None): one channel group
+    a node of this level, ``[4K, Tr]``. Sibling subtraction: one a PARENT,
+    ``[4Kp, Tr]``, for the rows now at the child that parent marked, whose
+    heap index ``built_ref`` ``[Kp, 1]`` holds (-1: the parent did not
+    split; a row that stayed above this level is at no child). The sibling
+    is ``parent - built``, taken outside (``derive_siblings``)."""
     if Kp > 0:
         pos = _partition_tile(pos, binsb, ptab_ref, Kp=Kp, F=F, B=B,
                               prev_offset=prev_offset)
     if built_ref is None:
-        iota_k = jax.lax.broadcasted_iota(jnp.int32, (pos.shape[0], K), 1)
+        iota_k = jax.lax.broadcasted_iota(jnp.int32, (K, pos.shape[1]), 0)
         return pos, _grad_channels(pos - offset, iota_k, gh_ref)
     return pos, _grad_channels(pos, built_ref[:, :], gh_ref)
 
@@ -474,9 +513,10 @@ def _level_kernel(bins_ref, pos_ref, gh_ref, ptab_ref, *rest,
                   prev_offset: int, offset: int):
     """One grid step: partition `Tr` rows through the previous level's
     decisions, then accumulate their (g, h) into this level's histogram.
-    ``rest``: the outputs ``pos_out, hist_ref``, behind ``built_ref`` where
-    siblings are subtracted (the histogram is then the built children's,
-    ``Kc = Kp`` nodes wide)."""
+    ``pos_ref`` and ``gh_ref`` are the ``(1, Tr)`` and ``(2, Tr)`` blocks of
+    the lane-dense arrays. ``rest``: the outputs ``pos_out, hist_ref``,
+    behind ``built_ref`` where siblings are subtracted (the histogram is
+    then the built children's, ``Kc = Kp`` nodes wide)."""
     from jax.experimental import pallas as pl
 
     *built_ref, pos_out, hist_ref = rest
@@ -491,7 +531,7 @@ def _level_kernel(bins_ref, pos_ref, gh_ref, ptab_ref, *rest,
     binsb = bins_ref[:, :]  # [Tr, F] i32
     Tr = binsb.shape[0]
     Kc = K if built_ref is None else Kp
-    # pos: [Tr, 1] i32 heap positions
+    # pos: [1, Tr] i32 heap positions
     pos, ghs4 = _route_and_channels(
         pos_ref[:, :], binsb, gh_ref, ptab_ref, built_ref, K=K, Kp=Kp, F=F,
         B=B, prev_offset=prev_offset, offset=offset)
@@ -502,9 +542,9 @@ def _level_kernel(bins_ref, pos_ref, gh_ref, ptab_ref, *rest,
         iota_b = jax.lax.broadcasted_iota(jnp.int32, (Tr, B), 1)
         oh = (col == iota_b).astype(jnp.bfloat16)  # missing (==B) -> zero row
         out = jax.lax.dot_general(
-            ghs4, oh, (((0,), (0,)), ((), ())),
+            ghs4, oh, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )  # [4Kc, B]
+        )  # [4Kc, Tr] @ [Tr, B]
         hist_ref[f, :, :] += out[:2 * Kc] + out[2 * Kc:]
 
 
@@ -518,11 +558,12 @@ def _vma_struct(shape, dtype, axes):
 
 
 def _built_children(ptab, *, Kp: int, d: int, sub: bool):
-    """The extra operand of a sibling-subtracting level kernel: ``[1, Kp]``
-    i32, for every parent the heap index of the child its decision table
-    marks to be built (``is_split`` 1: the left, 2: the right), -1 where it
-    does not split. Returns (arrays, block specs): empty for the direct
-    build, whose kernels take no such operand."""
+    """The extra operand of a sibling-subtracting level kernel: ``[Kp, 1]``
+    i32 (a column: the channels compare it with the rows' ``[1, Tr]``
+    positions), for every parent the heap index of the child its decision
+    table marks to be built (``is_split`` 1: the left, 2: the right), -1
+    where it does not split. Returns (arrays, block specs): empty for the
+    direct build, whose kernels take no such operand."""
     if not sub:
         return [], []
     from jax.experimental import pallas as pl
@@ -531,8 +572,8 @@ def _built_children(ptab, *, Kp: int, d: int, sub: bool):
     assert Kp > 0, "the root has no parent to subtract from"
     mark = ptab[:, 0].astype(jnp.int32)
     parent = ((1 << (d - 1)) - 1) + jnp.arange(Kp, dtype=jnp.int32)
-    built = jnp.where(mark > 0, 2 * parent + mark, -1)[None, :]
-    return [built], [pl.BlockSpec((1, Kp), lambda c: (0, 0),
+    built = jnp.where(mark > 0, 2 * parent + mark, -1)[:, None]
+    return [built], [pl.BlockSpec((Kp, 1), lambda c: (0, 0),
                                   memory_space=pltpu.VMEM)]
 
 
@@ -559,18 +600,18 @@ def _fused_level_pallas(bins, pos, gh, ptab, *, K, Kp, B, d, tr=TR,
         grid=(n // tr,),
         in_specs=[
             pl.BlockSpec((tr, F), lambda c: (c, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((tr, 1), lambda c: (c, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((tr, 2), lambda c: (c, 0), memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, tr), lambda c: (0, c), memory_space=pltpu.VMEM),
+            pl.BlockSpec((2, tr), lambda c: (0, c), memory_space=pltpu.VMEM),
             pl.BlockSpec((max(Kp, 1), W), lambda c: (0, 0),
                          memory_space=pltpu.VMEM),
         ] + built_specs,
         out_specs=[
-            pl.BlockSpec((tr, 1), lambda c: (c, 0), memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, tr), lambda c: (0, c), memory_space=pltpu.VMEM),
             pl.BlockSpec((F, 2 * Kc, B), lambda c: (0, 0, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            _vma_struct((n, 1), jnp.int32, vma),
+            _vma_struct((1, n), jnp.int32, vma),
             _vma_struct((F, 2 * Kc, B), jnp.float32, vma),
         ],
         interpret=_INTERPRET,
@@ -580,11 +621,12 @@ def _fused_level_pallas(bins, pos, gh, ptab, *, K, Kp, B, d, tr=TR,
 def _hoisted_kernel(bins_ref, oh_ref, pos_ref, gh_ref, ptab_ref, *rest,
                     K: int, Kp: int, F: int, Fh: int, B: int,
                     prev_offset: int, offset: int):
-    """Hoisted-one-hot grid step: partition + grad channels (cheap VPU),
-    ONE [4Kc, Tr] x [Tr, Fh*B] MXU matmul streaming the resident one-hot
-    for the first ``Fh`` features, and an in-kernel construct loop for the
-    remaining ``F - Fh`` (empty when the full expansion fit HBM). ``rest``
-    and ``Kc`` as in ``_level_kernel``."""
+    """Hoisted-one-hot grid step: partition + grad channels (cheap VPU, on
+    whole vregs: rows on the lanes), ONE [4Kc, Tr] x [Tr, Fh*B] MXU matmul
+    streaming the resident one-hot for the first ``Fh`` features, and an
+    in-kernel construct loop for the remaining ``F - Fh`` (empty when the
+    full expansion fit HBM). ``pos_ref``, ``gh_ref``, ``rest`` and ``Kc``
+    as in ``_level_kernel``."""
     from jax.experimental import pallas as pl
 
     *built_ref, pos_out, hist_ref = rest
@@ -599,14 +641,14 @@ def _hoisted_kernel(bins_ref, oh_ref, pos_ref, gh_ref, ptab_ref, *rest,
     binsb = bins_ref[:, :]
     Tr = binsb.shape[0]
     Kc = K if built_ref is None else Kp
-    pos, ghs4 = _route_and_channels(  # ghs4: [Tr, 4Kc]
+    pos, ghs4 = _route_and_channels(  # ghs4: [4Kc, Tr]
         pos_ref[:, :], binsb, gh_ref, ptab_ref, built_ref, K=K, Kp=Kp, F=F,
         B=B, prev_offset=prev_offset, offset=offset)
     pos_out[:, :] = pos
 
     oh = oh_ref[:, :].astype(jnp.bfloat16)  # [Tr, Fh*B] int8 -> bf16
     out = jax.lax.dot_general(
-        ghs4, oh, (((0,), (0,)), ((), ())),
+        ghs4, oh, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )  # [4Kc, Fh*B]
     hist_ref[:, : Fh * B] += out[: 2 * Kc] + out[2 * Kc:]
@@ -615,7 +657,7 @@ def _hoisted_kernel(bins_ref, oh_ref, pos_ref, gh_ref, ptab_ref, *rest,
         iota_b = jax.lax.broadcasted_iota(jnp.int32, (Tr, B), 1)
         ohf = (col == iota_b).astype(jnp.bfloat16)
         outf = jax.lax.dot_general(
-            ghs4, ohf, (((0,), (0,)), ((), ())),
+            ghs4, ohf, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # [4Kc, B]
         hist_ref[:, f * B:(f + 1) * B] += outf[: 2 * Kc] + outf[2 * Kc:]
@@ -650,18 +692,18 @@ def _hoisted_level_pallas(bins, onehot, pos, gh, ptab, *, K, Kp, B, d,
         in_specs=[
             pl.BlockSpec((tr, F), lambda c: (c, 0), memory_space=pltpu.VMEM),
             pl.BlockSpec((tr, Qh), lambda c: (c, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((tr, 1), lambda c: (c, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((tr, 2), lambda c: (c, 0), memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, tr), lambda c: (0, c), memory_space=pltpu.VMEM),
+            pl.BlockSpec((2, tr), lambda c: (0, c), memory_space=pltpu.VMEM),
             pl.BlockSpec((max(Kp, 1), W), lambda c: (0, 0),
                          memory_space=pltpu.VMEM),
         ] + built_specs,
         out_specs=[
-            pl.BlockSpec((tr, 1), lambda c: (c, 0), memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, tr), lambda c: (0, c), memory_space=pltpu.VMEM),
             pl.BlockSpec((2 * Kc, Q), lambda c: (0, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            _vma_struct((n, 1), jnp.int32, vma),
+            _vma_struct((1, n), jnp.int32, vma),
             _vma_struct((2 * Kc, Q), jnp.float32, vma),
         ],
         interpret=_INTERPRET,
@@ -673,8 +715,9 @@ def _hoisted_level_pallas(bins, onehot, pos, gh, ptab, *, K, Kp, B, d,
 
 def _route_kernel(bins_ref, pos_ref, ptab_ref, pos_out, *, Kp: int, F: int,
                   B: int, prev_offset: int):
-    """One grid step of the tree's last routing: ``Tr`` rows through the
-    deepest level's decisions, and nothing else."""
+    """One grid step of the tree's last routing: ``Tr`` rows (a ``(1, Tr)``
+    block of positions in and out) through the deepest level's decisions,
+    and nothing else."""
     pos_out[:, :] = _partition_tile(pos_ref[:, :], bins_ref[:, :], ptab_ref,
                                     Kp=Kp, F=F, B=B, prev_offset=prev_offset)
 
@@ -698,12 +741,12 @@ def _route_rows_pallas(bins, pos, ptab, *, Kp, B, d, tr=TR, vma=()):
         grid=(n // tr,),
         in_specs=[
             pl.BlockSpec((tr, F), lambda c: (c, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((tr, 1), lambda c: (c, 0), memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, tr), lambda c: (0, c), memory_space=pltpu.VMEM),
             pl.BlockSpec((Kp, W), lambda c: (0, 0), memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec((tr, 1), lambda c: (c, 0),
+        out_specs=pl.BlockSpec((1, tr), lambda c: (0, c),
                                memory_space=pltpu.VMEM),
-        out_shape=_vma_struct((n, 1), jnp.int32, vma),
+        out_shape=_vma_struct((1, n), jnp.int32, vma),
         interpret=_INTERPRET,
     )(bins, pos, ptab)
 
@@ -714,11 +757,13 @@ def partition_apply_xla(bins, pos, ptab, *, Kp: int, B: int, d: int,
     it matters: the per-node table lookup is a one-hot matmul). Handles
     both table layouts — see ``_partition_tile``. ``prev_offset`` may be a
     TRACED scalar (the depth-scanned grow passes ``2^(d-1) - 1`` computed
-    inside the scan body); when None it is derived statically from ``d``."""
+    inside the scan body); when None it is derived statically from ``d``.
+    ``pos`` is ``[1, n]`` in and out; the body works on its one row."""
     if prev_offset is None:
         prev_offset = (1 << (d - 1)) - 1 if d > 0 else 0
     W = ptab.shape[1]
-    lp = pos[:, 0] - prev_offset  # [n]
+    p = pos[0]
+    lp = p - prev_offset  # [n]
     ohp = jax.nn.one_hot(jnp.where((lp >= 0) & (lp < Kp), lp, Kp),
                          Kp + 1, dtype=jnp.float32)[:, :Kp]  # [n, Kp]
     dec = jax.lax.dot_general(ohp, ptab, (((1,), (0,)), ((), ())),
@@ -741,25 +786,25 @@ def partition_apply_xla(bins, pos, ptab, *, Kp: int, B: int, d: int,
     goleft = jnp.where(missing, dl_of > 0.5, present_left)
     inb = (lp >= 0) & (lp < Kp)
     goes = inb & (isp_of > 0.5)
-    p = pos[:, 0]
     p = jnp.where(goes, jnp.where(goleft, 2 * p + 1, 2 * p + 2), p)
-    return p[:, None]
+    return p[None, :]
 
 
 @guard_jit(name="fused_level_xla", static_argnames=("K", "Kp", "B", "d"))
 def fused_level_xla(bins, pos, gh, ptab, *, K, Kp, B, d):
-    """Same contract as the pallas kernel, for non-TPU backends: partition
-    via (cheap on CPU) gathers, histogram via segment_sum scatter-add."""
+    """Same contract as the pallas kernel (``pos`` [1, n], ``gh`` [2, n]),
+    for non-TPU backends: partition via (cheap on CPU) gathers, histogram
+    via segment_sum scatter-add over the rows-major ``gh.T``."""
     if Kp > 0:
         pos = partition_apply_xla(bins, pos, ptab, Kp=Kp, B=B, d=d)
     offset = (1 << d) - 1
-    local = pos[:, 0] - offset
+    local = pos[0] - offset
     n, F = bins.shape
     seg = jnp.where((local >= 0) & (local < K), local, -1)
     MB = B + 1
     from .grow import blocked_histogram
 
-    hist = blocked_histogram(bins, gh, seg, K, MB)  # [K, F, MB, 2]
+    hist = blocked_histogram(bins, gh.T, seg, K, MB)  # [K, F, MB, 2]
     # -> kernel layout [F, 2K, B] (drop the missing bin: recovered by caller)
     hg = jnp.transpose(hist[:, :, :B, 0], (1, 0, 2))  # [F, K, B]
     hh = jnp.transpose(hist[:, :, :B, 1], (1, 0, 2))
@@ -780,13 +825,13 @@ def fused_level_scanned(bins, pos, gh, ptab, prev_offset, offset, *,
                                   prev_offset=prev_offset, offset=offset)
     pos = partition_apply_xla(bins, pos, ptab, Kp=K, B=B, d=-1,
                               prev_offset=prev_offset)
-    local = pos[:, 0] - offset
+    local = pos[0] - offset
     n, F = bins.shape
     seg = jnp.where((local >= 0) & (local < K), local, -1)
     MB = B + 1
     from .grow import blocked_histogram
 
-    hist = blocked_histogram(bins, gh, seg, K, MB)  # [K, F, MB, 2]
+    hist = blocked_histogram(bins, gh.T, seg, K, MB)  # [K, F, MB, 2]
     hg = jnp.transpose(hist[:, :, :B, 0], (1, 0, 2))  # [F, K, B]
     hh = jnp.transpose(hist[:, :, :B, 1], (1, 0, 2))
     return pos, jnp.concatenate([hg, hh], axis=1)  # [F, 2K, B]
@@ -839,22 +884,30 @@ def pallas_level_fits(rows: int, F: int, K: int, B: int,
 
 def pallas_route_fits(rows: int, F: int, Kp: int, W: int) -> bool:
     """Whether the routing kernel (``_route_rows_pallas``) fits: rows in
-    whole ``TR`` tiles and one grid step's working set inside the budget
-    the hoisted step has. Minor dimensions count at the 128 lanes they
-    occupy: double-buffered bins, positions in and out and the decision
-    table, plus the tile's f32 intermediates (the node one-hot, the
-    decisions, and three ``[TR, F]`` for the feature select). That bound
-    is tighter than ``_MAX_KERNEL_FEATURES`` (F of 384 at most, with any
-    table), and the kernel unrolls no loop over F. The
-    ``level_partition`` registry predicate is its one caller, as
-    ``pallas_level_fits`` is ``level_hist``'s."""
-    def lanes(w):
-        return -(-w // 128) * 128
+    whole ``TR`` tiles, a width the level kernels take too
+    (``_MAX_KERNEL_FEATURES``: wider, no Mosaic kernel of this module has
+    been compiled) and one grid step's working set inside the budget the
+    hoisted step has. Counted at the tiles they occupy (8 sublanes, 128
+    lanes). Blocks, double-buffered: the ``(TR, F)`` i32 bins tile,
+    positions in and out as ``(1, TR)`` rows (8 sublanes each, 32 bytes a
+    row of data where the ``(TR, 1)`` columns took 512), the ``(Kp, W)``
+    decision table. Values of ``_partition_tile``: the bins tile as loaded
+    with its f32 and bf16 casts, every node's ``[Kp, F]`` feature one-hot,
+    three ``[Kp, TR]`` (node one-hot, the nodes' bins, their product), the
+    ``[W, TR]`` decisions, two ``[B, TR]`` for a categorical table's set
+    lookup, and sixteen ``[1, TR]`` rows. The ``level_partition`` registry
+    predicate is its one caller, as ``pallas_level_fits`` is
+    ``level_hist``'s."""
+    def up(x, m):
+        return -(-x // m) * m
 
-    step = 4 * (2 * TR * lanes(F) + 4 * TR * 128 + 2 * Kp * lanes(W)
-                + TR * (lanes(Kp) + lanes(W) + 3 * lanes(F)))
-    return (rows > 0 and rows % TR == 0 and F > 0 and Kp > 0
-            and step <= _VMEM_HOIST_BUDGET)
+    lanes_f, kp8 = up(F, 128), up(Kp, 8)
+    blocks = 2 * 4 * (TR * lanes_f + 2 * 8 * TR + kp8 * up(W, 128))
+    values = (TR * lanes_f * (4 + 4 + 2) + kp8 * lanes_f * (4 + 2)
+              + 4 * TR * (3 * kp8 + up(W, 8) + 16 * 8
+                          + (2 * up(W - 5, 8) if W > 4 else 0)))
+    return (rows > 0 and rows % TR == 0 and 0 < F <= _MAX_KERNEL_FEATURES
+            and Kp > 0 and blocks + values <= _VMEM_HOIST_BUDGET)
 
 
 def derive_siblings(parent_hist, built, ptab):
@@ -883,8 +936,10 @@ def fused_level(bins, pos, gh, ptab, *, K, Kp, B, d, pallas: bool,
                 onehot: Optional[jax.Array] = None,
                 axis_name: Optional[str] = None,
                 sibling_sub: bool = False):
-    """Dispatch: (new pos [n,1] i32, hist [F, 2K, B] f32). ``hist`` excludes
-    the missing bin (derive per-feature missing sums as total - sum).
+    """Dispatch: ``pos`` [1, n] i32 and ``gh`` [2, n] f32 (row 0 g, row 1
+    h) in, (new pos [1, n] i32, hist [F, 2K, B] f32) out: rows on the lane
+    axis, for every impl. ``hist`` excludes the missing bin (derive
+    per-feature missing sums as total - sum).
     The impl is resolved through the kernel dispatch registry
     (``dispatch.resolve("level_hist", ...)`` — pins, degrade state and
     platform preference in one lookup). ``onehot`` (the HBM-resident
@@ -931,21 +986,22 @@ def fused_level(bins, pos, gh, ptab, *, K, Kp, B, d, pallas: bool,
 
 
 def leaf_delta(pos, leaf_values, max_nodes_pad: int, pallas: bool):
-    """Prediction-cache delta: ``leaf_values[pos]`` for every row, as an
-    exact one-hot matmul (TPU) or a plain gather (CPU). Leaf values are
-    split into THREE bf16 terms (24 significand bits = exact f32) so the
-    cache never drifts from the materialized model. This is the
-    UpdatePredictionCache fast path (reference ``gbtree.cc:219``).
+    """Prediction-cache delta ``[n]``: ``leaf_values[pos]`` for every row
+    of ``pos`` ``[1, n]``, as an exact one-hot matmul (TPU) or a plain
+    gather (CPU). Leaf values are split into THREE bf16 terms (24
+    significand bits = exact f32) so the cache never drifts from the
+    materialized model; the dot is ``tab^T [3, P] @ onehot [P, n]``, its
+    result ``[3, n]`` (rows on the lanes, no padded ``[n, 3]``). This is
+    the UpdatePredictionCache fast path (reference ``gbtree.cc:219``).
     ``pallas`` is the caller's platform flag; the impl resolves through
     the ``leaf_delta`` registry row, so pins apply and the route is
     counted for every grower."""
     from ..dispatch import Ctx, resolve
 
-    p = pos[:, 0]
     dec = resolve("leaf_delta", Ctx(platform=jax.default_backend(),
                                     pallas=bool(pallas)))
     if dec.impl != "pallas":
-        return leaf_values[jnp.clip(p, 0, leaf_values.shape[0] - 1)]
+        return leaf_values[jnp.clip(pos[0], 0, leaf_values.shape[0] - 1)]
     lv = jnp.zeros((max_nodes_pad,), jnp.float32).at[:leaf_values.shape[0]].set(leaf_values)
 
     def bf_mask(x):
@@ -956,8 +1012,8 @@ def leaf_delta(pos, leaf_values, max_nodes_pad: int, pallas: bool):
     r = lv - hi
     mid = bf_mask(r)
     lo = r - mid
-    tab = jnp.stack([hi, mid, lo], axis=1).astype(jnp.bfloat16)  # [P, 3]
-    oh = jax.nn.one_hot(p, max_nodes_pad, dtype=jnp.bfloat16)
-    out = jax.lax.dot_general(oh, tab, (((1,), (0,)), ((), ())),
-                              preferred_element_type=jnp.float32)  # [n, 3]
-    return out[:, 0] + out[:, 1] + out[:, 2]
+    tab = jnp.stack([hi, mid, lo]).astype(jnp.bfloat16)  # [3, P]
+    oh = jax.nn.one_hot(pos[0], max_nodes_pad, dtype=jnp.bfloat16, axis=0)
+    out = jax.lax.dot_general(tab, oh, (((1,), (0,)), ((), ())),
+                              preferred_element_type=jnp.float32)  # [3, n]
+    return out[0] + out[1] + out[2]

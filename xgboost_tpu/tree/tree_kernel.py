@@ -73,10 +73,12 @@ def tree_ffi_ready() -> bool:
 def tree_grow_native(bins, gh, cut_values, tree_mask, G0, H0, *,
                      max_depth: int, B: int, sibling_sub: bool,
                      hist_acc: str, split):
-    """One boosting round's depth loop as a single custom call.
+    """One boosting round's depth loop as a single custom call. ``gh`` is
+    the grower's ``[2, n]`` (rows on the lanes); the C ABI keeps the rows
+    major, so it goes in transposed and ``pos`` comes back reshaped.
 
     Returns ``(pos, is_split, feature, split_bin, split_cond, default_left,
-    node_g, node_h, node_w, loss_chg)`` — ``pos`` [n, 1] i32 already routed
+    node_g, node_h, node_w, loss_chg)`` — ``pos`` [1, n] i32 already routed
     into the LEAF level (the driver's final ``partition_apply`` is folded
     in), the rest heap arrays of ``max_nodes = 2^(max_depth+1) - 1``
     matching ``_level_update``'s state contract bit-for-bit (sub off +
@@ -92,7 +94,7 @@ def tree_grow_native(bins, gh, cut_values, tree_mask, G0, H0, *,
     n, F = bins.shape
     max_nodes = (1 << (max_depth + 1)) - 1
     mn = (max_nodes,)
-    return boundary.ffi_call(
+    pos, *heap = boundary.ffi_call(
         "xgbtpu_tree_grow",
         (jax.ShapeDtypeStruct((n, 1), jnp.int32),
          jax.ShapeDtypeStruct(mn, jnp.bool_),     # is_split
@@ -104,7 +106,7 @@ def tree_grow_native(bins, gh, cut_values, tree_mask, G0, H0, *,
          jax.ShapeDtypeStruct(mn, jnp.float32),   # node_h
          jax.ShapeDtypeStruct(mn, jnp.float32),   # node_w
          jax.ShapeDtypeStruct(mn, jnp.float32)),  # loss_chg
-        bins, gh, cut_values, tree_mask.astype(jnp.int32),
+        bins, gh.T, cut_values, tree_mask.astype(jnp.int32),
         G0.astype(jnp.float32), H0.astype(jnp.float32),
         max_depth=int(max_depth), B=int(B),
         sibling_sub=int(bool(sibling_sub)),
@@ -113,12 +115,14 @@ def tree_grow_native(bins, gh, cut_values, tree_mask, G0, H0, *,
         reg_alpha=np.float32(split.reg_alpha),
         max_delta_step=np.float32(split.max_delta_step),
         min_child_weight=np.float32(split.min_child_weight))
+    return (pos.reshape(1, n), *heap)
 
 
 def fused_level_sub_native(bins, pos, gh, ptab, prev_hist, *, K: int,
                            Kp: int, B: int, d: int):
-    """Same contract as ``fused_level_native`` — (new pos [n,1] i32, hist
-    [F, 2K, B] f32) — but building only the smaller child of each sibling
+    """Same contract as ``fused_level_native`` — ``pos`` [1, n] and ``gh``
+    [2, n] in, (new pos [1, n] i32, hist [F, 2K, B] f32) out, rows-major
+    only across the C ABI — but building only the smaller child of each sibling
     pair and deriving the other as parent − child from ``prev_hist`` (the
     previous level's [F, 2Kp, B]). Only valid at ``d >= 1``. This is one
     level of the whole-tree kernel with subtraction on, replayed alone:
@@ -129,12 +133,13 @@ def fused_level_sub_native(bins, pos, gh, ptab, prev_hist, *, K: int,
     n, F = bins.shape
     prev_offset = jnp.int32((1 << (d - 1)) - 1)
     offset = jnp.int32((1 << d) - 1)
-    return boundary.ffi_call(
+    pos_new, hist = boundary.ffi_call(
         "xgbtpu_hb_level_sub",
         (jax.ShapeDtypeStruct((n, 1), jnp.int32),
          jax.ShapeDtypeStruct((F, 2 * K, B), jnp.float32)),
-        bins, pos, gh, ptab, prev_hist, prev_offset, offset,
+        bins, pos.reshape(n, 1), gh.T, ptab, prev_hist, prev_offset, offset,
         K=K, Kp=Kp, B=B)
+    return pos_new.reshape(1, n), hist
 
 
 def fused_level_quant_native(bins, pos, gh, ptab, prev_hist_q, *, K: int,
@@ -144,7 +149,8 @@ def fused_level_quant_native(bins, pos, gh, ptab, prev_hist_q, *, K: int,
     ``gh`` (identical to the whole-tree kernel's per-round computation),
     partition, per-node row lists, packed-integer accumulation and (with
     ``sibling_sub``) EXACT integer sibling derivation from
-    ``prev_hist_q``. Returns ``(new pos [n,1] i32, hist_q [F, 2K, B, 2]
+    ``prev_hist_q``. ``pos`` [1, n] and ``gh`` [2, n] as everywhere above
+    the C ABI. Returns ``(new pos [1, n] i32, hist_q [F, 2K, B, 2]
     i32, hist_f [F, 2K, B] f32)`` — ``hist_q`` is the level's int64
     histogram as packed little-endian int32 word pairs (carried between
     levels so no f32 rounding ever touches the running sums; jax x64
@@ -156,10 +162,11 @@ def fused_level_quant_native(bins, pos, gh, ptab, prev_hist_q, *, K: int,
     n, F = bins.shape
     prev_offset = jnp.int32((1 << max(d - 1, 0)) - 1)
     offset = jnp.int32((1 << d) - 1)
-    return boundary.ffi_call(
+    pos_new, hist_q, hist_f = boundary.ffi_call(
         "xgbtpu_hb_level_quant",
         (jax.ShapeDtypeStruct((n, 1), jnp.int32),
          jax.ShapeDtypeStruct((F, 2 * K, B, 2), jnp.int32),
          jax.ShapeDtypeStruct((F, 2 * K, B), jnp.float32)),
-        bins, pos, gh, ptab, prev_hist_q, prev_offset, offset,
-        K=K, Kp=Kp, B=B, sibling_sub=int(bool(sibling_sub)))
+        bins, pos.reshape(n, 1), gh.T, ptab, prev_hist_q, prev_offset,
+        offset, K=K, Kp=Kp, B=B, sibling_sub=int(bool(sibling_sub)))
+    return pos_new.reshape(1, n), hist_q, hist_f
