@@ -1508,8 +1508,10 @@ class GBTree:
         Under an active mesh the whole chunk runs inside one shard_map
         (distributed_boost_rounds_scan)."""
         t0 = _time.perf_counter()
+        per_round = self.n_groups * self.gbtree_param.num_parallel_tree
         with _trace.span("scan_chunk", start=start_iteration,
-                         rounds=num_rounds):
+                         rounds=num_rounds, groups=self.n_groups,
+                         trees=per_round * num_rounds):
             out = self._boost_rounds_scan_impl(
                 binned, obj, label, weight, margin, start_iteration,
                 num_rounds, feature_weights)
@@ -1605,7 +1607,8 @@ class GBTree:
                     obj_fp=_obj_fingerprint(obj), cfg=cfg, n=n, n_pad=n_pad,
                     n_groups=K, n_parallel=npt,
                 )
-        with _trace.span("chunk.commit"):
+        with _trace.span("chunk.commit", groups=K,
+                         trees=len(groups) * num_rounds):
             if use_mesh:
                 from ..parallel.mesh import local_rows
 

@@ -205,10 +205,27 @@ class Booster:
             # local training and takes the normal path.
             self.update_many(dtrain, iteration, 1, chunk=1)
             return
+        before = self._num_trees()
         with _trace.span("update", iteration=iteration):
             self._update(dtrain, iteration, fobj)
         _REGISTRY.counter(
             "rounds_total", "Boosting rounds dispatched").inc()
+        self._count_trees("round", before)
+
+    def _num_trees(self) -> int:
+        return getattr(getattr(self._gbm, "model", None), "num_trees", 0)
+
+    def _count_trees(self, path: str, before: int) -> None:
+        """``trees_grown_total``: the trees that entered the model since
+        ``before``, G x ``num_parallel_tree`` a round: by a scan chunk
+        (``path="scan"``) or by one ``update`` (``path="round"``). A
+        round of ``process_type=update`` grows none: it re-stats the
+        model's own trees, taking them out and putting them back."""
+        if getattr(self._gbm, "_is_update_process", False):
+            return
+        _REGISTRY.counter(
+            "trees_grown_total", "Trees grown into the model by path"
+        ).labels(path=path).inc(self._num_trees() - before)
 
     def _update(self, dtrain: DMatrix, iteration: int, fobj=None) -> None:
         fault.begin_version(iteration)
@@ -313,6 +330,7 @@ class Booster:
                 # cache (see _do_boost)
                 entry.margin = None
                 info = dtrain.info
+                before = self._num_trees()
                 _t0 = time.perf_counter()
                 # the label goes up inside the chunk's ``chunk.prepare``
                 # step (gbtree), where a profile shows what it costs
@@ -343,6 +361,7 @@ class Booster:
                     raise
                 _REGISTRY.counter(
                     "rounds_total", "Boosting rounds dispatched").inc(k)
+                self._count_trees("scan", before)
                 done += k
             finally:
                 _flight.RECORDER.end_round()
@@ -484,7 +503,8 @@ class Booster:
         self._configure()
         fault.inject("eval")
         evals = list(evals)
-        with _trace.span("eval", iteration=iteration, n_sets=len(evals)):
+        with _trace.span("eval", iteration=iteration, n_sets=len(evals),
+                         rows=sum(d.num_row() for d, _ in evals)):
             return self._eval_set(evals, iteration, feval)
 
     def _eval_set(self, evals, iteration: int, feval=None) -> str:

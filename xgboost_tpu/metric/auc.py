@@ -29,7 +29,10 @@ def _dist_mean(local: float, local_w: float) -> float:
     return s / w if w > 0 else float("nan")
 
 
+# the scope goes UNDER the jit: one opened round a jitted call from outside
+# does not enter its program, and a device profile would not name these ops
 @jax.jit
+@jax.named_scope("xgb.eval_metric")
 def _binary_auc(score: jax.Array, label: jax.Array, weight: jax.Array) -> jax.Array:
     n = score.shape[0]
     order = jnp.argsort(score)
@@ -51,6 +54,7 @@ def _binary_auc(score: jax.Array, label: jax.Array, weight: jax.Array) -> jax.Ar
 
 
 @partial(jax.jit, static_argnames=("n_groups",))
+@jax.named_scope("xgb.eval_metric")
 def _grouped_auc(score, label, weight, group_of, n_groups):
     """Per-group binary AUCs, averaged over groups that have both classes —
     segmented version of ``_binary_auc`` (one lexsort + segment_sums; the
