@@ -389,6 +389,51 @@ def test_sibling_sub_kernels_keep_their_names_and_halve_the_output_rows(
     assert summary.is_level_kernel(lines[0].removeprefix("ROOT "))
 
 
+# a round's trees in one pass (ISSUE 34): Cover Type's six levels
+@pytest.mark.parametrize("d,T,tr,rows", [
+    (0, 8, 256, 16), (1, 8, 256, 16), (2, 8, 128, 32), (3, 8, 128, 64),
+    (4, 4, 128, 64), (5, 2, 128, 64), (3, 8, 0, 64)])
+def test_level_calls_that_carry_trees_compile_under_their_names(
+        one_v5e_chip, d, T, tr, rows):
+    """The level call of T class trees at Cover Type's width (54 columns x
+    256 bins, 33 resident; ``tr`` 0: no resident one-hot, a narrow matrix's
+    construct-only kernel), compiled for a described v5e: positions
+    ``s32[T, n]``, the accumulator ``2 T Kc`` rows, and the name the
+    benchmark's reduction books to the level histogram."""
+    import jax.numpy as jnp
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
+
+    n, F, B, Fh = 8192, (54 if tr else 12), 256, (33 if tr else 0)
+    K, Kp = 1 << d, (1 << d) >> 1
+    Kc = Kp if d else K
+    assert hk.level_trees(n, F, Kc, B, 8, Fh * B) == T
+    shapes = [S((n, F), jnp.int32), S((T, n), jnp.int32),
+              S((2 * T, n), jnp.float32), S((T, max(Kp, 1), 4), jnp.float32)]
+    if tr:
+        assert hk._hoist_tr(Fh * B, T * Kc, F, B) == tr
+        shapes.append(S((n, Fh * B), jnp.int8))
+        kernel, out = "_hoisted_level_pallas", f"f32[{rows},{F * B}]"
+    else:
+        kernel, out = "_fused_level_pallas", f"f32[{F},{rows},{B}]"
+
+    def level(bins, pos, gh, ptab, onehot=None):
+        with jax.named_scope("xgb.level_hist"):  # as grow_fused has it
+            return hk.fused_level_trees(bins, pos, gh, ptab, K=K, Kp=Kp, B=B,
+                                        d=d, onehot=onehot,
+                                        sibling_sub=d > 0)
+
+    lines = _mosaic_lines(jax.jit(level), *shapes)
+    calls = _calls_of(lines)
+    assert list(calls) == [kernel]
+    assert "/xgb.level_hist/" in calls[kernel]
+    head = lines[0].split(" custom-call(")[0]
+    assert out in head and f"s32[{T},{n}]" in head, lines[0][:300]
+    assert _benchmark_summary().is_level_kernel(
+        lines[0].removeprefix("ROOT "))
+
+
 # rows on the lanes (ISSUE 31): what the chip's compiler is handed
 @pytest.mark.parametrize("kernel,F,Kp,W,B", [
     ("_hoisted_level_pallas", 50, 16, 4, 256),  # the anchor's level 5

@@ -3,7 +3,8 @@
 Public surface (see ``core.py`` for the design notes):
 
 - :func:`resolve` / :class:`Ctx` / :class:`Decision` /
-  :class:`DispatchError` — the lookup.
+  :class:`DispatchError` — the lookup; :func:`note` counts a route a call
+  site took from its own input, beside the resolutions.
 - :func:`register` / :class:`KernelImpl` — add an impl (a GPU backend is
   a table entry).
 - :func:`pinned_off` / :func:`degraded` / :data:`LEGACY_ENVS` —
@@ -23,6 +24,7 @@ from .core import (  # noqa: F401
     degraded,
     explain,
     last_decisions,
+    note,
     op_names,
     pinned_off,
     register,
@@ -34,7 +36,7 @@ from .core import (  # noqa: F401
 
 __all__ = [
     "Ctx", "Decision", "DispatchError", "KernelImpl", "LEGACY_ENVS",
-    "degraded", "explain", "last_decisions", "op_names",
+    "degraded", "explain", "last_decisions", "note", "op_names",
     "pinned_off", "register", "reset", "resolve", "set_report_ctx",
     "table_snapshot",
 ]

@@ -55,7 +55,7 @@ from typing import (Any, Callable, Dict, Hashable, List, NamedTuple,
 
 __all__ = [
     "Ctx", "Decision", "DispatchError", "KernelImpl",
-    "register", "set_report_ctx", "resolve", "explain", "op_names",
+    "register", "set_report_ctx", "resolve", "note", "explain", "op_names",
     "pinned_off", "degraded", "last_decisions", "table_snapshot",
     "reset", "LEGACY_ENVS",
 ]
@@ -459,6 +459,18 @@ def resolve(op: str, ctx: Optional[Ctx] = None,
         _announce_route_change(op, prev, dec)
     _count(dec)
     return dec
+
+
+def note(op: str, impl: Any, reason: str = "observed") -> None:
+    """Count a route a call site took from what it observed in its own
+    input (``level_trees``: how many of a round's trees a level call
+    carried), into the same ``dispatch_decisions_total`` and route table
+    as a resolution. No table row stands behind it: nothing to pin, ban
+    or degrade."""
+    dec = Decision(op, str(impl), reason)
+    with _STATE.lock:
+        _STATE.last[op] = dec
+    _count(dec)
 
 
 def _count(dec: Decision) -> None:

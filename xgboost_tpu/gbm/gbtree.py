@@ -24,7 +24,8 @@ from ..predictor import StackedForest, predict_leaf, predict_margin, stack_fores
 from ..registry import BOOSTERS
 from ..analysis.retrace import guard_jit
 from ..tree.grow import GrowParams, grow_tree, leaf_value_map, prune_heap
-from ..tree.grow_fused import GrownTree, grow_tree_fused
+from ..tree.grow_fused import (GrownTree, _pallas_flag, grow_tree_fused,
+                               grow_trees_one_pass)
 from ..tree.model import RegTree
 from ..tree.param import SplitParams
 from ..utils import console_logger
@@ -618,6 +619,17 @@ def round_seed_traced(seed_base_u32, i, k: int = 0, ptree: int = 0):
             + jnp.uint32(k * 17 + ptree)) & jnp.uint32(0x7FFFFFFF)
 
 
+def _round_in_one_pass(cfg: GrowParams, trees: int) -> bool:
+    """Whether a scanned round's ``trees`` (class trees x
+    ``num_parallel_tree``) are grown level by level together, a level
+    kernel call carrying several trees' gradient channels over one pass of
+    the rows (``grow_fused.grow_trees_one_pass``), in place of one after
+    another: where there is more than one and the Mosaic level kernels
+    run. Observed in the job, chosen by nobody; a job that grows one tree
+    a round traces the class loop's program and nothing else."""
+    return trees > 1 and _pallas_flag(cfg)
+
+
 def _mesh_active() -> bool:
     from ..parallel.mesh import current_mesh
 
@@ -659,11 +671,40 @@ def _scan_rounds_impl(binsf, label, weight, m_pad, iters, cut_vals, eta,
     input margin is dead after the call (update_many re-points the cache
     at the returned one)."""
     K = n_groups
+    one_pass = _round_in_one_pass(cfg, K * n_parallel)
 
     def pad0(v):
         if n_pad == n:
             return v
         return jnp.concatenate([v, jnp.zeros((n_pad - n,), jnp.float32)])
+
+    def body_one_pass(m_pad, i):
+        """The round's trees grown together: the class loop's gradients,
+        keys and margin updates around one ``grow_trees_one_pass``."""
+        with jax.named_scope("xgb.gradient"):
+            m = m_pad[:n, 0] if K == 1 else m_pad[:n]
+            g, h = obj.get_gradient(m, label, weight, i)
+        gks, hks, keys, groups = [], [], [], []
+        for k in range(K):
+            with jax.named_scope("xgb.gradient"):
+                gk = pad0(g[:, k] if g.ndim == 2 else g)
+                hk = pad0(h[:, k] if h.ndim == 2 else h)
+            for pt in range(n_parallel):
+                seed = round_seed_traced(seed_base, i, k, pt)
+                keys.append(jax.random.PRNGKey(seed.astype(jnp.int32)))
+                gks.append(gk)
+                hks.append(hk)
+                groups.append(k)
+        grown = grow_trees_one_pass(binsf, gks, hks, cut_vals, keys, eta,
+                                    gamma, cfg, feature_weights=fw,
+                                    onehot=onehot)
+        for k, t in zip(groups, grown):
+            with jax.named_scope("xgb.leaf_delta"):
+                m_pad = m_pad.at[:, k].add(t.delta)
+        stacked = jax.tree_util.tree_map(
+            lambda *xs: jnp.stack(xs),
+            *[t._replace(delta=jnp.zeros((0,), jnp.float32)) for t in grown])
+        return m_pad, stacked
 
     def body(m_pad, i):
         with jax.named_scope("xgb.gradient"):
@@ -689,7 +730,7 @@ def _scan_rounds_impl(binsf, label, weight, m_pad, iters, cut_vals, eta,
         stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *trees)
         return m_pad, stacked
 
-    return jax.lax.scan(body, m_pad, iters)
+    return jax.lax.scan(body_one_pass if one_pass else body, m_pad, iters)
 
 
 @functools.partial(guard_jit, name="scan_rounds_lossguide",

@@ -29,7 +29,7 @@ replicated arithmetic on identical inputs.
 
 from __future__ import annotations
 
-from typing import NamedTuple, Optional
+from typing import List, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -50,13 +50,15 @@ from .hist_kernel import (
     TR,
     derive_siblings,
     fused_level,
+    fused_level_trees,
     leaf_delta,
+    level_trees,
     partition_apply,
     partition_apply_xla,
 )
 from .param import RT_EPS, calc_weight
 
-__all__ = ["GrownTree", "grow_tree_fused", "pad_rows"]
+__all__ = ["GrownTree", "grow_tree_fused", "grow_trees_one_pass", "pad_rows"]
 
 _INF = float(np.inf)
 
@@ -404,32 +406,10 @@ def _grow_tree_fused_impl(
     B = cut_values.shape[1]
     p = cfg.split
     max_depth = cfg.max_depth
-    max_nodes = cfg.max_nodes
 
-    k_sub, k_ctree, k_level = jax.random.split(key, 3)
-    if cfg.axis_name is not None:
-        k_sub = jax.random.fold_in(k_sub, jax.lax.axis_index(cfg.axis_name))
-
+    gh, tree_mask, k_level, G0, H0, st = _tree_start(
+        grad, hess, key, feature_weights, cfg, F, B)
     with jax.named_scope("xgb.root"):
-        grad, hess = apply_row_sampling(cfg, k_sub, grad, hess)
-        # per-row arrays of the tree have the rows on the LANE axis from
-        # here to ``leaf_delta`` (hist_kernel's module docstring)
-        gh = jnp.stack([grad, hess])  # [2, n]
-
-        if cfg.colsample_bytree < 1.0:
-            tree_mask = _sample_features_exact(
-                k_ctree, F, cfg.colsample_bytree, feature_weights
-            )
-        else:
-            tree_mask = jnp.ones((F,), bool)
-
-        # root totals (the InitRoot AllReduce site)
-        G0 = grad.sum()
-        H0 = hess.sum()
-        if cfg.axis_name is not None:
-            G0 = jax.lax.psum(G0, cfg.axis_name)
-            H0 = jax.lax.psum(H0, cfg.axis_name)
-        st = _init_state(cfg, F, G0, H0, B)
         pos = jnp.zeros((1, n), jnp.int32)  # every row starts at the root
 
     tree_grow_native_route = _use_tree_grow(cfg, pallas, max_depth,
@@ -544,19 +524,58 @@ def _grow_tree_fused_impl(
                                    cfg, d, mark_built=sub)
             parent_hist = histC
 
-    # ---- route rows through the last level's splits to their leaves ----
-    # (folded into the whole-tree kernel when that route ran: its pos
-    # output is already at the leaf level)
-    if max_depth > 0 and not tree_grow_native_route:
+    # (the last routing is folded into the whole-tree kernel when that
+    # route ran: its pos output is already at the leaf level)
+    return _tree_end(bins, pos, st, eta, gamma, cfg, B, pallas,
+                     route=not tree_grow_native_route)
+
+
+def _tree_start(grad, hess, key, feature_weights, cfg: GrowParams, F: int,
+                B: int):
+    """A tree's start: the three keys, row and column sampling, the
+    gradients ``[2, n]``, the root totals and the empty heap."""
+    k_sub, k_ctree, k_level = jax.random.split(key, 3)
+    if cfg.axis_name is not None:
+        k_sub = jax.random.fold_in(k_sub, jax.lax.axis_index(cfg.axis_name))
+
+    with jax.named_scope("xgb.root"):
+        grad, hess = apply_row_sampling(cfg, k_sub, grad, hess)
+        # per-row arrays of the tree have the rows on the LANE axis from
+        # here to ``leaf_delta`` (hist_kernel's module docstring)
+        gh = jnp.stack([grad, hess])  # [2, n]
+
+        if cfg.colsample_bytree < 1.0:
+            tree_mask = _sample_features_exact(
+                k_ctree, F, cfg.colsample_bytree, feature_weights
+            )
+        else:
+            tree_mask = jnp.ones((F,), bool)
+
+        # root totals (the InitRoot AllReduce site)
+        G0 = grad.sum()
+        H0 = hess.sum()
+        if cfg.axis_name is not None:
+            G0 = jax.lax.psum(G0, cfg.axis_name)
+            H0 = jax.lax.psum(H0, cfg.axis_name)
+        st = _init_state(cfg, F, G0, H0, B)
+    return gh, tree_mask, k_level, G0, H0, st
+
+
+def _tree_end(bins, pos, st: _HeapState, eta, gamma, cfg: GrowParams, B: int,
+              pallas: bool, route: bool = True) -> GrownTree:
+    """A tree's end: rows routed through the last level's splits to their
+    leaves (``pos`` ``[1, n]``; ``route`` False where they already are),
+    gamma pruning, leaf values, the margin's delta."""
+    if cfg.max_depth > 0 and route:
         with jax.named_scope("xgb.partition"):
             pos = partition_apply(
-                bins, pos, st.ptab, Kp=1 << (max_depth - 1), B=B,
-                d=max_depth, pallas=pallas, axis_name=cfg.axis_name,
+                bins, pos, st.ptab, Kp=1 << (cfg.max_depth - 1), B=B,
+                d=cfg.max_depth, pallas=pallas, axis_name=cfg.axis_name,
             )
 
     with jax.named_scope("xgb.finalize"):
         keep, leaf_value = _finalize(st, eta, gamma, cfg)
-    pad_nodes = max(128, 1 << (max_nodes - 1).bit_length())
+    pad_nodes = max(128, 1 << (cfg.max_nodes - 1).bit_length())
     with jax.named_scope("xgb.leaf_delta"):
         delta = leaf_delta(pos, leaf_value, pad_nodes, pallas=pallas)
 
@@ -567,6 +586,129 @@ def _grow_tree_fused_impl(
         loss_chg=st.loss_chg, leaf_value=leaf_value, delta=delta,
         cat_set=st.cat_set,
     )
+
+
+# ---------------------------------------------------------------------------
+# A round's trees in one pass over the rows (multiclass, num_parallel_tree).
+# The trees of one round are independent: their gradients come from the
+# round's margin before any of them is grown. Grown one after another, each
+# tree's each level streams the same resident one-hot and rebuilds the same
+# features' one-hot in VMEM; grown level by level together, a level call
+# carries several trees' gradient channels over one read of the rows
+# (``hist_kernel.fused_level_trees``). What a tree does with its histogram
+# is ``_grow_tree_fused_impl``'s own steps, each a jitted computation traced
+# once and called a tree, as ``jit(_grow_tree_fused_impl)`` is in the class
+# loop. A job that grows one tree a round never comes here
+# (``gbtree._scan_rounds_impl``).
+# ---------------------------------------------------------------------------
+
+
+_tree_root = guard_jit(_tree_start, name="tree_root",
+                       static_argnames=("cfg", "F", "B"))
+
+
+@guard_jit(name="tree_level", static_argnames=("cfg", "d", "mark_built"))
+def _tree_level(st: _HeapState, histC, parent_hist, cut_values, tree_mask,
+                k_level, *, cfg: GrowParams, d: int, mark_built: bool):
+    """A tree's step at level ``d`` once its histogram is built: the
+    siblings derived where the kernel built one child of every split
+    (``histC`` is then ``[F, 2Kp, B]``), the splits evaluated, the heap and
+    the next decision table written. Returns the state and the level's
+    full histogram, the next level's ``parent_hist``."""
+    if histC.shape[1] == (1 << d):  # the built half: 2 Kp = K rows
+        with jax.named_scope("xgb.level_hist"):
+            histC = derive_siblings(parent_hist, histC, st.ptab)
+    with jax.named_scope("xgb.split_eval"):
+        st = _level_update(st, histC, cut_values, tree_mask, k_level, cfg, d,
+                           mark_built=mark_built)
+    return st, histC
+
+
+_tree_leaves = guard_jit(_tree_end, name="tree_leaves",
+                         static_argnames=("cfg", "B", "pallas", "route"))
+
+
+def grow_trees_one_pass(
+    bins: jax.Array,  # [n_pad, F] narrow-int bins, as ``grow_tree_fused``
+    grads: Sequence[jax.Array],  # a tree's [n_pad] f32 each
+    hesss: Sequence[jax.Array],
+    cut_values: jax.Array,
+    keys: Sequence[jax.Array],
+    eta: jax.Array,
+    gamma: jax.Array,
+    cfg: GrowParams,
+    feature_weights: Optional[jax.Array] = None,
+    onehot: Optional[jax.Array] = None,
+) -> List[GrownTree]:
+    """The trees of one round (two or more; one chip, the Mosaic level
+    kernels), grown level by level together inside the caller's program:
+    per level the shared kernel call(s) (``level_trees`` says how many
+    trees a call carries, from the kernel's VMEM model at this level's
+    node count), then per tree ``_grow_tree_fused_impl``'s own steps. Every
+    tree is the one ``grow_tree_fused`` grows from the same gradients and
+    key: the same arithmetic on the same rows in the same order, bit for
+    bit wherever the level kernel's row tile is the same
+    (``fused_level_trees``)."""
+    from ..dispatch import Ctx, note, resolve
+    from . import hist_kernel as _hk
+
+    NT = len(keys)
+    assert NT > 1 and cfg.axis_name is None and not cfg.has_categorical
+    with jax.named_scope("xgb.level_hist"):
+        bins = bins.astype(jnp.int32)  # once a round, for every tree
+    n, F = bins.shape
+    B = cut_values.shape[1]
+    ghs, masks, k_levels, _, _, sts = map(list, zip(*[
+        _tree_root(g, h, key, feature_weights, cfg=cfg, F=F, B=B)
+        for g, h, key in zip(grads, hesss, keys)]))
+    with jax.named_scope("xgb.root"):
+        gh = jnp.concatenate(ghs)  # [2 NT, n]: tree t's g over h
+        pos = jnp.zeros((NT, n), jnp.int32)  # every row starts at the root
+    hists: List[Optional[jax.Array]] = [None] * NT
+    oh_width = 0 if onehot is None else int(onehot.shape[1])
+    sub = False  # the root has no parent
+    for d in range(cfg.max_depth):
+        K = 1 << d
+        Kp = K >> 1
+        Kc = Kp if sub else K
+        T = level_trees(n, F, Kc, B, NT, oh_width)
+        if T > 1 and resolve("level_hist", Ctx(
+                platform=jax.default_backend(), pallas=True,
+                interpret=bool(_hk._INTERPRET), rows=int(n), features=int(F),
+                nodes=int(T * Kc), bins=int(B),
+                table_width=int(sts[0].ptab.shape[-1]),
+                bins_dtype=str(bins.dtype), sharded=False,
+                onehot_width=oh_width)).impl != "pallas":
+            T = 1  # pinned or degraded away from the Mosaic kernels
+        new_pos, built = [], []
+        for lo in range(0, NT, T):
+            note("level_trees", T)
+            with jax.named_scope("xgb.level_hist"):
+                if T == 1:
+                    p, h = fused_level(
+                        bins, pos[lo:lo + 1], gh[2 * lo:2 * lo + 2],
+                        sts[lo].ptab, K=K, Kp=Kp, B=B, d=d, pallas=True,
+                        onehot=onehot, sibling_sub=sub)
+                    built.append(h)
+                else:
+                    p, h = fused_level_trees(
+                        bins, pos[lo:lo + T], gh[2 * lo:2 * (lo + T)],
+                        jnp.stack([st.ptab for st in sts[lo:lo + T]]),
+                        K=K, Kp=Kp, B=B, d=d, onehot=onehot,
+                        sibling_sub=sub)
+                    built += [h[t] for t in range(T)]
+            new_pos.append(p)
+        pos = new_pos[0] if len(new_pos) == 1 else jnp.concatenate(new_pos)
+        sub = (d + 1 < cfg.max_depth
+               and resolve("sibling_sub", Ctx(
+                   platform=jax.default_backend(), pallas=True,
+                   depth=d + 1)).impl == "on")
+        for t in range(NT):
+            sts[t], hists[t] = _tree_level(
+                sts[t], built[t], hists[t], cut_values, masks[t],
+                k_levels[t], cfg=cfg, d=d, mark_built=sub)
+    return [_tree_leaves(bins, pos[t:t + 1], sts[t], eta, gamma, cfg=cfg,
+                         B=B, pallas=True) for t in range(NT)]
 
 
 def _use_tree_grow(cfg: GrowParams, pallas: bool, max_depth: int,

@@ -55,8 +55,8 @@ from ..analysis.retrace import guard_jit
 
 __all__ = [
     "fused_level", "fused_level_xla", "fused_level_native",
-    "derive_siblings", "partition_apply", "partition_apply_xla",
-    "leaf_delta",
+    "fused_level_trees", "level_trees", "derive_siblings",
+    "partition_apply", "partition_apply_xla", "leaf_delta",
     "TR", "use_pallas", "use_native_hist", "build_onehot",
     "pallas_level_fits", "pallas_route_fits",
     "hoist_budget_bytes", "can_hoist", "hoist_plan", "device_free_bytes",
@@ -402,8 +402,16 @@ def _split_hilo(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
     return hi, x - hi
 
 
+def _bins_operand(binsb, B: int):
+    """The bins tile as the feature pick's matmul operand: bins 0..B and a
+    0/1 one-hot are exact in bf16 up to B = 256 (one MXU pass there), f32
+    at full precision beyond."""
+    return binsb.astype(jnp.float32).astype(
+        jnp.bfloat16 if B <= 256 else jnp.float32)
+
+
 def _partition_tile(pos, binsb, ptab_ref, *, Kp: int, F: int, B: int,
-                    prev_offset: int):
+                    prev_offset: int, tree=None, bins_op=None):
     """Route a tile's rows through the previous level's decision table
     (shared by the level kernels and the routing kernel). ``pos`` is
     ``[1, Tr]`` i32 (rows on the lanes) and so is the result; ``binsb`` is
@@ -422,10 +430,15 @@ def _partition_tile(pos, binsb, ptab_ref, *, Kp: int, F: int, B: int,
     a transpose of the bins tile: ``[Kp, F] @ binsb^T`` (contraction on
     both minor dimensions, the attention ``q @ k^T`` form) gives every
     node's split feature for every row, ``[Kp, Tr]``, and the node one-hot
-    picks the row's own."""
+    picks the row's own.
+
+    ``tree`` (a level call that carries several trees): ``ptab_ref`` is
+    ``[T, Kp, W]`` and this tree's table its ``tree``-th; ``bins_op`` is
+    then the bins tile already cast for the pick (``_bins_operand``), made
+    once for all T."""
     W = ptab_ref.shape[-1]
     Tr = binsb.shape[0]
-    ptab = ptab_ref[:, :]  # [Kp, W]
+    ptab = ptab_ref[:, :] if tree is None else ptab_ref[tree]  # [Kp, W]
     lp = pos - prev_offset  # [1, Tr]
     iota_kp = jax.lax.broadcasted_iota(jnp.int32, (Kp, Tr), 0)
     ohp = (lp == iota_kp).astype(jnp.float32)  # [Kp, Tr]
@@ -440,13 +453,13 @@ def _partition_tile(pos, binsb, ptab_ref, *, Kp: int, F: int, B: int,
     dl_of = dec[3:4, :]
     iota_f = jax.lax.broadcasted_iota(jnp.int32, (Kp, F), 1)
     ohf = ptab[:, 1:2].astype(jnp.int32) == iota_f  # [Kp, F]
-    # bins 0..B and a 0/1 one-hot are exact in bf16 up to B = 256: one MXU
-    # pass there, f32 at full precision beyond
-    narrow = B <= 256
-    dt = jnp.bfloat16 if narrow else jnp.float32
+    narrow = B <= 256  # see _bins_operand
+    ohf = ohf.astype(jnp.float32).astype(
+        jnp.bfloat16 if narrow else jnp.float32)
+    if bins_op is None:
+        bins_op = _bins_operand(binsb, B)
     node_bv = jax.lax.dot_general(
-        ohf.astype(jnp.float32).astype(dt),
-        binsb.astype(jnp.float32).astype(dt), (((1,), (1,)), ((), ())),
+        ohf, bins_op, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
         precision=None if narrow else jax.lax.Precision.HIGHEST,
     )  # [Kp, Tr]: bins[row, feature of node]
@@ -472,51 +485,89 @@ def _partition_tile(pos, binsb, ptab_ref, *, Kp: int, F: int, B: int,
     return pos + (goes > 0.5).astype(jnp.int32) * (child - pos)
 
 
-def _grad_channels(node, ids, gh_ref):
-    """[4K, Tr] bf16 per-node gradient channels; row order
-    [g_hi | h_hi | g_lo | h_lo] so ``out[:2K] + out[2K:] = [g, h]``. A row
-    of the data (a lane here) contributes to the channel group whose id
-    (``ids``: ``[K, Tr]`` or ``[K, 1]`` i32) its ``node`` (``[1, Tr]`` i32)
-    is, or to none. ``gh_ref`` is the ``(2, Tr)`` block: g above h."""
+def _grad_terms(node, ids, gh_ref, row: int = 0):
+    """The four f32 ``[K, Tr]`` terms of a tree's gradient channels, in the
+    order [g_hi, h_hi, g_lo, h_lo]: a row of the data (a lane here)
+    contributes to the channel group whose id (``ids``: ``[K, Tr]`` or
+    ``[K, 1]`` i32) its ``node`` (``[1, Tr]`` i32) is, or to none.
+    ``gh_ref`` is the ``(2T, Tr)`` block, this tree's g over h at rows
+    ``row``, ``row + 1``."""
     ohseg = (node == ids).astype(jnp.float32)  # [K, Tr]
-    g = gh_ref[0:1, :]
-    h = gh_ref[1:2, :]
+    g = gh_ref[row:row + 1, :]
+    h = gh_ref[row + 1:row + 2, :]
     g_hi, g_lo = _split_hilo(g)
     h_hi, h_lo = _split_hilo(h)
-    return jnp.concatenate(
-        [ohseg * g_hi, ohseg * h_hi, ohseg * g_lo, ohseg * h_lo], axis=0
-    ).astype(jnp.bfloat16)  # [4K, Tr]
+    return [ohseg * g_hi, ohseg * h_hi, ohseg * g_lo, ohseg * h_lo]
 
 
-def _route_and_channels(pos, binsb, gh_ref, ptab_ref, built_ref, *, K: int,
-                        Kp: int, F: int, B: int, prev_offset: int,
-                        offset: int):
-    """The level kernels' shared head: route the tile's rows (``pos``
-    ``[1, Tr]``) through the previous level's decisions, then form the
-    gradient channels. Direct build (``built_ref`` None): one channel group
-    a node of this level, ``[4K, Tr]``. Sibling subtraction: one a PARENT,
-    ``[4Kp, Tr]``, for the rows now at the child that parent marked, whose
-    heap index ``built_ref`` ``[Kp, 1]`` holds (-1: the parent did not
-    split; a row that stayed above this level is at no child). The sibling
-    is ``parent - built``, taken outside (``derive_siblings``)."""
+def _route_and_terms(pos, binsb, gh_ref, ptab_ref, built_ref, *, K: int,
+                     Kp: int, F: int, B: int, prev_offset: int, offset: int,
+                     tree=None, bins_op=None):
+    """The level kernels' shared head, for one tree: route the tile's rows
+    (``pos`` ``[1, Tr]``) through the previous level's decisions, then form
+    the gradient channels' terms (``_grad_terms``). Direct build
+    (``built_ref`` None): one channel group a node of this level. Sibling
+    subtraction: one a PARENT, for the rows now at the child that parent
+    marked, whose heap index ``built_ref`` ``[Kp, 1]`` holds (-1: the
+    parent did not split; a row that stayed above this level is at no
+    child). The sibling is ``parent - built``, taken outside
+    (``derive_siblings``). ``tree``: this tree's index in a call that
+    carries several (tables ``[T, Kp, W]``, built ids ``[T, Kp, 1]``, g
+    and h at rows ``2 tree``, ``2 tree + 1``)."""
+    row = 0 if tree is None else 2 * tree
     if Kp > 0:
         pos = _partition_tile(pos, binsb, ptab_ref, Kp=Kp, F=F, B=B,
-                              prev_offset=prev_offset)
+                              prev_offset=prev_offset, tree=tree,
+                              bins_op=bins_op)
     if built_ref is None:
         iota_k = jax.lax.broadcasted_iota(jnp.int32, (K, pos.shape[1]), 0)
-        return pos, _grad_channels(pos - offset, iota_k, gh_ref)
-    return pos, _grad_channels(pos, built_ref[:, :], gh_ref)
+        return pos, _grad_terms(pos - offset, iota_k, gh_ref, row)
+    built = built_ref[:, :] if tree is None else built_ref[tree]
+    return pos, _grad_terms(pos, built, gh_ref, row)
+
+
+def _route_and_channels(pos_ref, binsb, gh_ref, ptab_ref, built_ref, pos_out,
+                        *, T, Kp: int, B: int, **kw):
+    """Route the block's rows and return the gradient channels the one-hot
+    meets, bf16. One tree (``T`` None; ``pos_ref`` ``(1, Tr)``): ``[4Kc,
+    Tr]`` in the row order [g_hi | h_hi | g_lo | h_lo], so ``out[:2Kc] +
+    out[2Kc:] = [g, h]``. ``T`` trees of one round (``pos_ref`` ``(T,
+    Tr)``): each routes its own positions through its own table, over the
+    one bins tile, and the T channel groups are stacked ``[4 T Kc, Tr]``,
+    every tree's hi terms above every tree's lo terms, so that the same
+    ``out[:half] + out[half:]`` leaves tree t's ``[g, h]`` at rows ``2Kc t
+    .. 2Kc (t + 1)``: per tree the one-tree kernel's arithmetic, row for
+    row. Writes the routed positions to ``pos_out``."""
+    if T is None:
+        pos, terms = _route_and_terms(pos_ref[:, :], binsb, gh_ref, ptab_ref,
+                                      built_ref, Kp=Kp, B=B, **kw)
+        chans = jnp.concatenate(terms, axis=0).astype(jnp.bfloat16)
+        pos_out[:, :] = pos
+        return chans
+    bins_op = _bins_operand(binsb, B) if Kp > 0 else None
+    hi, lo = [], []
+    for t in range(T):
+        pos, terms = _route_and_terms(
+            pos_ref[t:t + 1, :], binsb, gh_ref, ptab_ref, built_ref, Kp=Kp,
+            B=B, tree=t, bins_op=bins_op, **kw)
+        pos_out[t:t + 1, :] = pos
+        hi += terms[:2]
+        lo += terms[2:]
+    return jnp.concatenate(hi + lo, axis=0).astype(jnp.bfloat16)
 
 
 def _level_kernel(bins_ref, pos_ref, gh_ref, ptab_ref, *rest,
                   K: int, Kp: int, F: int, B: int,
-                  prev_offset: int, offset: int):
+                  prev_offset: int, offset: int, T=None):
     """One grid step: partition `Tr` rows through the previous level's
     decisions, then accumulate their (g, h) into this level's histogram.
     ``pos_ref`` and ``gh_ref`` are the ``(1, Tr)`` and ``(2, Tr)`` blocks of
     the lane-dense arrays. ``rest``: the outputs ``pos_out, hist_ref``,
     behind ``built_ref`` where siblings are subtracted (the histogram is
-    then the built children's, ``Kc = Kp`` nodes wide)."""
+    then the built children's, ``Kc = Kp`` nodes wide). ``T`` trees of one
+    round (``_route_and_channels``): blocks ``(T, Tr)`` and ``(2T, Tr)``,
+    the histogram ``2 T Kc`` rows, and the feature's ``col == iota``
+    compare is built once for all of them."""
     from jax.experimental import pallas as pl
 
     *built_ref, pos_out, hist_ref = rest
@@ -530,12 +581,10 @@ def _level_kernel(bins_ref, pos_ref, gh_ref, ptab_ref, *rest,
 
     binsb = bins_ref[:, :]  # [Tr, F] i32
     Tr = binsb.shape[0]
-    Kc = K if built_ref is None else Kp
-    # pos: [1, Tr] i32 heap positions
-    pos, ghs4 = _route_and_channels(
-        pos_ref[:, :], binsb, gh_ref, ptab_ref, built_ref, K=K, Kp=Kp, F=F,
-        B=B, prev_offset=prev_offset, offset=offset)
-    pos_out[:, :] = pos
+    M = 2 * (K if built_ref is None else Kp) * (T or 1)  # histogram rows
+    ghs4 = _route_and_channels(  # [2M, Tr]
+        pos_ref, binsb, gh_ref, ptab_ref, built_ref, pos_out, T=T, K=K,
+        Kp=Kp, F=F, B=B, prev_offset=prev_offset, offset=offset)
 
     for f in range(F):
         col = binsb[:, f:f + 1]
@@ -544,8 +593,8 @@ def _level_kernel(bins_ref, pos_ref, gh_ref, ptab_ref, *rest,
         out = jax.lax.dot_general(
             ghs4, oh, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )  # [4Kc, Tr] @ [Tr, B]
-        hist_ref[f, :, :] += out[:2 * Kc] + out[2 * Kc:]
+        )  # [2M, Tr] @ [Tr, B]
+        hist_ref[f, :, :] += out[:M] + out[M:]
 
 
 def _vma_struct(shape, dtype, axes):
@@ -563,18 +612,35 @@ def _built_children(ptab, *, Kp: int, d: int, sub: bool):
     positions), for every parent the heap index of the child its decision
     table marks to be built (``is_split`` 1: the left, 2: the right), -1
     where it does not split. Returns (arrays, block specs): empty for the
-    direct build, whose kernels take no such operand."""
+    direct build, whose kernels take no such operand. ``[T, Kp, 1]`` from
+    the ``[T, Kp, W]`` tables of a call that carries T trees."""
     if not sub:
         return [], []
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     assert Kp > 0, "the root has no parent to subtract from"
-    mark = ptab[:, 0].astype(jnp.int32)
+    mark = ptab[..., 0].astype(jnp.int32)  # [Kp], or [T, Kp]
     parent = ((1 << (d - 1)) - 1) + jnp.arange(Kp, dtype=jnp.int32)
-    built = jnp.where(mark > 0, 2 * parent + mark, -1)[:, None]
-    return [built], [pl.BlockSpec((Kp, 1), lambda c: (0, 0),
+    built = jnp.where(mark > 0, 2 * parent + mark, -1)[..., None]
+    return [built], [pl.BlockSpec(built.shape, lambda c: (0,) * built.ndim,
                                   memory_space=pltpu.VMEM)]
+
+
+def _tree_axis(ptab, Kp: int):
+    """What a tree axis on the decision tables (``[T, Kp, W]``: the call
+    carries T trees of one round) changes of a level call's operands: (T or
+    None, the rows of the positions block, the tables' block spec)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    W = ptab.shape[-1]
+    if ptab.ndim == 3:
+        T = ptab.shape[0]
+        return T, T, pl.BlockSpec((T, max(Kp, 1), W), lambda c: (0, 0, 0),
+                                  memory_space=pltpu.VMEM)
+    return None, 1, pl.BlockSpec((max(Kp, 1), W), lambda c: (0, 0),
+                                 memory_space=pltpu.VMEM)
 
 
 @guard_jit(name="fused_level_pallas",
@@ -588,45 +654,51 @@ def _fused_level_pallas(bins, pos, gh, ptab, *, K, Kp, B, d, tr=TR,
     assert n % tr == 0, f"rows {n} not padded to {tr}"
     prev_offset = (1 << (d - 1)) - 1 if d > 0 else 0
     offset = (1 << d) - 1
-    W = ptab.shape[1]
     Kc = Kp if sub else K  # nodes built: one child of every parent
+    T, R, ptab_spec = _tree_axis(ptab, Kp)
     built, built_specs = _built_children(ptab, Kp=Kp, d=d, sub=sub)
     kern = functools.partial(
         _level_kernel, K=K, Kp=Kp, F=F, B=B,
-        prev_offset=prev_offset, offset=offset,
+        prev_offset=prev_offset, offset=offset, T=T,
     )
-    return pl.pallas_call(
+    pos_new, hist = pl.pallas_call(
         kern,
         grid=(n // tr,),
         in_specs=[
             pl.BlockSpec((tr, F), lambda c: (c, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, tr), lambda c: (0, c), memory_space=pltpu.VMEM),
-            pl.BlockSpec((2, tr), lambda c: (0, c), memory_space=pltpu.VMEM),
-            pl.BlockSpec((max(Kp, 1), W), lambda c: (0, 0),
+            pl.BlockSpec((R, tr), lambda c: (0, c), memory_space=pltpu.VMEM),
+            pl.BlockSpec((2 * R, tr), lambda c: (0, c),
                          memory_space=pltpu.VMEM),
+            ptab_spec,
         ] + built_specs,
         out_specs=[
-            pl.BlockSpec((1, tr), lambda c: (0, c), memory_space=pltpu.VMEM),
-            pl.BlockSpec((F, 2 * Kc, B), lambda c: (0, 0, 0),
+            pl.BlockSpec((R, tr), lambda c: (0, c), memory_space=pltpu.VMEM),
+            pl.BlockSpec((F, 2 * R * Kc, B), lambda c: (0, 0, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            _vma_struct((1, n), jnp.int32, vma),
-            _vma_struct((F, 2 * Kc, B), jnp.float32, vma),
+            _vma_struct((R, n), jnp.int32, vma),
+            _vma_struct((F, 2 * R * Kc, B), jnp.float32, vma),
         ],
         interpret=_INTERPRET,
     )(bins, pos, gh, ptab, *built)
+    if T is None:
+        return pos_new, hist
+    # [F, T * 2Kc, B] -> a tree's [F, 2Kc, B] each
+    return pos_new, jnp.transpose(hist.reshape(F, T, 2 * Kc, B), (1, 0, 2, 3))
 
 
 def _hoisted_kernel(bins_ref, oh_ref, pos_ref, gh_ref, ptab_ref, *rest,
                     K: int, Kp: int, F: int, Fh: int, B: int,
-                    prev_offset: int, offset: int):
+                    prev_offset: int, offset: int, T=None):
     """Hoisted-one-hot grid step: partition + grad channels (cheap VPU, on
     whole vregs: rows on the lanes), ONE [4Kc, Tr] x [Tr, Fh*B] MXU matmul
     streaming the resident one-hot for the first ``Fh`` features, and an
     in-kernel construct loop for the remaining ``F - Fh`` (empty when the
-    full expansion fit HBM). ``pos_ref``, ``gh_ref``, ``rest`` and ``Kc``
-    as in ``_level_kernel``."""
+    full expansion fit HBM). ``pos_ref``, ``gh_ref``, ``rest``, ``Kc`` and
+    ``T`` as in ``_level_kernel``: with T trees the one-hot tile is read
+    once and meets all their channels, ``[4 T Kc, Tr]``, in that one
+    matmul."""
     from jax.experimental import pallas as pl
 
     *built_ref, pos_out, hist_ref = rest
@@ -640,18 +712,17 @@ def _hoisted_kernel(bins_ref, oh_ref, pos_ref, gh_ref, ptab_ref, *rest,
 
     binsb = bins_ref[:, :]
     Tr = binsb.shape[0]
-    Kc = K if built_ref is None else Kp
-    pos, ghs4 = _route_and_channels(  # ghs4: [4Kc, Tr]
-        pos_ref[:, :], binsb, gh_ref, ptab_ref, built_ref, K=K, Kp=Kp, F=F,
-        B=B, prev_offset=prev_offset, offset=offset)
-    pos_out[:, :] = pos
+    M = 2 * (K if built_ref is None else Kp) * (T or 1)  # accumulator rows
+    ghs4 = _route_and_channels(  # [2M, Tr]
+        pos_ref, binsb, gh_ref, ptab_ref, built_ref, pos_out, T=T, K=K,
+        Kp=Kp, F=F, B=B, prev_offset=prev_offset, offset=offset)
 
     oh = oh_ref[:, :].astype(jnp.bfloat16)  # [Tr, Fh*B] int8 -> bf16
     out = jax.lax.dot_general(
         ghs4, oh, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
-    )  # [4Kc, Fh*B]
-    hist_ref[:, : Fh * B] += out[: 2 * Kc] + out[2 * Kc:]
+    )  # [2M, Fh*B]
+    hist_ref[:, : Fh * B] += out[:M] + out[M:]
     for f in range(Fh, F):
         col = binsb[:, f:f + 1]
         iota_b = jax.lax.broadcasted_iota(jnp.int32, (Tr, B), 1)
@@ -659,8 +730,8 @@ def _hoisted_kernel(bins_ref, oh_ref, pos_ref, gh_ref, ptab_ref, *rest,
         outf = jax.lax.dot_general(
             ghs4, ohf, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )  # [4Kc, B]
-        hist_ref[:, f * B:(f + 1) * B] += outf[: 2 * Kc] + outf[2 * Kc:]
+        )  # [2M, B]
+        hist_ref[:, f * B:(f + 1) * B] += outf[:M] + outf[M:]
 
 
 @guard_jit(name="hoisted_level_pallas",
@@ -679,12 +750,12 @@ def _hoisted_level_pallas(bins, onehot, pos, gh, ptab, *, K, Kp, B, d,
     assert n % tr == 0, f"rows {n} not padded to {tr}"
     prev_offset = (1 << (d - 1)) - 1 if d > 0 else 0
     offset = (1 << d) - 1
-    W = ptab.shape[1]
     Kc = Kp if sub else K  # nodes built: one child of every parent
+    T, R, ptab_spec = _tree_axis(ptab, Kp)
     built, built_specs = _built_children(ptab, Kp=Kp, d=d, sub=sub)
     kern = functools.partial(
         _hoisted_kernel, K=K, Kp=Kp, F=F, Fh=Fh, B=B,
-        prev_offset=prev_offset, offset=offset,
+        prev_offset=prev_offset, offset=offset, T=T,
     )
     pos_new, hist2 = pl.pallas_call(
         kern,
@@ -692,25 +763,29 @@ def _hoisted_level_pallas(bins, onehot, pos, gh, ptab, *, K, Kp, B, d,
         in_specs=[
             pl.BlockSpec((tr, F), lambda c: (c, 0), memory_space=pltpu.VMEM),
             pl.BlockSpec((tr, Qh), lambda c: (c, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, tr), lambda c: (0, c), memory_space=pltpu.VMEM),
-            pl.BlockSpec((2, tr), lambda c: (0, c), memory_space=pltpu.VMEM),
-            pl.BlockSpec((max(Kp, 1), W), lambda c: (0, 0),
+            pl.BlockSpec((R, tr), lambda c: (0, c), memory_space=pltpu.VMEM),
+            pl.BlockSpec((2 * R, tr), lambda c: (0, c),
                          memory_space=pltpu.VMEM),
+            ptab_spec,
         ] + built_specs,
         out_specs=[
-            pl.BlockSpec((1, tr), lambda c: (0, c), memory_space=pltpu.VMEM),
-            pl.BlockSpec((2 * Kc, Q), lambda c: (0, 0),
+            pl.BlockSpec((R, tr), lambda c: (0, c), memory_space=pltpu.VMEM),
+            pl.BlockSpec((2 * R * Kc, Q), lambda c: (0, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            _vma_struct((1, n), jnp.int32, vma),
-            _vma_struct((2 * Kc, Q), jnp.float32, vma),
+            _vma_struct((R, n), jnp.int32, vma),
+            _vma_struct((2 * R * Kc, Q), jnp.float32, vma),
         ],
         interpret=_INTERPRET,
     )(bins, onehot, pos, gh, ptab, *built)
-    # [2Kc, F*B] -> the dispatcher contract [F, 2Kc, B]
-    hist = jnp.transpose(hist2.reshape(2 * Kc, F, B), (1, 0, 2))
-    return pos_new, hist
+    if T is None:
+        # [2Kc, F*B] -> the dispatcher contract [F, 2Kc, B]
+        hist = jnp.transpose(hist2.reshape(2 * Kc, F, B), (1, 0, 2))
+        return pos_new, hist
+    # [T * 2Kc, F*B] -> a tree's [F, 2Kc, B] each
+    return pos_new, jnp.transpose(hist2.reshape(T, 2 * Kc, F, B),
+                                  (0, 2, 1, 3))
 
 
 def _route_kernel(bins_ref, pos_ref, ptab_ref, pos_out, *, Kp: int, F: int,
@@ -983,6 +1058,54 @@ def fused_level(bins, pos, gh, ptab, *, K, Kp, B, d, pallas: bool,
     if dec.impl == "native":
         return fused_level_native(bins, pos, gh, ptab, K=K, Kp=Kp, B=B, d=d)
     return fused_level_xla(bins, pos, gh, ptab, K=K, Kp=Kp, B=B, d=d)
+
+
+def level_trees(rows: int, F: int, Kc: int, B: int, trees: int,
+                onehot_width: int = 0) -> int:
+    """How many of a round's ``trees`` (class trees x ``num_parallel_tree``)
+    one Mosaic level call carries when each builds ``Kc`` nodes: the
+    largest T dividing ``trees`` whose ``T x Kc`` nodes fit the kernel's
+    VMEM model, the streaming kernel's (``_hoist_tr``, at the resident
+    one-hot's width as planned: the plan is not asked again) or, with no
+    resident one-hot, the construct-only kernel's accumulator gate. The
+    level then runs ``trees / T`` calls; 1 means a call a tree through
+    ``fused_level``. Read from the shapes alone: nothing pins it."""
+    for T in range(trees, 1, -1):
+        if trees % T:
+            continue
+        if onehot_width:
+            tr = _hoist_tr(onehot_width, T * Kc, F, B)
+            if tr and rows % tr == 0:
+                return T
+        elif rows % TR == 0 and pallas_level_fits(rows, F, T * Kc, B):
+            return T
+    return 1
+
+
+def fused_level_trees(bins, pos, gh, ptab, *, K, Kp, B, d,
+                      onehot: Optional[jax.Array] = None,
+                      sibling_sub: bool = False):
+    """One level of T trees of the same round in ONE pass over the rows
+    (T from ``level_trees``; Mosaic kernels only, one chip): ``pos`` [T, n]
+    i32, ``gh`` [2T, n] f32 (tree t's g over h at rows 2t, 2t + 1) and the
+    trees' decision tables ``ptab`` [T, Kp, W] in, (new pos [T, n], hist
+    [T, F, 2K, B]) out, or [T, F, 2Kp, B], the built children's, under
+    ``sibling_sub``: per tree what ``fused_level`` returns, bit for bit at
+    the same row tile (a tile's sums are the one-tree kernel's, row for
+    row; where ``T x Kc`` nodes take a smaller tile than ``Kc`` do, the
+    f32 accumulator adds the tiles' sums in another grouping and a cell
+    may differ in its last bit, as a level's does from the next's today).
+    The bins tile and the one-hot tile are read once a grid step and meet
+    all T trees' gradient channels in one matmul."""
+    F = bins.shape[1]
+    Kc = Kp if sibling_sub else K
+    if onehot is not None:
+        return _hoisted_level_pallas(
+            bins, onehot, pos, gh, ptab, K=K, Kp=Kp, B=B, d=d,
+            tr=_hoist_tr(onehot.shape[1], ptab.shape[0] * Kc, F, B),
+            sub=sibling_sub)
+    return _fused_level_pallas(bins, pos, gh, ptab, K=K, Kp=Kp, B=B, d=d,
+                               sub=sibling_sub)
 
 
 def leaf_delta(pos, leaf_values, max_nodes_pad: int, pallas: bool):
