@@ -38,7 +38,8 @@ import threading
 import time
 from dataclasses import dataclass
 
-STAGES = ("kernels", "train", "predict", "serve", "four_chips", "reference")
+STAGES = ("kernels", "wide", "train", "predict", "serve", "four_chips",
+          "reference")
 
 _TAG = ""  # set to the rehearsal label by main()
 
@@ -216,6 +217,32 @@ def _params(max_bin: int) -> dict:
 # ---------------------------------------------------------------------------
 
 
+def _xla_oracle():
+    """``fused_level_xla`` on host arrays, (pos, hist) back as numpy. Placed
+    on the host CPU device where JAX has one: XLA's TPU compile of its
+    scatter-add grows with the row count (67 s a shape at 64k rows on the
+    v5e, for 0.03 s of run — CHANGES.md, PR 21), and where it runs does not
+    matter to an oracle."""
+    import jax
+    import numpy as np
+
+    from xgboost_tpu.tree import hist_kernel as hk
+
+    try:
+        dev = jax.devices("cpu")[0]
+    except RuntimeError:  # JAX_PLATFORMS names no cpu: compile it on the chip
+        dev = jax.devices()[0]
+    say(f"  oracle fused_level_xla placed on {dev}")
+
+    def oracle(bins_np, pos_np, gh_np, ptab_np, **kw):
+        args = [jax.device_put(a, dev)
+                for a in (bins_np, pos_np, gh_np, ptab_np)]
+        pos_x, hist_x = hk.fused_level_xla(*args, **kw)
+        return np.asarray(pos_x), np.asarray(hist_x)
+
+    return oracle
+
+
 def stage_kernels(sz: Sizes, X, routes: dict) -> None:
     """Each level kernel the anchor can route to, compiled, against
     ``fused_level_xla`` on a slice of the anchor: identical ``pos``,
@@ -223,30 +250,14 @@ def stage_kernels(sz: Sizes, X, routes: dict) -> None:
     (the comparison tests/test_hoisted.py makes in interpret mode). Below
     the root each runs twice: the direct build, and the child every parent
     marked with its sibling derived from the oracle's parent histogram
-    (``*_sub``, tests/test_sibling_sub.py's comparison).
-
-    The oracle is placed on the host CPU device where JAX has one: XLA's
-    TPU compile of its scatter-add grows with the row count (67 s a shape
-    at 64k rows on the v5e, for 0.03 s of run — CHANGES.md, PR 21), and
-    where it runs does not matter to an oracle."""
-    import jax
+    (``*_sub``, tests/test_sibling_sub.py's comparison)."""
     import jax.numpy as jnp
     import numpy as np
 
     from xgboost_tpu.data.quantile import BinnedMatrix
     from xgboost_tpu.tree import hist_kernel as hk
 
-    try:
-        oracle_dev = jax.devices("cpu")[0]
-    except RuntimeError:  # JAX_PLATFORMS names no cpu: compile it on the chip
-        oracle_dev = jax.devices()[0]
-    say(f"  oracle fused_level_xla placed on {oracle_dev}")
-
-    def oracle(bins_np, pos_np, gh_np, ptab_np, **kw):
-        args = [jax.device_put(a, oracle_dev)
-                for a in (bins_np, pos_np, gh_np, ptab_np)]
-        pos_x, hist_x = hk.fused_level_xla(*args, **kw)
-        return np.asarray(pos_x), np.asarray(hist_x)
+    oracle = _xla_oracle()
 
     n, F = sz.kernel_rows, sz.cols
     n_anchor = -(-int(sz.rows * 0.75) // hk.TR) * hk.TR
@@ -364,6 +375,131 @@ def stage_kernels(sz: Sizes, X, routes: dict) -> None:
     _check_routes("kernels", before, routes,
                   must_see=("onehot_build", "level_partition",
                             "sketch_cuts", "bin_matrix"))
+
+
+# ---------------------------------------------------------------------------
+# stage: the tiled kernels at 2,000 columns == fused_level_xla
+# ---------------------------------------------------------------------------
+
+WIDE_COLS, WIDE_BINS = 2000, 128
+
+
+def stage_wide(sz: Sizes, routes: dict) -> None:
+    """A matrix no untiled kernel takes (2,000 columns at 128 bins, ISSUE
+    35), on a few thousand rows: every level through ``fused_level``, which
+    must find the tiled kernel by itself, against ``fused_level_xla`` as
+    the kernels stage compares (positions identical, histograms in the
+    hi/lo class; below the root also the built child with its sibling
+    derived); the routing kernel at the width; and the sketch by column
+    blocks against the whole-matrix program."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from xgboost_tpu.data import quantile
+    from xgboost_tpu.tree import hist_kernel as hk
+
+    oracle = _xla_oracle()
+    n, F, B = min(sz.kernel_rows, 8192), WIDE_COLS, WIDE_BINS
+    rng = np.random.RandomState(SEED + 1)
+    X = rng.randn(n, F).astype(np.float32)
+    X[rng.rand(n, F) < 0.01] = np.nan
+    before = _decisions()
+    whole = quantile.BinnedMatrix.from_dense(X, max_bin=B)
+    quantile._FORCE_BLOCK_COLS = 500
+    try:
+        blocks = quantile.BinnedMatrix.from_dense(X, max_bin=B)
+    finally:
+        quantile._FORCE_BLOCK_COLS = None
+    check(np.array_equal(whole.cuts.values, blocks.cuts.values)
+          and np.array_equal(np.asarray(whole.bins), np.asarray(blocks.bins)),
+          "wide: column-blocked cuts or bins differ from the whole-matrix "
+          "program's")
+    say(f"  sketch + bins of {n}x{F} in 4 column blocks == whole matrix")
+    bins_np = np.asarray(whole.bins)
+    bins32 = whole.bins.astype(jnp.int32)
+    gh_np = rng.randn(2, n).astype(np.float32)
+    gh_np[1] = np.abs(gh_np[1]) + 0.05
+    gh = jnp.asarray(gh_np)
+
+    def level_inputs(d):
+        Kp = (1 << d) >> 1
+        if d == 0:
+            return np.zeros((1, n), np.int32), np.zeros((1, 4), np.float32)
+        prev = (1 << (d - 1)) - 1
+        pos_np = (prev + rng.randint(0, Kp, size=(1, n))).astype(np.int32)
+        ptab_np = np.stack([
+            ((rng.rand(Kp) < 0.85) * rng.randint(1, 3, Kp)
+             ).astype(np.float32),
+            rng.randint(0, F, Kp).astype(np.float32),
+            rng.randint(0, B - 1, Kp).astype(np.float32),
+            rng.randint(0, 2, Kp).astype(np.float32)], axis=1)
+        return pos_np, ptab_np
+
+    def compare(tag, d, sub, pos_np, ptab_np):
+        K, Kp = 1 << d, (1 << d) >> 1
+        kw = dict(K=K, Kp=Kp, B=B, d=d)
+        plan = hk.level_plan(n, F, Kp if sub else K, B)
+        check(plan is not None and plan.kernel == "tiled",
+              f"{tag}: the model does not send this level to the tiles: "
+              f"{plan}")
+        pos_x, hist_x = oracle(bins_np, pos_np, gh_np, ptab_np, **kw)
+        _, habs = oracle(bins_np, pos_np, np.abs(gh_np), ptab_np, **kw)
+        tol = habs * 2.0 ** -15 + 1e-6
+        pos, ptab = jnp.asarray(pos_np), jnp.asarray(ptab_np)
+
+        def call():
+            return hk.fused_level(bins32, pos, gh, ptab, pallas=True,
+                                  sibling_sub=sub, **kw)
+
+        (pos_p, hist_p), cold = _timed(call)
+        _, warm = _timed(call)
+        check(np.array_equal(np.asarray(pos_p), pos_x),
+              f"{tag}: pos differs from XLA")
+        if sub:
+            check(hist_p.shape == (F, 2 * Kp, B),
+                  f"{tag}: not the built half")
+            up = dict(K=Kp, Kp=0, B=B, d=d - 1)
+            parent = jnp.asarray(oracle(bins_np, pos_np, gh_np, ptab_np,
+                                        **up)[1])
+            pabs = oracle(bins_np, pos_np, np.abs(gh_np), ptab_np,
+                          **up)[1].reshape(F, 2, Kp, 1, B)
+            tol = np.broadcast_to(pabs, (F, 2, Kp, 2, B)).reshape(
+                F, 2 * K, B) * 2.0 ** -15 + 1e-6
+            hist_p = hk.derive_siblings(parent, hist_p, ptab)
+        err = np.abs(np.asarray(hist_p) - hist_x)
+        check(bool((err <= tol).all()),
+              f"{tag}: histogram off by {float((err - tol).max()):.3e} "
+              "beyond tolerance")
+        say(f"  {tag}: {plan.tiles} tiles tr {plan.tr}: cold {cold:.2f}s warm {warm:.4f}s  pos identical, "
+            f"max|dhist| {float(err.max()):.2e}")
+
+    # d=6 under subtraction builds 32 nodes: the most a tile's accumulator
+    # holds at 128 bins
+    for d in (0, 3, 5, 6):
+        pos_np, ptab_np = level_inputs(d)
+        for sub in ((False, True) if 0 < d < 6 else (d == 6,)):
+            compare(f"wide {F}x{B} d={d}{'_sub' if sub else ''}", d, sub,
+                    pos_np, ptab_np)
+        if d:
+            pos, ptab = jnp.asarray(pos_np), jnp.asarray(ptab_np)
+            Kp = (1 << d) >> 1
+
+            def route():
+                return hk.partition_apply(bins32, pos, ptab, Kp=Kp, B=B, d=d,
+                                          pallas=True)
+
+            pos_r, cold = _timed(route)
+            _, warm = _timed(route)
+            want = oracle(bins_np, pos_np, gh_np, ptab_np, K=1 << d, Kp=Kp,
+                          B=B, d=d)[0]
+            check(np.array_equal(np.asarray(pos_r), want),
+                  f"wide d={d} route_rows: pos differs from XLA")
+            say(f"  wide d={d} route_rows (tile "
+                f"{hk._route_tr(n, F, Kp, 4)}): cold {cold:.2f}s warm "
+                f"{warm:.4f}s  pos identical")
+    _check_routes("wide", before, routes,
+                  must_see=("level_hist", "level_partition", "sketch_cuts",
+                            "bin_matrix"))
 
 
 # ---------------------------------------------------------------------------
@@ -838,6 +974,8 @@ def main(argv=None) -> int:
         say(f"[{stage}]")
         if stage == "kernels":
             stage_kernels(sz, X, routes)
+        elif stage == "wide":
+            stage_wide(sz, routes)
         elif stage == "train":
             with _pinned(walk_pin):
                 stage_train(sz, xgb, X, y, routes, args.rehearse)

@@ -631,3 +631,51 @@ def test_model_bytes_equal_with_and_without_a_profiler_session(
     profiled = []
     _profile(tmp_path, lambda: profiled.append(fit()))
     assert profiled == [plain]
+
+
+# the level kernels tiled over features (ISSUE 35): Epsilon's width
+_EPS_F, _EPS_B = 2000, 128
+
+
+@pytest.mark.parametrize("F,B,depth", [(_EPS_F, _EPS_B, 6),
+                                       (_EPS_F, 256, 6),
+                                       (520, _EPS_B, 7)])
+def test_wide_tree_compiles_on_the_tiled_kernels_under_their_names(
+        one_v5e_chip, monkeypatch, F, B, depth):
+    """A whole tree program over a matrix no untiled kernel takes, compiled
+    for a described v5e at real widths (8,192 rows: the kernels' blocks do
+    not depend on the row count): the chip's compiler takes every tiled
+    level call (the tile's accumulator, the 128-lane bins block) and the
+    routing kernel at its 256-row tile; a level is one ``_tiled_level_pallas``, which the benchmark's
+    reduction books to the level histogram by its name, behind one
+    ``_route_rows_pallas`` under ``xgb.partition``, which it does not."""
+    import jax.numpy as jnp
+
+    from xgboost_tpu.tree import grow, grow_fused
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
+
+    monkeypatch.setattr(hk, "use_pallas", lambda: True)
+    n = 8192
+    lines = _mosaic_lines(
+        grow_fused._grow_tree_fused_impl._guarded_jit,
+        S((n, F), jnp.uint8 if B < 255 else jnp.uint16), S((n,), jnp.float32),
+        S((n,), jnp.float32), S((F, B), jnp.float32), S((2,), jnp.uint32),
+        S((), jnp.float32), S((), jnp.float32),
+        cfg=grow.GrowParams(max_depth=depth))
+    summary = _benchmark_summary()
+    levels = [ln for ln in lines
+              if summary.is_level_kernel(ln.removeprefix("ROOT "))]
+    routes = [ln for ln in lines if ln not in levels]
+    assert len(levels) == depth and len(routes) == depth
+    Fp = -(-F // 128) * 128
+    for d, ln in enumerate(levels):
+        assert re.match(r"(?:ROOT )?%_tiled_level_pallas[.\d]* = ", ln), ln[:80]
+        Kc = max(1 << d >> 1, 1)
+        assert f"f32[{Fp},{2 * Kc},{B}]" in ln.split(" custom-call(")[0]
+        assert "/xgb.level_hist/jit(_tiled_level_pallas)/" in ln
+    for d, ln in enumerate(routes):
+        assert re.match(r"(?:ROOT )?%_route_rows_pallas[.\d]* = ", ln), ln[:80]
+        assert "xgb.partition/jit(_route_rows_pallas)/" in ln
+        assert summary.kind_of(ln.removeprefix("ROOT ")) == "mosaic"

@@ -83,9 +83,11 @@ _TPU_LP = dict(platform="tpu", pallas=True, bins_dtype="int32")
     (_TPU_LP, "", ("pallas",)),
     (dict(_TPU_LP, sharded=True), "", ("pallas",)),
     (dict(_TPU_LP, table_width=5 + 256, nodes=128), "", ("pallas",)),
-    # ragged rows, too many features, no pallas flag: as before this row
+    # ragged rows, a bins row too wide for any tile, no pallas flag: as
+    # before this row
     (dict(_TPU_LP, rows=N + 8), "", ("xla",)),
-    (dict(_TPU_LP, features=513), "", ("xla",)),
+    (dict(_TPU_LP, features=513), "", ("pallas",)),  # a tile from the width
+    (dict(_TPU_LP, features=6000), "", ("xla",)),
     (dict(_TPU_LP, pallas=False), "", ("xla",)),
     # off the TPU nothing changes route
     (dict(), "", ("native", "xla")),

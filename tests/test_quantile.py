@@ -131,3 +131,78 @@ def test_streaming_dmatrix_rebin_at_other_max_bin():
     bst = xgb.train({"objective": "binary:logistic", "max_depth": 3}, d, 2,
                     verbose_eval=False)
     assert np.isfinite(bst.predict(d)).all()
+
+
+# ---------------------------------------------------------------------------
+# column blocks (ISSUE 35): a matrix too large for the device to sketch at
+# once goes a block of columns at a time; the result is the whole-matrix
+# program's to the bit
+# ---------------------------------------------------------------------------
+
+
+def _awkward_matrix(n=3000, F=37, seed=0):
+    """NaNs scattered and in a whole column, a constant column, a column of
+    two values, duplicates, and weights."""
+    rng = np.random.RandomState(seed)
+    X = rng.randn(n, F).astype(np.float32)
+    X[rng.rand(n, F) < 0.05] = np.nan
+    X[:, 5] = 1.5
+    X[:, 11] = np.nan
+    X[:, 20] = (rng.rand(n) < 0.3).astype(np.float32)
+    X[:, 21] = np.round(X[:, 21], 1)
+    return X, rng.rand(n).astype(np.float32)
+
+
+@pytest.mark.parametrize("route", ["auto", "xla"])
+@pytest.mark.parametrize("weighted", [False, True])
+@pytest.mark.parametrize("cols", [1, 8, 36])
+def test_column_blocks_equal_the_whole_matrix(monkeypatch, cols, weighted,
+                                              route):
+    from xgboost_tpu.data import quantile
+    from xgboost_tpu.observability import REGISTRY
+
+    if route == "xla":
+        monkeypatch.setenv("XGBTPU_DISPATCH",
+                           "sketch_cuts=xla,bin_matrix=xla")
+    X, w = _awkward_matrix()
+    w = w if weighted else None
+    whole = BinnedMatrix.from_dense(X, max_bin=64, weights=w)
+
+    def blocks_taken():
+        fam = REGISTRY.get("sketch_blocks_total")
+        return 0 if fam is None else sum(c.value for _, c in fam.series())
+
+    before = blocks_taken()
+    monkeypatch.setattr(quantile, "_FORCE_BLOCK_COLS", cols)
+    blocked = BinnedMatrix.from_dense(X, max_bin=64, weights=w)
+    assert blocks_taken() - before == 2 * -(-37 // cols)  # cuts, then bins
+    assert np.array_equal(whole.cuts.values, blocked.cuts.values)
+    assert np.array_equal(whole.cuts.min_vals, blocked.cuts.min_vals)
+    assert blocked.bins.dtype == whole.bins.dtype
+    assert blocked.bins.shape == (3000, 37)
+    assert np.array_equal(np.asarray(whole.bins), np.asarray(blocked.bins))
+    # given cuts, the binning alone goes by blocks too
+    again = BinnedMatrix.from_dense(X, max_bin=64, cuts=whole.cuts)
+    assert np.array_equal(np.asarray(whole.bins), np.asarray(again.bins))
+
+
+def test_block_width_comes_from_the_free_memory(monkeypatch):
+    from xgboost_tpu.data import quantile
+    from xgboost_tpu.tree import hist_kernel
+
+    # no memory statistics (the CPU): the whole matrix, as ever
+    assert quantile.sketch_block_cols(400_000, 2000) == 2000
+    free = 15_700_000_000
+    monkeypatch.setattr(hist_kernel, "device_free_bytes", lambda: free)
+    # the four narrow deployments keep the whole-matrix program
+    for n, F in ((750_000, 50), (10_500_000, 28), (2_270_296, 136),
+                 (435_759, 54)):
+        assert quantile.sketch_block_cols(n, F) == F
+    # 400,000 x 2,000: 22.4 GB at 28 bytes a cell; four equal blocks
+    cols = quantile.sketch_block_cols(400_000, 2000)
+    assert cols == 500
+    assert quantile._SKETCH_BYTES_PER_CELL * 400_000 * cols <= 0.5 * free
+    # no near divisor: the blocks are as even as they come
+    assert quantile.sketch_block_cols(400_000, 1999) == 667
+    # a matrix of one very long column is still one column a block
+    assert quantile.sketch_block_cols(2_000_000_000, 3) == 1

@@ -126,11 +126,14 @@ def test_route_rows_under_a_two_device_shard_map(monkeypatch):
     (hk.TR, 50, 128, 5 + 256, True),  # categorical, bin256
     (hk.TR + 8, 50, 32, 4, False),  # ragged rows
     (0, 50, 32, 4, False),
-    # lane-dense positions (ISSUE 31) left the working set under the budget
-    # at every width the level kernels take: the width's own cap bounds F
-    (hk.TR, hk._MAX_KERNEL_FEATURES + 1, 32, 4, False),
+    # the row tile comes from the width (ISSUE 35): no cap at the untiled
+    # level kernels' 512 columns; the whole bins row has to fit a 128-row
+    # tile, which it does to about 5,000 columns
+    (hk.TR, hk._MAX_KERNEL_FEATURES + 1, 32, 4, True),
     (hk.TR, hk._MAX_KERNEL_FEATURES, 32, 4, True),
-    (hk.TR, hk._MAX_KERNEL_FEATURES, 128, 5 + 256, False),  # the set lookup
+    (hk.TR, hk._MAX_KERNEL_FEATURES, 128, 5 + 256, True),  # a smaller tile
+    (391 * hk.TR, 2000, 32, 4, True),  # Epsilon
+    (hk.TR, 6000, 32, 4, False),
     (hk.TR, 384, 32, 4, True),
     (hk.TR, 300, 128, 4, True),
 ])

@@ -325,14 +325,19 @@ def test_pallas_route_fits_the_cells_by_the_new_blocks(monkeypatch, cell):
     assert hk.pallas_route_fits(n, F, Kp, 4)
     step = _route_step_bytes(F, Kp, 4)
     assert step < hk._VMEM_HOIST_BUDGET
-    # the model is that arithmetic to the byte ...
+    # the cells keep the whole ``TR`` tile (since PR 35 the tile is chosen
+    # from the width, ``_route_tr``), and the model at that tile is that
+    # arithmetic to the byte: a byte less and the tile halves ...
+    assert hk._route_tr(n, F, Kp, 4) == hk.TR
+    assert hk._route_vmem_bytes(hk.TR, F, Kp, 4) == step
     monkeypatch.setattr(hk, "_VMEM_HOIST_BUDGET", step)
-    assert hk.pallas_route_fits(n, F, Kp, 4)
+    assert hk._route_tr(n, F, Kp, 4) == hk.TR
     monkeypatch.setattr(hk, "_VMEM_HOIST_BUDGET", step - 1)
-    assert not hk.pallas_route_fits(n, F, Kp, 4)
-    # ... in which positions in and out are (1, TR) rows: 128 KiB a step
-    # double-buffered, where the (TR, 1) columns took 2 MiB
-    doc = " ".join(hk.pallas_route_fits.__doc__.split())
-    assert "``(1, TR)`` rows" in doc and "``(Kp, W)``" in doc
-    assert "(TR, 1)" not in doc.replace("``(TR, 1)`` columns took", "")
+    assert hk._route_tr(n, F, Kp, 4) == hk.TR // 2
+    assert hk.pallas_route_fits(n, F, Kp, 4)
+    # ... in which positions in and out are (1, tr) rows: 128 KiB a step
+    # double-buffered, where the (tr, 1) columns took 2 MiB
+    doc = " ".join(hk._route_vmem_bytes.__doc__.split())
+    assert "``(1, tr)`` rows" in doc and "``(Kp, W)``" in doc
+    assert "(tr, 1)" not in doc.replace("``(tr, 1)`` columns took", "")
     assert 2 * 2 * 8 * hk.TR * 4 == 128 * 1024

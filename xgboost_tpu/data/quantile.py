@@ -237,6 +237,95 @@ def _cuts_dispatch(Xj: jax.Array, wj: jax.Array, max_bin: int):
     return _cuts_kernel(Xj, wj, max_bin)
 
 
+# The whole-matrix sketch program holds about seven ``[n, F]`` 4-byte arrays
+# at its peak (the float32 block, the sort keys, the argsort's int32 order,
+# the sorted values, the weights twice, the CDF): 7.4 GB at 2.27M x 136, and
+# more than a 16 GB chip has past about 500M cells. A column's cuts and bins
+# depend on that column alone, so a matrix the device cannot take at once
+# goes through the same programs a block of columns at a time, and the
+# result is the whole-matrix program's to the bit.
+_SKETCH_BYTES_PER_CELL = 28
+
+# test hook: columns a block, whatever the device's memory says
+_FORCE_BLOCK_COLS: Optional[int] = None
+
+
+def sketch_block_cols(n: int, F: int) -> int:
+    """Columns the sketch and the binning take in one device program: all
+    ``F`` (the whole-matrix route every narrow matrix keeps) where the
+    program's working set, ``_SKETCH_BYTES_PER_CELL`` a cell, is within
+    three quarters of the device's free memory or the backend reports none
+    (the CPU); else equal blocks of at most the columns that fit half of
+    it, an exact divisor of ``F`` where one is near (one compiled shape)."""
+    if _FORCE_BLOCK_COLS is not None:
+        return max(1, min(int(_FORCE_BLOCK_COLS), F))
+    from ..tree.hist_kernel import device_free_bytes
+
+    free = device_free_bytes()
+    if free is None or _SKETCH_BYTES_PER_CELL * n * F <= 0.75 * free:
+        return F
+    fit = max(1, int(0.5 * free) // (_SKETCH_BYTES_PER_CELL * max(n, 1)))
+    blocks = -(-F // fit)
+    for nb in range(blocks, 2 * blocks + 1):
+        if F % nb == 0:
+            return F // nb
+    return -(-F // blocks)
+
+
+def _column_blocks(X, what: str, cols: Optional[int] = None):
+    """The one column-block loop of the sketch and the binning: ``X`` a
+    block of columns at a time as float32 device arrays, each under a
+    ``sketch_block`` span and counted in ``sketch_blocks_total``; yields
+    ``(f0, f1, block)``. ``X`` is a dense matrix, cut by
+    ``sketch_block_cols`` (one block, the whole matrix as it always went
+    up, where it fits), or a CSR storage, whose NaN-filled dense columns
+    (``dense_cols``) come ``cols`` at a time."""
+    from ..observability import REGISTRY, trace
+
+    n, F = int(X.shape[0]), int(X.shape[1])
+    take = getattr(X, "dense_cols", None)
+    if cols is None:
+        cols = sketch_block_cols(n, F)
+    if take is None and cols >= F:
+        yield 0, F, jnp.asarray(X, dtype=jnp.float32)
+        return
+    for b, f0 in enumerate(range(0, F, cols)):
+        f1 = min(f0 + cols, F)
+        with trace.span("sketch_block", block=b, cols=f1 - f0, what=what):
+            blk = X[:, f0:f1] if take is None else take(f0, f1)
+            if isinstance(blk, np.ndarray):
+                blk = np.ascontiguousarray(blk, dtype=np.float32)
+            yield f0, f1, jnp.asarray(blk, dtype=jnp.float32)
+        REGISTRY.counter(
+            "sketch_blocks_total",
+            "Column blocks the sketch and the binning took one at a time",
+        ).inc()
+
+
+def _cuts_by_blocks(X, weights: jax.Array, max_bin: int,
+                    cols: Optional[int] = None):
+    """(values ``[F, max_bin]``, min_vals ``[F]``) of ``X``'s columns, a
+    block at a time (``_column_blocks``): a column's cuts depend on that
+    column alone, so they are the whole-matrix program's to the bit."""
+    F = int(X.shape[1])
+    values = np.empty((F, max_bin), np.float32)
+    min_vals = np.empty((F,), np.float32)
+    for f0, f1, Xb in _column_blocks(X, "cuts", cols):
+        v, m = _cuts_dispatch(Xb, weights, max_bin)
+        values[f0:f1] = np.asarray(v)
+        min_vals[f0:f1] = np.asarray(m)
+    return values, min_vals
+
+
+def _bins_by_blocks(X, cuts: "HistogramCuts", cols: Optional[int] = None):
+    """The narrow bins of ``X``'s columns against ``cuts``, a block at a
+    time: yields ``(f0, f1, bins block)`` on the device."""
+    cut_j = jnp.asarray(cuts.values)
+    dtype = storage_dtype(cuts.max_bin)
+    for f0, f1, Xb in _column_blocks(X, "bins", cols):
+        yield f0, f1, _bins_dispatch(Xb, cut_j[f0:f1], dtype)
+
+
 def compute_cuts(
     X: np.ndarray | jax.Array,
     max_bin: int = 256,
@@ -248,22 +337,23 @@ def compute_cuts(
     Categorical features get IDENTITY cuts ``[1, 2, ..., max_bin]`` so a
     category code ``c`` lands in bin ``c`` — one bin per category, the same
     one-bin-per-category layout the reference builds for categorical data
-    (``hist_util.cc`` AddCutPoint categorical path)."""
+    (``hist_util.cc`` AddCutPoint categorical path). A matrix too large
+    for the device to sketch at once goes a block of columns at a time
+    (``sketch_block_cols``); the cuts are the same to the bit."""
     import time
 
     from ..observability import flight, trace
 
-    X = jnp.asarray(X, dtype=jnp.float32)
+    if not hasattr(X, "shape"):
+        X = np.asarray(X, dtype=np.float32)
+    n, F = int(X.shape[0]), int(X.shape[1])
     if weights is None or (hasattr(weights, "size") and weights.size == 0):
-        weights = jnp.ones((X.shape[0],), dtype=jnp.float32)
+        weights = jnp.ones((n,), dtype=jnp.float32)
     else:
         weights = jnp.asarray(weights, dtype=jnp.float32)
     t0 = time.perf_counter()
-    with trace.span("sketch", rows=int(X.shape[0]), features=int(X.shape[1]),
-                    max_bin=max_bin):
-        values, min_vals = _cuts_dispatch(X, weights, max_bin)
-        values = np.array(values)
-        min_vals = np.array(min_vals)
+    with trace.span("sketch", rows=n, features=F, max_bin=max_bin):
+        values, min_vals = _cuts_by_blocks(X, weights, max_bin)
     flight.note("sketch", time.perf_counter() - t0)
     if categorical:
         apply_categorical_identity(values, min_vals, categorical)
@@ -370,14 +460,17 @@ def _bins_dispatch(Xj: jax.Array, cut_values: jax.Array, dtype) -> jax.Array:
 
 def bin_matrix(X: np.ndarray | jax.Array, cuts: HistogramCuts) -> jax.Array:
     """Quantize a dense matrix against cuts. Analog of
-    ``GHistIndexMatrix::Init`` / ELLPACK packing (``gradient_index.cc:199``)."""
+    ``GHistIndexMatrix::Init`` / ELLPACK packing (``gradient_index.cc:199``).
+    By the column blocks of the sketch where the matrix is too large for
+    one program (``sketch_block_cols``): the narrow bins of the blocks are
+    joined on the device."""
     from ..observability import trace
 
-    with trace.span("quantize", rows=int(np.shape(X)[0]),
-                    max_bin=cuts.max_bin):
-        Xj = jnp.asarray(X, dtype=jnp.float32)
-        return _bins_dispatch(Xj, jnp.asarray(cuts.values),
-                              storage_dtype(cuts.max_bin))
+    if not hasattr(X, "shape"):
+        X = np.asarray(X, dtype=np.float32)
+    with trace.span("quantize", rows=int(X.shape[0]), max_bin=cuts.max_bin):
+        parts = [b for _, _, b in _bins_by_blocks(X, cuts)]
+        return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
 
 
 @dataclasses.dataclass
@@ -658,24 +751,13 @@ class BinnedMatrix:
         else:
             w = jnp.asarray(weights, dtype=jnp.float32)
 
-        blocks = [(f0, min(f0 + col_block, F)) for f0 in range(0, F, col_block)]
         if cuts is None:
-            vals = np.empty((F, max_bin), np.float32)
-            mins = np.empty((F,), np.float32)
-            for f0, f1 in blocks:
-                Xb = storage.dense_cols(f0, f1)
-                v, m = _cuts_dispatch(jnp.asarray(Xb), w, max_bin)
-                vals[f0:f1] = np.asarray(v)
-                mins[f0:f1] = np.asarray(m)
+            vals, mins = _cuts_by_blocks(storage, w, max_bin, col_block)
             cuts = HistogramCuts(values=vals, min_vals=mins)
             if cat:
                 apply_categorical_identity(cuts.values, cuts.min_vals, list(cat))
-        dtype = storage_dtype(cuts.max_bin)
-        bins = np.empty((n, F), dtype=np.dtype(dtype))
-        cut_j = jnp.asarray(cuts.values)
-        for f0, f1 in blocks:
-            Xb = storage.dense_cols(f0, f1)
-            bb = _bins_dispatch(jnp.asarray(Xb), cut_j[f0:f1], dtype)
+        bins = np.empty((n, F), dtype=np.dtype(storage_dtype(cuts.max_bin)))
+        for f0, f1, bb in _bins_by_blocks(storage, cuts, col_block):
             bins[:, f0:f1] = np.asarray(bb)
         counts: Tuple[int, ...] = ()
         if cat:

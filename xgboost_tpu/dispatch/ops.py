@@ -138,7 +138,7 @@ def _pallas_level_applicable(ctx: Ctx) -> bool:
     return bool(ctx.get("pallas")) and hist_kernel.pallas_level_fits(
         int(ctx.get("rows", 0)), int(ctx.get("features", 0)),
         int(ctx.get("nodes", 1)), int(ctx.get("bins", 0)),
-        int(ctx.get("onehot_width", 0)))
+        int(ctx.get("onehot_width", 0)), int(ctx.get("table_width", 4)))
 
 
 register("level_hist", "pallas", pref=(("*", 0),),

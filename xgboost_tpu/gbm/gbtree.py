@@ -1548,11 +1548,17 @@ class GBTree:
         exactly; results match the per-round path to float-fusion noise.
         Under an active mesh the whole chunk runs inside one shard_map
         (distributed_boost_rounds_scan)."""
+        from ..tree.hist_kernel import feature_tile
+
         t0 = _time.perf_counter()
         per_round = self.n_groups * self.gbtree_param.num_parallel_tree
+        tile = feature_tile(binned.n_features, binned.cuts.max_bin,
+                            self.train_param.max_depth) \
+            if _pallas_flag(None) else 0
         with _trace.span("scan_chunk", start=start_iteration,
                          rounds=num_rounds, groups=self.n_groups,
-                         trees=per_round * num_rounds):
+                         trees=per_round * num_rounds,
+                         features=binned.n_features, feature_tile=tile):
             out = self._boost_rounds_scan_impl(
                 binned, obj, label, weight, margin, start_iteration,
                 num_rounds, feature_weights)

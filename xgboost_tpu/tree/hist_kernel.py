@@ -45,7 +45,7 @@ from __future__ import annotations
 
 import functools
 import threading
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -58,7 +58,8 @@ __all__ = [
     "fused_level_trees", "level_trees", "derive_siblings",
     "partition_apply", "partition_apply_xla", "leaf_delta",
     "TR", "use_pallas", "use_native_hist", "build_onehot",
-    "pallas_level_fits", "pallas_route_fits",
+    "pallas_level_fits", "pallas_route_fits", "level_plan", "LevelPlan",
+    "feature_tile",
     "hoist_budget_bytes", "can_hoist", "hoist_plan", "device_free_bytes",
 ]
 
@@ -72,9 +73,23 @@ _INTERPRET = False
 # 0xFFFF0000 as int32: masks an f32 down to its bf16-representable prefix
 _MASK_HI = np.int32(np.uint32(0xFFFF0000).view(np.int32))
 
-# kernels unroll the feature loop; very wide matrices would explode compile
-# time, so the dispatcher falls back to XLA beyond this width
+# The UNTILED level kernels unroll the feature loop over every column and
+# keep the whole ``[2K, F*B]`` accumulator in VMEM: they take a matrix up to
+# this width. A wider one (or a narrower one whose accumulator outgrows
+# VMEM) goes to the TILED kernel below them, whose accumulator and
+# unrolled loop cover ``_FEATURE_TILE`` columns whatever ``F`` is.
 _MAX_KERNEL_FEATURES = 512
+
+# Columns of a feature tile of the tiled level kernel: the lane width of
+# the ``(tr, ft)`` i32 bins block (a Mosaic block's last dimension is a
+# multiple of 128 or the array's own). The bins are padded to whole tiles
+# with the missing bin, whose one-hot is all zero.
+_FEATURE_TILE = 128
+
+# test hook: a feature tile forced on the tiled kernel, and every level sent
+# to it, so that the CPU suite can hold a narrow matrix's tiles against the
+# untiled kernels (the interpreter takes any block width)
+_FORCE_TILE: Optional[int] = None
 
 
 def use_pallas() -> bool:
@@ -167,14 +182,37 @@ def fused_level_native(bins, pos, gh, ptab, *, K, Kp, B, d=None,
     return pos_new.reshape(1, n), hist
 
 
+def _fell_off_mosaic(dec) -> bool:
+    """Whether a TPU job's level or last routing resolved to XLA with
+    nobody asking: a route the user pinned (``XGBTPU_DISPATCH``) is a
+    choice, not a fall-back."""
+    return (dec.impl == "xla" and dec.reason != "pinned"
+            and jax.default_backend() == "tpu")
+
+
+def _warn_off_mosaic(dec, what: str, n: int, F: int, K: int, B: int) -> None:
+    """One ``console_logger.warning`` a shape where a TPU job's level or
+    last routing falls off the Mosaic kernels to XLA (a ``segment_sum``
+    scatter, a gather: a few GB/s on this chip), so that a matrix no
+    kernel takes does not crawl unseen (``_fell_off_mosaic``)."""
+    from ..dispatch.core import _warn_once
+
+    _warn_once(
+        f"offmosaic:{dec.op}:{n}:{F}:{B}",
+        f"tpu_hist: {what} of a {n} x {F} matrix ({B} bins, {K} nodes) fits "
+        f"no Mosaic kernel and runs in XLA ({dec.reason}: {dec.detail}); "
+        "expect it to be slow")
+
+
 def partition_apply(bins, pos, ptab, *, Kp: int, B: int, d: int,
                     pallas: bool = False, axis_name=None):
     """Route rows (``pos`` [1, n] i32 in, [1, n] i32 out) through level
     ``d-1``'s decisions, by the impl the dispatch registry resolves
     ``level_partition`` to: the Mosaic routing kernel where the call
-    site's ``pallas`` flag is set and the tile fits (TPU: a gather streams
-    at a few GB/s there), the native FFI kernel on the CPU path, XLA
-    everywhere else (identical integer decisions)."""
+    site's ``pallas`` flag is set and a row tile fits at this width
+    (``_route_tr``; TPU: a gather streams at a few GB/s there), the native
+    FFI kernel on the CPU path, XLA everywhere else (identical integer
+    decisions)."""
     from ..dispatch import Ctx, resolve
 
     n, F = bins.shape
@@ -183,6 +221,8 @@ def partition_apply(bins, pos, ptab, *, Kp: int, B: int, d: int,
         interpret=bool(_INTERPRET), rows=int(n), features=int(F),
         nodes=int(Kp), table_width=int(ptab.shape[-1]),
         bins_dtype=str(bins.dtype), sharded=axis_name is not None))
+    if pallas and _fell_off_mosaic(dec):
+        _warn_off_mosaic(dec, "the last routing", n, F, Kp, B)
     if dec.impl == "pallas":
         vma = ()
         if axis_name is not None:
@@ -190,7 +230,17 @@ def partition_apply(bins, pos, ptab, *, Kp: int, B: int, d: int,
             # ``fused_level``
             vma = (axis_name,)
             ptab = jax.lax.pcast(ptab, vma, to="varying")
-        return _route_rows_pallas(bins, pos, ptab, Kp=Kp, B=B, d=d, vma=vma)
+        ft = _tile_at(F, B, 1)
+        if ft:
+            # even the root ran the tiled kernel, so every level read the
+            # bins padded to whole feature tiles: route on that array, and
+            # the tree keeps one widened copy and not two. (A matrix whose
+            # deep levels alone are tiled keeps the unpadded array its
+            # shallow levels and this routing read, the padded one beside.)
+            bins = _pad_features(bins, ft, B)
+        return _route_rows_pallas(
+            bins, pos, ptab, Kp=Kp, B=B, d=d, vma=vma,
+            tr=_route_tr(n, bins.shape[1], Kp, ptab.shape[-1]) or TR)
     if dec.impl == "native":
         from ..native import boundary
 
@@ -286,7 +336,10 @@ def hoist_plan(n_pad: int, F: int, B: int, max_depth: int = 6) -> int:
     first Fh features and constructs the rest in-kernel (the
     feature-group partitioning idea of the reference's
     gpu_hist/histogram.cu:127-177 applied to the resident expansion);
-    0 means construct everything."""
+    0 means construct everything. The streaming kernel is untiled (its
+    ``[2K, F*B]`` accumulator is counted whole), so a matrix only the
+    tiled kernel takes gets 0 here: every column's one-hot is built in
+    VMEM."""
     if not use_pallas() or B <= 0 or n_pad <= 0:
         return 0
     budget = hoist_budget_bytes()
@@ -537,12 +590,14 @@ def _route_and_channels(pos_ref, binsb, gh_ref, ptab_ref, built_ref, pos_out,
     every tree's hi terms above every tree's lo terms, so that the same
     ``out[:half] + out[half:]`` leaves tree t's ``[g, h]`` at rows ``2Kc t
     .. 2Kc (t + 1)``: per tree the one-tree kernel's arithmetic, row for
-    row. Writes the routed positions to ``pos_out``."""
+    row. Writes the routed positions to ``pos_out``; None where the rows
+    came routed already (the tiled kernel: ``Kp`` 0, no bins, no table)."""
     if T is None:
         pos, terms = _route_and_terms(pos_ref[:, :], binsb, gh_ref, ptab_ref,
                                       built_ref, Kp=Kp, B=B, **kw)
         chans = jnp.concatenate(terms, axis=0).astype(jnp.bfloat16)
-        pos_out[:, :] = pos
+        if pos_out is not None:
+            pos_out[:, :] = pos
         return chans
     bins_op = _bins_operand(binsb, B) if Kp > 0 else None
     hi, lo = [], []
@@ -550,7 +605,8 @@ def _route_and_channels(pos_ref, binsb, gh_ref, ptab_ref, built_ref, pos_out,
         pos, terms = _route_and_terms(
             pos_ref[t:t + 1, :], binsb, gh_ref, ptab_ref, built_ref, Kp=Kp,
             B=B, tree=t, bins_op=bins_op, **kw)
-        pos_out[t:t + 1, :] = pos
+        if pos_out is not None:
+            pos_out[t:t + 1, :] = pos
         hi += terms[:2]
         lo += terms[2:]
     return jnp.concatenate(hi + lo, axis=0).astype(jnp.bfloat16)
@@ -580,12 +636,19 @@ def _level_kernel(bins_ref, pos_ref, gh_ref, ptab_ref, *rest,
         hist_ref[...] = jnp.zeros_like(hist_ref)
 
     binsb = bins_ref[:, :]  # [Tr, F] i32
-    Tr = binsb.shape[0]
     M = 2 * (K if built_ref is None else Kp) * (T or 1)  # histogram rows
     ghs4 = _route_and_channels(  # [2M, Tr]
         pos_ref, binsb, gh_ref, ptab_ref, built_ref, pos_out, T=T, K=K,
         Kp=Kp, F=F, B=B, prev_offset=prev_offset, offset=offset)
 
+    _construct_columns(hist_ref, binsb, ghs4, M, B)
+
+
+def _construct_columns(hist_ref, binsb, ghs4, M: int, B: int):
+    """The construct loop of the kernels whose accumulator is ``[columns,
+    M, B]``: for every column of the ``[Tr, columns]`` bins tile, its
+    one-hot built in VMEM and met with the channels ``[2M, Tr]``."""
+    Tr, F = binsb.shape
     for f in range(F):
         col = binsb[:, f:f + 1]
         iota_b = jax.lax.broadcasted_iota(jnp.int32, (Tr, B), 1)
@@ -623,7 +686,7 @@ def _built_children(ptab, *, Kp: int, d: int, sub: bool):
     mark = ptab[..., 0].astype(jnp.int32)  # [Kp], or [T, Kp]
     parent = ((1 << (d - 1)) - 1) + jnp.arange(Kp, dtype=jnp.int32)
     built = jnp.where(mark > 0, 2 * parent + mark, -1)[..., None]
-    return [built], [pl.BlockSpec(built.shape, lambda c: (0,) * built.ndim,
+    return [built], [pl.BlockSpec(built.shape, lambda *_: (0,) * built.ndim,
                                   memory_space=pltpu.VMEM)]
 
 
@@ -788,6 +851,132 @@ def _hoisted_level_pallas(bins, onehot, pos, gh, ptab, *, K, Kp, B, d,
                                   (0, 2, 1, 3))
 
 
+# ---------------------------------------------------------------------------
+# The TILED level kernel: an accumulator over a tile of columns. The untiled
+# kernels above keep ``[2K, F*B]`` f32 in VMEM and unroll their construct
+# loop over every column, which stops them near a hundred columns at 256
+# bins and at ``_MAX_KERNEL_FEATURES`` outright. Here the grid has a second,
+# OUTER axis over feature tiles: the accumulator block is the tile's
+# ``[ft, 2K, B]``, the unrolled loop runs over ``ft`` columns whatever ``F``
+# is, and the row tiles are swept once a feature tile, inner, so a block of
+# the accumulator stays in VMEM for its whole sweep. A row's route depends on
+# its whole bins row (``_partition_tile``: ``[Kp, F] @ binsb^T``), so it is
+# NOT redone a feature tile (that would read the ``[n, F]`` i32 bins F / ft
+# times a level): the rows are routed ONCE a level by the routing kernel
+# (``_route_rows_pallas``, under ``xgb.partition``), and the tiles read the
+# routed positions and their own columns. Every column's one-hot is built in
+# VMEM (nothing resident is streamed: ``hoist_plan``), and a tile's step stays
+# inside the budget the untiled streaming step has (``_tile_tr``).
+# Per histogram cell the sums are the untiled kernel's, row tile by row tile
+# in the same order: at the same row tile the result is the untiled kernel's
+# bit for bit (tests/test_feature_tiles.py).
+# ---------------------------------------------------------------------------
+
+
+def _tiled_level_kernel(bins_ref, pos_ref, gh_ref, *rest, K: int, B: int,
+                        offset: int, T=None):
+    """Grid step (feature tile j, row tile c): ``_level_kernel``'s construct
+    loop over the ``ft`` columns of the ``(Tr, ft)`` bins block into the
+    tile's ``[ft, 2 T Kc, B]`` accumulator, zeroed at the tile's first row
+    tile. ``rest``: the output ``hist_ref``, behind ``built_ref`` where
+    siblings are subtracted."""
+    from jax.experimental import pallas as pl
+
+    *built_ref, hist_ref = rest
+    built_ref = built_ref[0] if built_ref else None
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        hist_ref[...] = jnp.zeros_like(hist_ref)
+
+    # rows ALREADY at this level: the untiled kernels' channel stack, in
+    # the same row order, with no routing and nothing written
+    ghs4 = _route_and_channels(pos_ref, None, gh_ref, None, built_ref, None,
+                               T=T, K=K, Kp=0, F=0, B=0, prev_offset=0,
+                               offset=offset)
+    _construct_columns(hist_ref, bins_ref[:, :], ghs4, ghs4.shape[0] // 2, B)
+
+
+# "level" in the name: the benchmark books a Mosaic call so named to the
+# level histogram (reduce/summary.py)
+@guard_jit(name="tiled_level_pallas",
+           static_argnames=("K", "Kp", "B", "d", "tr", "ft", "vma", "sub"))
+def _tiled_level_pallas(bins, pos, gh, ptab, *, K, Kp, B, d, tr, ft, vma=(),
+                        sub=False):
+    """One level's histogram by feature tiles: routed ``pos`` ``[R, n]``,
+    ``gh`` ``[2R, n]`` and the i32 bins padded to whole tiles in, ``[Fp,
+    2 R Kc, B]`` out. Grid (feature tiles, row tiles); positions ``(R,
+    tr)`` and gradients ``(2R, tr)`` by row tile, the built children's ids
+    where siblings are subtracted."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n, Fp = bins.shape
+    assert Fp % ft == 0, (Fp, ft)
+    assert n % tr == 0, f"rows {n} not padded to {tr}"
+    R = pos.shape[0]
+    M = 2 * R * (Kp if sub else K)
+    built, built_specs = _built_children(ptab, Kp=Kp, d=d, sub=sub)
+    kern = functools.partial(_tiled_level_kernel, K=K, B=B,
+                             offset=(1 << d) - 1,
+                             T=R if ptab.ndim == 3 else None)
+    return pl.pallas_call(
+        kern,
+        grid=(Fp // ft, n // tr),
+        in_specs=[
+            pl.BlockSpec((tr, ft), lambda j, c: (c, j),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((R, tr), lambda j, c: (0, c),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((2 * R, tr), lambda j, c: (0, c),
+                         memory_space=pltpu.VMEM),
+        ] + built_specs,
+        out_specs=pl.BlockSpec((ft, M, B), lambda j, c: (j, 0, 0),
+                               memory_space=pltpu.VMEM),
+        out_shape=_vma_struct((Fp, M, B), jnp.float32, vma),
+        interpret=_INTERPRET,
+    )(bins, pos, gh, *built)
+
+
+def _pad_features(bins, ft: int, B: int):
+    """The i32 bins padded to whole feature tiles with the missing bin
+    (an all-zero one-hot: a padded column's histogram is zero and is cut
+    off). The same expression wherever a tree's program asks for it, so
+    XLA keeps one padded array a tree (it folds the pad into the
+    widening), read by every level's tiles and routing."""
+    pad = -bins.shape[1] % ft
+    if not pad:
+        return bins
+    return jnp.pad(bins, ((0, 0), (0, pad)), constant_values=B)
+
+
+def _tiled_level(bins, pos, gh, ptab, *, K, Kp, B, d, plan, vma, sub):
+    """One level through the tiled kernel (``plan.kernel == "tiled"``): the
+    rows routed once, by the routing kernel a tree (its Mosaic time is
+    ``xgb.partition``'s, not the level histogram's), then the feature
+    tiles; the contract of the untiled calls."""
+    n, F = bins.shape
+    T = ptab.shape[0] if ptab.ndim == 3 else None
+    bins = _pad_features(bins, plan.ft, B)
+    if Kp > 0:
+        tr_r = _route_tr(n, bins.shape[1], Kp, ptab.shape[-1])
+        with jax.named_scope("xgb.partition"):
+            if T is None:
+                pos = _route_rows_pallas(bins, pos, ptab, Kp=Kp, B=B, d=d,
+                                         tr=tr_r, vma=vma)
+            else:
+                pos = jnp.concatenate([
+                    _route_rows_pallas(bins, pos[t:t + 1], ptab[t], Kp=Kp,
+                                       B=B, d=d, tr=tr_r, vma=vma)
+                    for t in range(T)])
+    hist = _tiled_level_pallas(bins, pos, gh, ptab, K=K, Kp=Kp, B=B, d=d,
+                               tr=plan.tr, ft=plan.ft, vma=vma, sub=sub)[:F]
+    if T is None:
+        return pos, hist  # [F, 2Kc, B]
+    # [F, T * 2Kc, B] -> a tree's [F, 2Kc, B] each
+    return pos, jnp.transpose(hist.reshape(F, T, -1, B), (1, 0, 2, 3))
+
+
 def _route_kernel(bins_ref, pos_ref, ptab_ref, pos_out, *, Kp: int, F: int,
                   B: int, prev_offset: int):
     """One grid step of the tree's last routing: ``Tr`` rows (a ``(1, Tr)``
@@ -918,11 +1107,13 @@ _VMEM_HOIST_BUDGET = 12 * 1024 * 1024  # total working set of the hoisted step
 
 def _hoist_vmem_bytes(tr: int, Qh: int, K: int, F: int,
                       B: Optional[int] = None) -> int:
-    """Working-set estimate for one hoisted grid step: double-buffered int8
-    one-hot tile + its bf16 cast + the [4K, Qh] dot output + the [2K, F*B]
-    f32 accumulator (always full-width — the construct loop for unhoisted
-    features writes into it) + the bins tile + per-feature construct
-    scratch. ``B=None`` (legacy 3-arg callers) means full hoist: Qh==F*B."""
+    """Working-set estimate for one grid step of the UNTILED hoisted
+    kernel: double-buffered int8 one-hot tile + its bf16 cast + the
+    [4K, Qh] dot output + the [2K, F*B] f32 accumulator (full-width in
+    this kernel: the construct loop for unhoisted features writes into it;
+    the tiled kernel keeps ``_FEATURE_TILE`` columns of it,
+    ``_tile_vmem_bytes``) + the bins tile + per-feature construct scratch.
+    ``B=None`` (legacy 3-arg callers) means full hoist: Qh==F*B."""
     if B is None:
         B = Qh // F
     Q = F * B
@@ -932,57 +1123,161 @@ def _hoist_vmem_bytes(tr: int, Qh: int, K: int, F: int,
 
 
 def _hoist_tr(Qh: int, K: int, F: int, B: Optional[int] = None) -> int:
-    """Largest workable row tile for the hoisted kernel at this level's
-    node count, or 0 if no tile fits VMEM. Single source of truth for both
-    the build-side gate (``hoist_plan``) and the dispatch (``fused_level``)
-    so they cannot disagree."""
+    """Largest workable row tile for the untiled hoisted kernel at this
+    level's node count, or 0 if no tile fits VMEM. Single source of truth
+    for both the build-side gate (``hoist_plan``) and the dispatch
+    (``level_plan``) so they cannot disagree."""
     for tr in (TR_HOIST, TR_HOIST // 2, TR_HOIST // 4):
         if _hoist_vmem_bytes(tr, Qh, K, F, B) <= _VMEM_HOIST_BUDGET:
             return tr
     return 0
 
 
-def pallas_level_fits(rows: int, F: int, K: int, B: int,
-                      onehot_width: int = 0) -> bool:
-    """Whether SOME pallas level kernel fits this level's working set:
-    the hoisted streaming kernel (when a resident one-hot of
-    ``onehot_width`` lanes exists and a row tile divides ``rows``) or the
-    in-kernel construction (feature/accumulator VMEM gates). The
-    ``level_hist`` registry predicate (dispatch/ops.py) and the kernel
-    branch below share this single model so they cannot disagree."""
-    if onehot_width:
-        tr = _hoist_tr(onehot_width, K, F, B)
-        if tr and rows % tr == 0:
-            return True
+def _construct_fits(F: int, K: int, B: int) -> bool:
+    """Whether the untiled construct-only kernel takes this level: a width
+    its unrolled loop was compiled at, and the whole accumulator in its
+    share of VMEM."""
     return F <= _MAX_KERNEL_FEATURES and F * 2 * K * B * 4 <= _VMEM_ACC_BUDGET
+
+
+def _up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def _tile_vmem_bytes(tr: int, ft: int, K: int, B: int) -> int:
+    """Working set of one grid step of the tiled kernel, at the tiles it
+    occupies (8 sublanes, 128 lanes), ``K`` the nodes of every tree the
+    call carries. Double-buffered blocks: the ``(tr, ft)`` i32 bins tile,
+    positions and gradients (8 and 16 sublanes of ``tr`` lanes), and the
+    ``[ft, 2K, B]`` f32 accumulator, whose block index moves with the
+    tile. Values: the ``[4K, tr]`` channel terms in f32 and bf16, a
+    column's ``[tr, B]`` compare and one-hot, the dot's ``[4K, B]``."""
+    lanes_b = _up(B, 128)
+    return (2 * 4 * (tr * _up(ft, 128) + 24 * tr)
+            + 2 * 4 * ft * _up(2 * K, 8) * lanes_b
+            + 4 * K * tr * (4 + 2)
+            + tr * lanes_b * (4 + 2) + 4 * K * lanes_b * 4)
+
+
+def _tile_tr(ft: int, K: int, B: int) -> int:
+    """Largest row tile of the tiled kernel under the budget the hoisted
+    step has; 0 where none fits (64 built nodes at 128 bins: the tile's
+    accumulator alone, double-buffered, is past it)."""
+    for tr in (TR, TR // 2, TR // 4, TR // 8):
+        if _tile_vmem_bytes(tr, ft, K, B) <= _VMEM_HOIST_BUDGET:
+            return tr
+    return 0
+
+
+class LevelPlan(NamedTuple):
+    """How one Mosaic level call runs: the VMEM model's answer for (rows,
+    columns, nodes, bins, resident one-hot). ``kernel`` ``"hoisted"`` and
+    ``"construct"`` are the untiled kernels at row tile ``tr``;
+    ``"tiled"`` sweeps ``tiles`` feature tiles of ``ft`` columns at row
+    tile ``tr``."""
+
+    kernel: str
+    tr: int
+    tiles: int = 1
+    ft: int = 0
+
+
+def level_plan(rows: int, F: int, K: int, B: int, onehot_width: int = 0,
+               table_width: int = 4) -> Optional[LevelPlan]:
+    """The ONE VMEM model of the level kernels, shared by the registry
+    predicate (``pallas_level_fits``) and the dispatch (``fused_level``,
+    ``fused_level_trees``, ``level_trees``); the build-side gate
+    (``hoist_plan``) asks the streaming kernel's part of it
+    (``_hoist_tr``). In order: the untiled streaming kernel (a resident
+    one-hot of ``onehot_width`` lanes, a row tile that divides ``rows``),
+    the untiled in-kernel construction (feature and accumulator gates),
+    then the tiled kernel, whose accumulator covers a tile of columns and
+    which needs the level's routing beside it (a decision table of
+    ``table_width`` columns); None where none fits. A shape either untiled
+    kernel takes never reaches the tiles."""
+    if _FORCE_TILE is None:
+        if onehot_width:
+            tr = _hoist_tr(onehot_width, K, F, B)
+            if tr and rows % tr == 0:
+                return LevelPlan("hoisted", tr)
+        if _construct_fits(F, K, B):
+            return LevelPlan("construct", TR)
+    ft = _FEATURE_TILE if _FORCE_TILE is None else _FORCE_TILE
+    Fp = _up(F, ft)
+    tr = _tile_tr(ft, K, B)
+    # rows come padded to ``TR`` (``pad_rows``), which every tile divides:
+    # like the untiled construction's, this gate reads the shape alone. The
+    # routing has at most K parents.
+    if not tr or not _route_tr(TR, Fp, K, table_width):
+        return None
+    return LevelPlan("tiled", tr, Fp // ft, ft)
+
+
+def pallas_level_fits(rows: int, F: int, K: int, B: int,
+                      onehot_width: int = 0, table_width: int = 4) -> bool:
+    """Whether SOME pallas level kernel fits this level's working set
+    (``level_plan``): the ``level_hist`` registry predicate
+    (dispatch/ops.py) and the kernel branch below share that single model
+    so they cannot disagree."""
+    return level_plan(rows, F, K, B, onehot_width, table_width) is not None
+
+
+def _tile_at(F: int, B: int, K: int) -> int:
+    """The feature tile where a level of ``K`` built nodes over this matrix
+    runs the tiled kernel with nothing resident, 0 where an untiled kernel
+    takes it or none does. Shape-only: the row count moves the row tile,
+    not the tile's width."""
+    plan = level_plan(TR, F, K, B)
+    return plan.ft if plan is not None and plan.kernel == "tiled" else 0
+
+
+def feature_tile(F: int, B: int, max_depth: int) -> int:
+    """Columns of a feature tile where the deepest level of a tree over
+    this matrix runs the tiled kernel, else 0 (``xgb.scan_chunk``'s
+    ``feature_tile``, where the Mosaic kernels run)."""
+    return _tile_at(F, B, 1 << max(max_depth - 2, 0))
+
+
+def _route_vmem_bytes(tr: int, F: int, Kp: int, W: int) -> int:
+    """One grid step of the routing kernel, counted at the tiles it
+    occupies (8 sublanes, 128 lanes). Blocks, double-buffered: the
+    ``(tr, F)`` i32 bins tile, positions in and out as ``(1, tr)`` rows
+    (8 sublanes each, 32 bytes a row of data where the ``(tr, 1)`` columns
+    took 512), the ``(Kp, W)`` decision table. Values of
+    ``_partition_tile``: the bins tile as loaded with its f32 and bf16
+    casts, every node's ``[Kp, F]`` feature one-hot, three ``[Kp, tr]``
+    (node one-hot, the nodes' bins, their product), the ``[W, tr]``
+    decisions, two ``[B, tr]`` for a categorical table's set lookup, and
+    sixteen ``[1, tr]`` rows."""
+    lanes_f, kp8 = _up(F, 128), _up(Kp, 8)
+    blocks = 2 * 4 * (tr * lanes_f + 2 * 8 * tr + kp8 * _up(W, 128))
+    values = (tr * lanes_f * (4 + 4 + 2) + kp8 * lanes_f * (4 + 2)
+              + 4 * tr * (3 * kp8 + _up(W, 8) + 16 * 8
+                          + (2 * _up(W - 5, 8) if W > 4 else 0)))
+    return blocks + values
+
+
+def _route_tr(rows: int, F: int, Kp: int, W: int) -> int:
+    """The routing kernel's row tile, chosen from the width: the largest
+    of ``TR`` down to ``TR / 8`` whose working set is inside the budget
+    the hoisted step has (``TR`` up to 512 columns, 256 rows at 2,000),
+    or 0; rows come in whole ``TR`` tiles (``pad_rows``), as they
+    always had to."""
+    if rows % TR:
+        return 0
+    for tr in (TR, TR // 2, TR // 4, TR // 8):
+        if _route_vmem_bytes(tr, F, Kp, W) <= _VMEM_HOIST_BUDGET:
+            return tr
+    return 0
 
 
 def pallas_route_fits(rows: int, F: int, Kp: int, W: int) -> bool:
     """Whether the routing kernel (``_route_rows_pallas``) fits: rows in
-    whole ``TR`` tiles, a width the level kernels take too
-    (``_MAX_KERNEL_FEATURES``: wider, no Mosaic kernel of this module has
-    been compiled) and one grid step's working set inside the budget the
-    hoisted step has. Counted at the tiles they occupy (8 sublanes, 128
-    lanes). Blocks, double-buffered: the ``(TR, F)`` i32 bins tile,
-    positions in and out as ``(1, TR)`` rows (8 sublanes each, 32 bytes a
-    row of data where the ``(TR, 1)`` columns took 512), the ``(Kp, W)``
-    decision table. Values of ``_partition_tile``: the bins tile as loaded
-    with its f32 and bf16 casts, every node's ``[Kp, F]`` feature one-hot,
-    three ``[Kp, TR]`` (node one-hot, the nodes' bins, their product), the
-    ``[W, TR]`` decisions, two ``[B, TR]`` for a categorical table's set
-    lookup, and sixteen ``[1, TR]`` rows. The ``level_partition`` registry
-    predicate is its one caller, as ``pallas_level_fits`` is
-    ``level_hist``'s."""
-    def up(x, m):
-        return -(-x // m) * m
-
-    lanes_f, kp8 = up(F, 128), up(Kp, 8)
-    blocks = 2 * 4 * (TR * lanes_f + 2 * 8 * TR + kp8 * up(W, 128))
-    values = (TR * lanes_f * (4 + 4 + 2) + kp8 * lanes_f * (4 + 2)
-              + 4 * TR * (3 * kp8 + up(W, 8) + 16 * 8
-                          + (2 * up(W - 5, 8) if W > 4 else 0)))
-    return (rows > 0 and rows % TR == 0 and 0 < F <= _MAX_KERNEL_FEATURES
-            and Kp > 0 and blocks + values <= _VMEM_HOIST_BUDGET)
+    whole tiles of a size its working set allows at this width
+    (``_route_tr``; the bins tile is the whole row, so the tile shrinks as
+    the matrix widens). The ``level_partition`` registry predicate is its
+    one caller, as ``pallas_level_fits`` is ``level_hist``'s."""
+    return rows > 0 and F > 0 and Kp > 0 and _route_tr(rows, F, Kp, W) > 0
 
 
 def derive_siblings(parent_hist, built, ptab):
@@ -1007,6 +1302,37 @@ def derive_siblings(parent_hist, built, ptab):
     return children.reshape(F, 4 * Kp, B)  # node 2*lp + side, g rows first
 
 
+def _level_call(bins, onehot, pos, gh, ptab, *, K, Kp, B, d, vma, sub):
+    """The Mosaic call(s) of one level, as ``level_plan`` says for the
+    nodes of every tree ``ptab`` carries: the streaming kernel, the
+    in-kernel construction, or the tiled kernel behind one routing
+    (``_tiled_level``; the printed routes then say how many feature tiles
+    the call swept)."""
+    n, F = bins.shape
+    trees = ptab.shape[0] if ptab.ndim == 3 else 1
+    nodes = trees * (Kp if sub else K)
+    W = ptab.shape[-1]
+    # two calls, not ``0 if onehot is None else ...``: the package's lint
+    # (TS103) reads that expression as a branch on a traced value
+    if onehot is None:
+        plan = level_plan(n, F, nodes, B, 0, W)
+    else:
+        plan = level_plan(n, F, nodes, B, onehot.shape[1], W)
+    if plan is not None and plan.kernel == "hoisted":
+        return _hoisted_level_pallas(bins, onehot, pos, gh, ptab, K=K, Kp=Kp,
+                                     B=B, d=d, tr=plan.tr, vma=vma, sub=sub)
+    if plan is not None and plan.kernel == "tiled":
+        from ..dispatch import note
+
+        note("feature_tiles", plan.tiles)
+        return _tiled_level(bins, pos, gh, ptab, K=K, Kp=Kp, B=B, d=d,
+                            plan=plan, vma=vma, sub=sub)
+    # the in-kernel construction; also what a pin to ``pallas`` gets where
+    # the model says nothing fits
+    return _fused_level_pallas(bins, pos, gh, ptab, K=K, Kp=Kp, B=B, d=d,
+                               vma=vma, sub=sub)
+
+
 def fused_level(bins, pos, gh, ptab, *, K, Kp, B, d, pallas: bool,
                 onehot: Optional[jax.Array] = None,
                 axis_name: Optional[str] = None,
@@ -1020,7 +1346,8 @@ def fused_level(bins, pos, gh, ptab, *, K, Kp, B, d, pallas: bool,
     platform preference in one lookup). ``onehot`` (the HBM-resident
     [n, F*B] int8 expansion) selects the streaming kernel inside the
     pallas impl; deep levels whose accumulators outgrow VMEM fall back to
-    the in-kernel construction, then to native/XLA.
+    the in-kernel construction, then to the tiled kernel (``level_plan``),
+    then to native/XLA.
 
     ``sibling_sub`` (a caller that holds the previous level's histogram and
     whose ``ptab`` marks a child of every split, ``d >= 1``): the pallas
@@ -1032,12 +1359,17 @@ def fused_level(bins, pos, gh, ptab, *, K, Kp, B, d, pallas: bool,
 
     n, F = bins.shape
     Kc = Kp if sibling_sub else K  # the nodes a pallas impl would build
+    oh_width = 0
+    if onehot is not None:
+        oh_width = int(onehot.shape[1])
     dec = resolve("level_hist", Ctx(
         platform=jax.default_backend(), pallas=bool(pallas),
         interpret=bool(_INTERPRET), rows=int(n), features=int(F),
         nodes=int(Kc), bins=int(B), table_width=int(ptab.shape[-1]),
         bins_dtype=str(bins.dtype), sharded=axis_name is not None,
-        onehot_width=0 if onehot is None else int(onehot.shape[1])))
+        onehot_width=oh_width))
+    if pallas and _fell_off_mosaic(dec):
+        _warn_off_mosaic(dec, f"level {d}", n, F, Kc, B)
     vma = (axis_name,) if axis_name is not None else ()
     if dec.impl == "pallas":
         if axis_name is not None:
@@ -1045,16 +1377,8 @@ def fused_level(bins, pos, gh, ptab, *, K, Kp, B, d, pallas: bool,
             # the psum'd histogram); the pallas boundary wants operands
             # uniformly varying, so relax it — a no-op on device
             ptab = jax.lax.pcast(ptab, (axis_name,), to="varying")
-        if onehot is not None:
-            tr = _hoist_tr(onehot.shape[1], Kc, F, B)
-            if tr and n % tr == 0:
-                return _hoisted_level_pallas(bins, onehot, pos, gh, ptab,
-                                             K=K, Kp=Kp, B=B, d=d, tr=tr,
-                                             vma=vma, sub=sibling_sub)
-        # reaching here means pallas_level_fits passed via the in-kernel
-        # construction gates, so the plain kernel is safe
-        return _fused_level_pallas(bins, pos, gh, ptab, K=K, Kp=Kp, B=B,
-                                   d=d, vma=vma, sub=sibling_sub)
+        return _level_call(bins, onehot, pos, gh, ptab, K=K, Kp=Kp, B=B,
+                           d=d, vma=vma, sub=sibling_sub)
     if dec.impl == "native":
         return fused_level_native(bins, pos, gh, ptab, K=K, Kp=Kp, B=B, d=d)
     return fused_level_xla(bins, pos, gh, ptab, K=K, Kp=Kp, B=B, d=d)
@@ -1065,19 +1389,27 @@ def level_trees(rows: int, F: int, Kc: int, B: int, trees: int,
     """How many of a round's ``trees`` (class trees x ``num_parallel_tree``)
     one Mosaic level call carries when each builds ``Kc`` nodes: the
     largest T dividing ``trees`` whose ``T x Kc`` nodes fit the kernel's
-    VMEM model, the streaming kernel's (``_hoist_tr``, at the resident
+    VMEM model (``level_plan``), the streaming kernel's (at the resident
     one-hot's width as planned: the plan is not asked again) or, with no
-    resident one-hot, the construct-only kernel's accumulator gate. The
-    level then runs ``trees / T`` calls; 1 means a call a tree through
+    resident one-hot, the construct-only kernel's accumulator gate; where
+    one tree's level already runs the tiled kernel, its. The level
+    then runs ``trees / T`` calls; 1 means a call a tree through
     ``fused_level``. Read from the shapes alone: nothing pins it."""
+    one = level_plan(rows, F, Kc, B, onehot_width)
+    tiled = one is not None and one.kernel == "tiled"
     for T in range(trees, 1, -1):
         if trees % T:
             continue
-        if onehot_width:
-            tr = _hoist_tr(onehot_width, T * Kc, F, B)
-            if tr and rows % tr == 0:
-                return T
-        elif rows % TR == 0 and pallas_level_fits(rows, F, T * Kc, B):
+        plan = level_plan(rows, F, T * Kc, B, onehot_width)
+        if plan is None:
+            continue
+        if tiled:  # a level the untiled kernels take stays with them
+            fits = plan.kernel == "tiled"
+        elif onehot_width:
+            fits = plan.kernel == "hoisted"
+        else:
+            fits = plan.kernel == "construct" and rows % TR == 0
+        if fits:
             return T
     return 1
 
@@ -1097,15 +1429,8 @@ def fused_level_trees(bins, pos, gh, ptab, *, K, Kp, B, d,
     may differ in its last bit, as a level's does from the next's today).
     The bins tile and the one-hot tile are read once a grid step and meet
     all T trees' gradient channels in one matmul."""
-    F = bins.shape[1]
-    Kc = Kp if sibling_sub else K
-    if onehot is not None:
-        return _hoisted_level_pallas(
-            bins, onehot, pos, gh, ptab, K=K, Kp=Kp, B=B, d=d,
-            tr=_hoist_tr(onehot.shape[1], ptab.shape[0] * Kc, F, B),
-            sub=sibling_sub)
-    return _fused_level_pallas(bins, pos, gh, ptab, K=K, Kp=Kp, B=B, d=d,
-                               sub=sibling_sub)
+    return _level_call(bins, onehot, pos, gh, ptab, K=K, Kp=Kp, B=B, d=d,
+                       vma=(), sub=sibling_sub)
 
 
 def leaf_delta(pos, leaf_values, max_nodes_pad: int, pallas: bool):
