@@ -391,7 +391,10 @@ def stage_wide(sz: Sizes, routes: dict) -> None:
     the kernels stage compares (positions identical, histograms in the
     hi/lo class; below the root also the built child with its sibling
     derived); the routing kernel at the width; and the sketch by column
-    blocks against the whole-matrix program."""
+    blocks against the whole-matrix program. Both kernels read the tree's
+    feature-major bins (ISSUE 36: ``[2048, n]``, a column's one-hot
+    ``[B, tr]``), which the dispatcher makes from the ``[n, 2000]`` array
+    it is handed, as a tree's program does."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -470,9 +473,11 @@ def stage_wide(sz: Sizes, routes: dict) -> None:
         check(bool((err <= tol).all()),
               f"{tag}: histogram off by {float((err - tol).max()):.3e} "
               "beyond tolerance")
-        say(f"  {tag}: {plan.tiles} tiles tr {plan.tr}: cold {cold:.2f}s warm {warm:.4f}s  pos identical, "
-            f"max|dhist| {float(err.max()):.2e}")
+        say(f"  {tag}: {plan.tiles} tiles of ({plan.ft}, {plan.tr}) "
+            f"feature-major: cold {cold:.2f}s warm {warm:.4f}s  pos "
+            f"identical, max|dhist| {float(err.max()):.2e}")
 
+    Fp = hk._up(F, hk._FEATURE_TILE)
     # d=6 under subtraction builds 32 nodes: the most a tile's accumulator
     # holds at 128 bins
     for d in (0, 3, 5, 6):
@@ -494,9 +499,9 @@ def stage_wide(sz: Sizes, routes: dict) -> None:
                           B=B, d=d)[0]
             check(np.array_equal(np.asarray(pos_r), want),
                   f"wide d={d} route_rows: pos differs from XLA")
-            say(f"  wide d={d} route_rows (tile "
-                f"{hk._route_tr(n, F, Kp, 4)}): cold {cold:.2f}s warm "
-                f"{warm:.4f}s  pos identical")
+            say(f"  wide d={d} route_rows (block ({Fp}, "
+                f"{hk._route_tr(n, Fp, Kp, 4)}) feature-major): cold "
+                f"{cold:.2f}s warm {warm:.4f}s  pos identical")
     _check_routes("wide", before, routes,
                   must_see=("level_hist", "level_partition", "sketch_cuts",
                             "bin_matrix"))

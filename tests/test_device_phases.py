@@ -186,10 +186,17 @@ def _benchmark_summary():
     return load("reduce/summary.py")
 
 
+def _compiled_text(fn, *shapes, **static):
+    return fn.lower(*shapes, **static).compile().as_text()
+
+
 def _mosaic_lines(fn, *shapes, **static):
     """The Mosaic calls ``fn`` compiles to, as the compiled module's text
     has them (a profile's op line names an op by this text)."""
-    hlo = fn.lower(*shapes, **static).compile().as_text()
+    return _mosaic_lines_of(_compiled_text(fn, *shapes, **static))
+
+
+def _mosaic_lines_of(hlo):
     return [line.strip() for line in hlo.splitlines()
             if 'custom_call_target="tpu_custom_call"' in line]
 
@@ -645,10 +652,14 @@ def test_wide_tree_compiles_on_the_tiled_kernels_under_their_names(
     """A whole tree program over a matrix no untiled kernel takes, compiled
     for a described v5e at real widths (8,192 rows: the kernels' blocks do
     not depend on the row count): the chip's compiler takes every tiled
-    level call (the tile's accumulator, the 128-lane bins block) and the
-    routing kernel at its 256-row tile; a level is one ``_tiled_level_pallas``, which the benchmark's
+    level call (the tile's accumulator, the ``(128, tr)`` block of the
+    feature-major bins) and the routing kernel at its 256-row tile; a level
+    is one ``_tiled_level_pallas``, which the benchmark's
     reduction books to the level histogram by its name, behind one
-    ``_route_rows_pallas`` under ``xgb.partition``, which it does not."""
+    ``_route_rows_pallas`` under ``xgb.partition``, which it does not.
+    Every one of them reads the feature-major ``s32[Fp, n]`` (ISSUE 36),
+    which the program makes ONCE a tree: one fusion carries the
+    transpose's name (XLA folds it into the widening and the pad)."""
     import jax.numpy as jnp
 
     from xgboost_tpu.tree import grow, grow_fused
@@ -658,18 +669,24 @@ def test_wide_tree_compiles_on_the_tiled_kernels_under_their_names(
 
     monkeypatch.setattr(hk, "use_pallas", lambda: True)
     n = 8192
-    lines = _mosaic_lines(
+    hlo = _compiled_text(
         grow_fused._grow_tree_fused_impl._guarded_jit,
         S((n, F), jnp.uint8 if B < 255 else jnp.uint16), S((n,), jnp.float32),
         S((n,), jnp.float32), S((F, B), jnp.float32), S((2,), jnp.uint32),
         S((), jnp.float32), S((), jnp.float32),
         cfg=grow.GrowParams(max_depth=depth))
+    lines = _mosaic_lines_of(hlo)
     summary = _benchmark_summary()
     levels = [ln for ln in lines
               if summary.is_level_kernel(ln.removeprefix("ROOT "))]
     routes = [ln for ln in lines if ln not in levels]
     assert len(levels) == depth and len(routes) == depth
     Fp = -(-F // 128) * 128
+    made = [ln for ln in hlo.splitlines() if " fusion(" in ln
+            and '/xgb.level_hist/transpose"' in ln]
+    assert len(made) == 1 and f" = s32[{Fp},{n}]" in made[0], made
+    for ln in lines:
+        assert f"operand_layout_constraints={{s32[{Fp},{n}]{{1,0}}, " in ln
     for d, ln in enumerate(levels):
         assert re.match(r"(?:ROOT )?%_tiled_level_pallas[.\d]* = ", ln), ln[:80]
         Kc = max(1 << d >> 1, 1)

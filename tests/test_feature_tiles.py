@@ -15,8 +15,15 @@ kernel bodies interpreted, at small sizes:
 (d) the VMEM model: every tile and row tile the plan returns is under the
     budget it states, a shape the untiled kernels take never reaches the
     tiles, the routing beside the tiles is asked at the table's own width,
-    and the span and the printed routes say what a call swept.
+    and the span and the printed routes say what a call swept;
+(e) the feature-major bins (ISSUE 36): the tiled kernel and the routing
+    beside it read ``[Fp, n]`` (a column's one-hot is ``[B, tr]``, rows on
+    the lanes); a tiled tree asks for that array as one expression of its
+    one widened matrix, and the four older cells' programs hold none.
 """
+
+import contextlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -80,8 +87,10 @@ LEVELS = [(0, False)] + [(d, sub) for d in (1, 3) for sub in (False, True)]
 @pytest.mark.parametrize("T", [None, 3])
 def test_tiles_add_up_to_the_untiled_kernel(mosaic_route, T, d, sub, ft, tr):
     """50 columns in tiles of 16 or 32 (the last padded with the missing
-    bin) against the untiled construction at the same row tile: the same
-    positions, and every histogram cell the same bits."""
+    bin; the ``(ft, tr)`` blocks of the feature-major bins, a column's
+    one-hot ``[B, tr]``) against the untiled construction (``(tr, F)``,
+    ``[tr, B]``) at the same row tile: the same positions, and every
+    histogram cell the same bits."""
     F, B = 50, 16
     bins, pos, gh, ptab = _level_inputs(F, B, d, T)
     K = 1 << d
@@ -227,6 +236,16 @@ def test_routing_kernel_at_the_width(mosaic_route, F):
     got = hk.partition_apply(bins, pos, ptab, Kp=8, B=B, d=d, pallas=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     assert bool((got != pos).any())
+    # what the dispatcher ran: the kernel on the tree's feature-major bins,
+    # a ``(Fp, tr)`` block a step; the row-major form gives the same rows
+    binsT = hk._feature_major(bins, 128, B)
+    Fp = hk._up(F, 128)
+    assert binsT.shape == (Fp, n) and bool((binsT[F:] == B).all())
+    assert hk._route_tr(n, Fp, 8, 4) == tr
+    for operand, major in ((binsT, True), (bins, False)):
+        got = hk._route_rows_pallas(operand, pos, ptab, Kp=8, B=B, d=d, tr=tr,
+                                    feature_major=major)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 # ---------------------------------------------------------------------------
@@ -312,6 +331,10 @@ def test_wide_plan_and_what_a_call_sweeps(monkeypatch):
             == hk.LevelPlan("tiled", 1024, 16, 128)
     assert hk.feature_tile(2000, 128, 6) == 128
     assert hk._tile_at(2000, 128, 1) == 128
+    # the models restated for the feature-major blocks (ISSUE 36) count
+    # the same bytes at whole tiles of 128: the routing's tile stays 256
+    assert [hk._route_tr(400384, 2048, Kp, 4) for Kp in (1, 2, 4, 8, 16, 32)] \
+        == [256] * 6
     assert hk.pallas_level_fits(500000, 2000, 1, 128)
     assert [hk.level_plan(8192, 200, Kc, 256).kernel
             for Kc in (1, 2, 4, 8, 16)] == ["construct"] * 4 + ["tiled"]
@@ -343,6 +366,149 @@ def test_the_tiles_ask_for_the_routing_at_the_tables_width(F, W, fits):
         nodes=Kc, bins=B, table_width=W, bins_dtype="uint8", sharded=False,
         onehot_width=0))
     assert (dec.impl == "pallas") == fits
+
+
+# ---------------------------------------------------------------------------
+# (e) the feature-major bins: one expression a tree, none in the older cells
+# ---------------------------------------------------------------------------
+
+
+def _feature_major_arrays(jaxpr, Fp, n):
+    """The ``transpose`` equations of a flat jaxpr that make an int32
+    ``[Fp, n]``, each with the variable its expression starts from (through
+    the pad to whole tiles)."""
+    made = {eqn.outvars[0]: eqn for eqn in jaxpr.eqns}
+    out = []
+    for eqn in jaxpr.eqns:
+        aval = eqn.outvars[0].aval
+        if eqn.primitive.name == "transpose" and aval.shape == (Fp, n) \
+                and aval.dtype == jnp.int32:
+            src = eqn.invars[0]
+            while src in made and made[src].primitive.name in ("pad", "pjit"):
+                src = made[src].invars[0]  # ``jnp.pad`` is a jitted call
+            out.append(src)
+    return out
+
+
+@pytest.mark.parametrize("trees", [1, 3])
+def test_a_tiled_tree_asks_for_one_feature_major_array(mosaic_route,
+                                                       monkeypatch, trees):
+    """A tiled tree's program (its jitted steps traced inline): the narrow
+    bins are widened once, a tree or, where a round's trees are grown
+    together, a round; every level's tiles, every routing below the root
+    and the last routing read a feature-major ``[Fp, n]`` that is the SAME
+    expression of that one widened array (what lets XLA keep one copy: the
+    program compiled for the chip holds one, tests/test_device_phases.py);
+    and no Mosaic call of the program reads the bins row-major."""
+    monkeypatch.setattr(hk, "_FORCE_TILE", 16)
+    n, F, B, depth = 1024, 50, 16, 3
+    Fp = 64
+    cfg = grow.GrowParams(max_depth=depth)
+    S = jax.ShapeDtypeStruct
+    eta, gamma = jnp.float32(0.3), jnp.float32(0.0)
+
+    def one(bins, g, h, cuts, key):
+        return grow_fused._grow_tree_fused_impl(bins, g[0], h[0], cuts,
+                                                key[0], eta, gamma, cfg)
+
+    def together(bins, g, h, cuts, key):
+        return grow_fused.grow_trees_one_pass(
+            bins, list(g), list(h), cuts, list(key), eta, gamma, cfg)
+
+    with jax.disable_jit():
+        jaxpr = jax.make_jaxpr(one if trees == 1 else together)(
+            S((n, F), jnp.uint8), S((trees, n), jnp.float32),
+            S((trees, n), jnp.float32), S((F, B), jnp.float32),
+            S((trees, 2), jnp.uint32)).jaxpr
+    widened = [e for e in jaxpr.eqns
+               if e.primitive.name == "convert_element_type"
+               and e.invars[0].aval.shape == (n, F)
+               and e.invars[0].aval.dtype == jnp.uint8]
+    assert len(widened) == 1
+    calls = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    levels = [e for e in calls  # the histogram out; a routing: positions
+              if e.outvars[0].aval.dtype == jnp.float32]
+    assert len(levels) >= depth
+    # routings: a tree's a level below the root, and its last
+    assert len(calls) - len(levels) == trees * depth
+    sources = _feature_major_arrays(jaxpr, Fp, n)
+    # asked for by every tiled level (its tiles and routings) and by every
+    # tree's last routing
+    assert len(sources) == len(levels) + trees
+    assert set(sources) == {widened[0].outvars[0]}
+    for e in calls:
+        assert e.invars[0].aval.shape == (Fp, n), e.invars[0].aval
+        assert not any(v.aval.shape in ((n, F), (n, Fp)) for v in e.invars)
+
+
+# columns, depth, hoist plan, objective and chips of the four older cells
+OLDER_CELLS = {
+    "anchor_train": (50, 6, 34, {"objective": "binary:logistic"}, 1),
+    "higgs_train_x4": (28, 8, 7, {"objective": "binary:logistic"}, 4),
+    "mslr_rank_train": (136, 6, 12, {"objective": "rank:ndcg",
+                                     "lambdarank_num_pair_per_sample": 1}, 1),
+    "covtype_train": (54, 6, 33, {"objective": "multi:softmax",
+                                  "num_class": 8}, 1),
+}
+
+
+@pytest.mark.parametrize("cell", OLDER_CELLS)
+def test_the_older_cells_programs_hold_no_feature_major_bins(
+        mosaic_route, monkeypatch, cell):
+    """The program each of the four older cells boosts with (the scan
+    chunk's, on one chip and under a mesh of four; MSLR's objective is not
+    scan-safe, so the per-round tree program), traced at the cell's
+    columns, 256 bins, depth, objective and hoist plan on 4,096 rows a chip:
+    every level call is the streaming kernel, none is tiled, and the
+    widened bins are never transposed: ISSUE 36 changed nothing they
+    run."""
+    from xgboost_tpu.gbm import gbtree
+    from xgboost_tpu.parallel import grow as pgrow
+    from xgboost_tpu.parallel import make_mesh, mesh_context
+
+    F, depth, plan, objective, chips = OLDER_CELLS[cell]
+    if len(jax.devices()) < chips:
+        pytest.skip(f"needs {chips} devices")
+    rows = 4096  # a feature's resident one-hot is 1 MiB: the plan in MiB
+    monkeypatch.setenv("XGBTPU_HOIST_BUDGET_MB", str(plan))
+    assert hk.hoist_plan(rows, F, 256, depth) == plan
+    n = rows * chips
+    rng = np.random.RandomState(1)
+    X = rng.randn(n, F).astype(np.float32)
+    y = rng.randint(0, 2, n).astype(np.float32)
+    kw = {"qid": np.repeat(np.arange(n // 64), 64)} \
+        if objective["objective"] == "rank:ndcg" else {}
+    params = dict(objective, tree_method="tpu_hist", max_depth=depth,
+                  max_bin=256, seed=1)
+    module, attr = (pgrow, "_dist_scan_impl") if chips > 1 else (
+        grow_fused, "_grow_tree_fused_impl") if kw else (
+        gbtree, "_scan_rounds_impl")
+    orig = getattr(module, attr)
+    jitted = getattr(orig, "_guarded_jit", orig)
+    traced = []
+
+    class Traced(Exception):
+        pass
+
+    def tracing(*args, **kwargs):
+        traced.append(jitted.trace(*args, **kwargs).jaxpr)
+        raise Traced  # nothing of the program runs
+
+    def boost():
+        d = xgb.DMatrix(X, label=y, **kw)
+        xgb.Booster(params, [d]).update_many(d, 0, 2, chunk=2)
+
+    monkeypatch.setattr(module, attr, tracing)
+    with pytest.raises(Traced), (mesh_context(make_mesh(chips)) if chips > 1
+                                 else contextlib.nullcontext()):
+        boost()
+    text = str(traced[0])
+    assert "tiled_level" not in text
+    assert text.count("_hoisted_level_pallas") >= depth
+    assert "_fused_level_pallas" not in text
+    assert re.search(r"i32\[%d,%d\]" % (rows, F), text)  # the widened bins
+    for cols in (F, hk._up(F, 128)):  # and never the rows minor
+        assert not re.search(r"i32\[%d,(%d|%d)\]" % (cols, rows, n), text)
 
 
 # ---------------------------------------------------------------------------

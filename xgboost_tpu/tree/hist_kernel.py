@@ -30,6 +30,9 @@ lane-dense form is laid out one and two sublanes deep in HBM (``T(1,128)``,
 ``T(2,128)``: 4 and 8 bytes a row), a ``(k, tr)`` block of it takes at most
 8 sublanes of VMEM (32 bytes a row) and is whole vregs. The XLA and native impls take the
 same form and reshape at their own edge (the C ABI keeps rows major).
+The bins are ``[n, F]`` for the untiled kernels (a column's one-hot is
+``[tr, B]``); a tree whose levels run the TILED kernel reads them
+feature-major, ``[Fp, n]``, rows on the lanes too (``_feature_major``).
 
 Missing values: the quantized matrix encodes missing as bin id ``B``; the
 one-hot over ``[0, B)`` is then all-zero, so missing rows simply drop out of
@@ -80,10 +83,10 @@ _MASK_HI = np.int32(np.uint32(0xFFFF0000).view(np.int32))
 # unrolled loop cover ``_FEATURE_TILE`` columns whatever ``F`` is.
 _MAX_KERNEL_FEATURES = 512
 
-# Columns of a feature tile of the tiled level kernel: the lane width of
-# the ``(tr, ft)`` i32 bins block (a Mosaic block's last dimension is a
-# multiple of 128 or the array's own). The bins are padded to whole tiles
-# with the missing bin, whose one-hot is all zero.
+# Columns of a feature tile of the tiled level kernel: the sublanes of the
+# ``(ft, tr)`` i32 block of the feature-major bins (``_feature_major``) and
+# the leading dimension of the accumulator block. The bins are padded to
+# whole tiles with the missing bin, whose one-hot is all zero.
 _FEATURE_TILE = 128
 
 # test hook: a feature tile forced on the tiled kernel, and every level sent
@@ -233,14 +236,16 @@ def partition_apply(bins, pos, ptab, *, Kp: int, B: int, d: int,
         ft = _tile_at(F, B, 1)
         if ft:
             # even the root ran the tiled kernel, so every level read the
-            # bins padded to whole feature tiles: route on that array, and
-            # the tree keeps one widened copy and not two. (A matrix whose
-            # deep levels alone are tiled keeps the unpadded array its
-            # shallow levels and this routing read, the padded one beside.)
-            bins = _pad_features(bins, ft, B)
+            # feature-major bins: route on that array, and the tree keeps
+            # one widened copy and not two. (A matrix whose deep levels
+            # alone are tiled keeps the row-major array its shallow levels
+            # and this routing read, the feature-major one beside.)
+            bins = _feature_major(bins, ft, B)
+            F = bins.shape[0]
         return _route_rows_pallas(
             bins, pos, ptab, Kp=Kp, B=B, d=d, vma=vma,
-            tr=_route_tr(n, bins.shape[1], Kp, ptab.shape[-1]) or TR)
+            feature_major=bool(ft),
+            tr=_route_tr(n, F, Kp, ptab.shape[-1]) or TR)
     if dec.impl == "native":
         from ..native import boundary
 
@@ -464,12 +469,14 @@ def _bins_operand(binsb, B: int):
 
 
 def _partition_tile(pos, binsb, ptab_ref, *, Kp: int, F: int, B: int,
-                    prev_offset: int, tree=None, bins_op=None):
+                    prev_offset: int, tree=None, bins_op=None,
+                    feature_major: bool = False):
     """Route a tile's rows through the previous level's decision table
     (shared by the level kernels and the routing kernel). ``pos`` is
     ``[1, Tr]`` i32 (rows on the lanes) and so is the result; ``binsb`` is
-    the ``[Tr, F]`` i32 bins tile (rows on the sublanes, as the histogram's
-    one-hot wants it); both are values in VMEM. Table layout: ``[Kp, 4]``
+    the ``[Tr, F]`` i32 bins tile (rows on the sublanes, as the untiled
+    kernels' one-hot wants it), or ``[F, Tr]`` out of a tiled tree's
+    ``feature_major`` array; both are values in VMEM. Table layout: ``[Kp, 4]``
     numerical (is_split, feature, bin, default_left), or ``[Kp, 5 + B]``
     when categorical features exist — column 4 flags a categorical node and
     columns 5: carry its RIGHT-going category set (evaluate_splits.h
@@ -483,14 +490,15 @@ def _partition_tile(pos, binsb, ptab_ref, *, Kp: int, F: int, B: int,
     a transpose of the bins tile: ``[Kp, F] @ binsb^T`` (contraction on
     both minor dimensions, the attention ``q @ k^T`` form) gives every
     node's split feature for every row, ``[Kp, Tr]``, and the node one-hot
-    picks the row's own.
+    picks the row's own. A ``feature_major`` tile is that transpose
+    already: a plain ``[Kp, F] @ [F, Tr]``.
 
     ``tree`` (a level call that carries several trees): ``ptab_ref`` is
     ``[T, Kp, W]`` and this tree's table its ``tree``-th; ``bins_op`` is
     then the bins tile already cast for the pick (``_bins_operand``), made
     once for all T."""
     W = ptab_ref.shape[-1]
-    Tr = binsb.shape[0]
+    Tr = pos.shape[1]
     ptab = ptab_ref[:, :] if tree is None else ptab_ref[tree]  # [Kp, W]
     lp = pos - prev_offset  # [1, Tr]
     iota_kp = jax.lax.broadcasted_iota(jnp.int32, (Kp, Tr), 0)
@@ -512,7 +520,7 @@ def _partition_tile(pos, binsb, ptab_ref, *, Kp: int, F: int, B: int,
     if bins_op is None:
         bins_op = _bins_operand(binsb, B)
     node_bv = jax.lax.dot_general(
-        ohf, bins_op, (((1,), (1,)), ((), ())),
+        ohf, bins_op, (((1,), (0 if feature_major else 1,)), ((), ())),
         preferred_element_type=jnp.float32,
         precision=None if narrow else jax.lax.Precision.HIGHEST,
     )  # [Kp, Tr]: bins[row, feature of node]
@@ -860,13 +868,23 @@ def _hoisted_level_pallas(bins, onehot, pos, gh, ptab, *, K, Kp, B, d,
 # ``[ft, 2K, B]``, the unrolled loop runs over ``ft`` columns whatever ``F``
 # is, and the row tiles are swept once a feature tile, inner, so a block of
 # the accumulator stays in VMEM for its whole sweep. A row's route depends on
-# its whole bins row (``_partition_tile``: ``[Kp, F] @ binsb^T``), so it is
-# NOT redone a feature tile (that would read the ``[n, F]`` i32 bins F / ft
-# times a level): the rows are routed ONCE a level by the routing kernel
-# (``_route_rows_pallas``, under ``xgb.partition``), and the tiles read the
-# routed positions and their own columns. Every column's one-hot is built in
-# VMEM (nothing resident is streamed: ``hoist_plan``), and a tile's step stays
-# inside the budget the untiled streaming step has (``_tile_tr``).
+# its whole bins row (``_partition_tile``), so it is NOT redone a feature
+# tile (that would read the i32 bins F / ft times a level): the rows are
+# routed ONCE a level by the routing kernel (``_route_rows_pallas``, under
+# ``xgb.partition``), and the tiles read the routed positions and their own
+# columns. Every column's one-hot is built in VMEM (nothing resident is
+# streamed: ``hoist_plan``), and a tile's step stays inside the budget the
+# untiled streaming step has (``_tile_tr``).
+# A tiled tree's bins are FEATURE-MAJOR, ``[Fp, n]`` i32 (``_feature_major``,
+# made once a tree by the widening): a tile's block is ``(ft, tr)``, a
+# column a ``[1, tr]`` row of it, and its one-hot ``[B, tr]`` a SUBLANE
+# broadcast against an iota over the sublanes: one broadcast a 128-row lane
+# group, shared by the group's ``B / 8`` vregs (out of a ``(tr, ft)`` block
+# every vreg of eight rows took a lane broadcast of its own: 0.241 us a
+# (1,024-row tile, feature) against 0.093, PERF.md section 6, PR 36). It
+# meets the channels with both minor dimensions contracted, ``[2M, tr] x
+# [B, tr] -> [2M, B]``: the MXU latches the weights transposed. The routing
+# beside the tiles reads the same array (``feature_major``).
 # Per histogram cell the sums are the untiled kernel's, row tile by row tile
 # in the same order: at the same row tile the result is the untiled kernel's
 # bit for bit (tests/test_feature_tiles.py).
@@ -875,9 +893,10 @@ def _hoisted_level_pallas(bins, onehot, pos, gh, ptab, *, K, Kp, B, d,
 
 def _tiled_level_kernel(bins_ref, pos_ref, gh_ref, *rest, K: int, B: int,
                         offset: int, T=None):
-    """Grid step (feature tile j, row tile c): ``_level_kernel``'s construct
-    loop over the ``ft`` columns of the ``(Tr, ft)`` bins block into the
-    tile's ``[ft, 2 T Kc, B]`` accumulator, zeroed at the tile's first row
+    """Grid step (feature tile j, row tile c): the ``ft`` columns of the
+    ``(ft, Tr)`` block of the feature-major bins, each one's one-hot
+    ``[B, Tr]`` met with the channels ``[2M, Tr]`` into the tile's ``[ft,
+    M, B]`` accumulator (``M = 2 T Kc``), zeroed at the tile's first row
     tile. ``rest``: the output ``hist_ref``, behind ``built_ref`` where
     siblings are subtracted."""
     from jax.experimental import pallas as pl
@@ -894,24 +913,35 @@ def _tiled_level_kernel(bins_ref, pos_ref, gh_ref, *rest, K: int, B: int,
     ghs4 = _route_and_channels(pos_ref, None, gh_ref, None, built_ref, None,
                                T=T, K=K, Kp=0, F=0, B=0, prev_offset=0,
                                offset=offset)
-    _construct_columns(hist_ref, bins_ref[:, :], ghs4, ghs4.shape[0] // 2, B)
+    M = ghs4.shape[0] // 2
+    binsT = bins_ref[:, :]  # [ft, Tr] i32
+    ft, Tr = binsT.shape
+    iota_b = jax.lax.broadcasted_iota(jnp.int32, (B, Tr), 0)
+    for f in range(ft):
+        # missing (== B) -> a zero column
+        oh_t = (binsT[f:f + 1, :] == iota_b).astype(jnp.bfloat16)
+        out = jax.lax.dot_general(
+            ghs4, oh_t, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [2M, Tr] x [B, Tr] -> [2M, B]
+        hist_ref[f, :, :] += out[:M] + out[M:]
 
 
 # "level" in the name: the benchmark books a Mosaic call so named to the
 # level histogram (reduce/summary.py)
 @guard_jit(name="tiled_level_pallas",
            static_argnames=("K", "Kp", "B", "d", "tr", "ft", "vma", "sub"))
-def _tiled_level_pallas(bins, pos, gh, ptab, *, K, Kp, B, d, tr, ft, vma=(),
+def _tiled_level_pallas(binsT, pos, gh, ptab, *, K, Kp, B, d, tr, ft, vma=(),
                         sub=False):
     """One level's histogram by feature tiles: routed ``pos`` ``[R, n]``,
-    ``gh`` ``[2R, n]`` and the i32 bins padded to whole tiles in, ``[Fp,
-    2 R Kc, B]`` out. Grid (feature tiles, row tiles); positions ``(R,
-    tr)`` and gradients ``(2R, tr)`` by row tile, the built children's ids
-    where siblings are subtracted."""
+    ``gh`` ``[2R, n]`` and the feature-major i32 bins ``[Fp, n]`` in whole
+    tiles in, ``[Fp, 2 R Kc, B]`` out. Grid (feature tiles, row tiles);
+    bins ``(ft, tr)``, positions ``(R, tr)`` and gradients ``(2R, tr)`` by
+    row tile, the built children's ids where siblings are subtracted."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    n, Fp = bins.shape
+    Fp, n = binsT.shape
     assert Fp % ft == 0, (Fp, ft)
     assert n % tr == 0, f"rows {n} not padded to {tr}"
     R = pos.shape[0]
@@ -924,7 +954,7 @@ def _tiled_level_pallas(bins, pos, gh, ptab, *, K, Kp, B, d, tr, ft, vma=(),
         kern,
         grid=(Fp // ft, n // tr),
         in_specs=[
-            pl.BlockSpec((tr, ft), lambda j, c: (c, j),
+            pl.BlockSpec((ft, tr), lambda j, c: (j, c),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((R, tr), lambda j, c: (0, c),
                          memory_space=pltpu.VMEM),
@@ -935,41 +965,45 @@ def _tiled_level_pallas(bins, pos, gh, ptab, *, K, Kp, B, d, tr, ft, vma=(),
                                memory_space=pltpu.VMEM),
         out_shape=_vma_struct((Fp, M, B), jnp.float32, vma),
         interpret=_INTERPRET,
-    )(bins, pos, gh, *built)
+    )(binsT, pos, gh, *built)
 
 
-def _pad_features(bins, ft: int, B: int):
-    """The i32 bins padded to whole feature tiles with the missing bin
-    (an all-zero one-hot: a padded column's histogram is zero and is cut
-    off). The same expression wherever a tree's program asks for it, so
-    XLA keeps one padded array a tree (it folds the pad into the
-    widening), read by every level's tiles and routing."""
+def _feature_major(bins, ft: int, B: int):
+    """A tiled tree's i32 bins ``[n, F]`` as its Mosaic calls read them:
+    FEATURE-MAJOR ``[Fp, n]`` (rows on the lanes), padded to whole feature
+    tiles with the missing bin (an all-zero one-hot: a padded column's
+    histogram is zero and is cut off). The same expression wherever a
+    tree's program asks for it, so XLA keeps one such array a tree (it
+    folds the pad and the transpose into the widening), read by every
+    level's tiles and routing."""
     pad = -bins.shape[1] % ft
-    if not pad:
-        return bins
-    return jnp.pad(bins, ((0, 0), (0, pad)), constant_values=B)
+    if pad:
+        bins = jnp.pad(bins, ((0, 0), (0, pad)), constant_values=B)
+    return bins.T
 
 
 def _tiled_level(bins, pos, gh, ptab, *, K, Kp, B, d, plan, vma, sub):
     """One level through the tiled kernel (``plan.kernel == "tiled"``): the
     rows routed once, by the routing kernel a tree (its Mosaic time is
     ``xgb.partition``'s, not the level histogram's), then the feature
-    tiles; the contract of the untiled calls."""
+    tiles, both on the feature-major bins; the contract of the untiled
+    calls."""
     n, F = bins.shape
     T = ptab.shape[0] if ptab.ndim == 3 else None
-    bins = _pad_features(bins, plan.ft, B)
+    binsT = _feature_major(bins, plan.ft, B)
     if Kp > 0:
-        tr_r = _route_tr(n, bins.shape[1], Kp, ptab.shape[-1])
+        tr_r = _route_tr(n, binsT.shape[0], Kp, ptab.shape[-1])
         with jax.named_scope("xgb.partition"):
             if T is None:
-                pos = _route_rows_pallas(bins, pos, ptab, Kp=Kp, B=B, d=d,
-                                         tr=tr_r, vma=vma)
+                pos = _route_rows_pallas(binsT, pos, ptab, Kp=Kp, B=B, d=d,
+                                         tr=tr_r, vma=vma, feature_major=True)
             else:
                 pos = jnp.concatenate([
-                    _route_rows_pallas(bins, pos[t:t + 1], ptab[t], Kp=Kp,
-                                       B=B, d=d, tr=tr_r, vma=vma)
+                    _route_rows_pallas(binsT, pos[t:t + 1], ptab[t], Kp=Kp,
+                                       B=B, d=d, tr=tr_r, vma=vma,
+                                       feature_major=True)
                     for t in range(T)])
-    hist = _tiled_level_pallas(bins, pos, gh, ptab, K=K, Kp=Kp, B=B, d=d,
+    hist = _tiled_level_pallas(binsT, pos, gh, ptab, K=K, Kp=Kp, B=B, d=d,
                                tr=plan.tr, ft=plan.ft, vma=vma, sub=sub)[:F]
     if T is None:
         return pos, hist  # [F, 2Kc, B]
@@ -978,33 +1012,39 @@ def _tiled_level(bins, pos, gh, ptab, *, K, Kp, B, d, plan, vma, sub):
 
 
 def _route_kernel(bins_ref, pos_ref, ptab_ref, pos_out, *, Kp: int, F: int,
-                  B: int, prev_offset: int):
-    """One grid step of the tree's last routing: ``Tr`` rows (a ``(1, Tr)``
-    block of positions in and out) through the deepest level's decisions,
-    and nothing else."""
+                  B: int, prev_offset: int, feature_major: bool):
+    """One grid step of a routing: ``Tr`` rows (a ``(1, Tr)`` block of
+    positions in and out) through a level's decisions, and nothing else."""
     pos_out[:, :] = _partition_tile(pos_ref[:, :], bins_ref[:, :], ptab_ref,
-                                    Kp=Kp, F=F, B=B, prev_offset=prev_offset)
+                                    Kp=Kp, F=F, B=B, prev_offset=prev_offset,
+                                    feature_major=feature_major)
 
 
 # no "level" in this name: the TPU compiler names the Mosaic call after the
 # function, and the benchmark books calls so named to the level histogram
 @guard_jit(name="route_rows_pallas",
-           static_argnames=("Kp", "B", "d", "tr", "vma"))
-def _route_rows_pallas(bins, pos, ptab, *, Kp, B, d, tr=TR, vma=()):
+           static_argnames=("Kp", "B", "d", "tr", "vma", "feature_major"))
+def _route_rows_pallas(bins, pos, ptab, *, Kp, B, d, tr=TR, vma=(),
+                       feature_major=False):
+    """The routing kernel over ``bins`` ``[n, F]`` i32, block ``(tr, F)``;
+    a tiled tree's ``feature_major`` ``[Fp, n]``, block ``(Fp, tr)``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    n, F = bins.shape
+    n, F = bins.shape[::-1] if feature_major else bins.shape
     assert n % tr == 0, f"rows {n} not padded to {tr}"
     prev_offset = (1 << (d - 1)) - 1 if d > 0 else 0
     W = ptab.shape[1]
     kern = functools.partial(_route_kernel, Kp=Kp, F=F, B=B,
-                             prev_offset=prev_offset)
+                             prev_offset=prev_offset,
+                             feature_major=feature_major)
+    block, at = ((F, tr), lambda c: (0, c)) if feature_major else (
+        (tr, F), lambda c: (c, 0))
     return pl.pallas_call(
         kern,
         grid=(n // tr,),
         in_specs=[
-            pl.BlockSpec((tr, F), lambda c: (c, 0), memory_space=pltpu.VMEM),
+            pl.BlockSpec(block, at, memory_space=pltpu.VMEM),
             pl.BlockSpec((1, tr), lambda c: (0, c), memory_space=pltpu.VMEM),
             pl.BlockSpec((Kp, W), lambda c: (0, 0), memory_space=pltpu.VMEM),
         ],
@@ -1147,16 +1187,18 @@ def _up(x: int, m: int) -> int:
 def _tile_vmem_bytes(tr: int, ft: int, K: int, B: int) -> int:
     """Working set of one grid step of the tiled kernel, at the tiles it
     occupies (8 sublanes, 128 lanes), ``K`` the nodes of every tree the
-    call carries. Double-buffered blocks: the ``(tr, ft)`` i32 bins tile,
-    positions and gradients (8 and 16 sublanes of ``tr`` lanes), and the
-    ``[ft, 2K, B]`` f32 accumulator, whose block index moves with the
-    tile. Values: the ``[4K, tr]`` channel terms in f32 and bf16, a
-    column's ``[tr, B]`` compare and one-hot, the dot's ``[4K, B]``."""
+    call carries. Double-buffered blocks: the ``(ft, tr)`` i32 tile of the
+    feature-major bins (``ft`` sublanes of ``tr`` lanes), positions and
+    gradients (8 and 16 sublanes of ``tr`` lanes), and the ``[ft, 2K, B]``
+    f32 accumulator, whose block index moves with the tile. Values: the
+    ``[4K, tr]`` channel terms in f32 and bf16, a column's ``[B, tr]``
+    compare and one-hot (``B`` sublanes of ``tr`` lanes), the dot's
+    ``[4K, B]``."""
     lanes_b = _up(B, 128)
-    return (2 * 4 * (tr * _up(ft, 128) + 24 * tr)
+    return (2 * 4 * (_up(ft, 8) * tr + 24 * tr)
             + 2 * 4 * ft * _up(2 * K, 8) * lanes_b
             + 4 * K * tr * (4 + 2)
-            + tr * lanes_b * (4 + 2) + 4 * K * lanes_b * 4)
+            + _up(B, 8) * tr * (4 + 2) + 4 * K * lanes_b * 4)
 
 
 def _tile_tr(ft: int, K: int, B: int) -> int:
@@ -1241,7 +1283,10 @@ def feature_tile(F: int, B: int, max_depth: int) -> int:
 def _route_vmem_bytes(tr: int, F: int, Kp: int, W: int) -> int:
     """One grid step of the routing kernel, counted at the tiles it
     occupies (8 sublanes, 128 lanes). Blocks, double-buffered: the
-    ``(tr, F)`` i32 bins tile, positions in and out as ``(1, tr)`` rows
+    ``(tr, F)`` i32 bins tile (``tr`` sublanes of ``_up(F, 128)`` lanes;
+    a tiled tree's feature-major ``(F, tr)`` tile is ``_up(F, 8)`` sublanes
+    of ``tr`` lanes: never more, and the same at whole feature tiles of
+    128), positions in and out as ``(1, tr)`` rows
     (8 sublanes each, 32 bytes a row of data where the ``(tr, 1)`` columns
     took 512), the ``(Kp, W)`` decision table. Values of
     ``_partition_tile``: the bins tile as loaded with its f32 and bf16
