@@ -24,7 +24,7 @@ def _clean_flight(monkeypatch):
     """Fresh recorder + trace state per test: the recorder is process-wide
     and always on, so tests must not see each other's rings or sinks."""
     for var in ("XGBTPU_TRACE", "XGBTPU_FLIGHT", "XGBTPU_PROFILE",
-                "XGBTPU_PROFILE_ROUNDS", "XGBTPU_COST_ANALYSIS"):
+                "XGBTPU_PROFILE_ROUNDS"):
         monkeypatch.delenv(var, raising=False)
     RECORDER.reset()
     trace.reset()
@@ -465,24 +465,3 @@ def test_profile_env_captures_window(tmp_path, monkeypatch):
     # once per process: a second window is refused, never re-armed
     flight.profile_tick(0)
     assert not flight._prof_state["active"]
-
-
-def test_cost_analysis_export_and_no_count(monkeypatch):
-    import jax.numpy as jnp
-
-    from xgboost_tpu.analysis.retrace import guard_jit, retrace_counts
-
-    monkeypatch.setenv("XGBTPU_COST_ANALYSIS", "1")
-    f = guard_jit(lambda x: (x @ x).sum(), name="flight_cost_demo")
-    f(jnp.ones((32, 32)))
-    f(jnp.ones((32, 32)))
-    # the AOT cost pass re-traces the body but must NOT count as a
-    # retrace (it is bookkeeping, not a new program)
-    assert retrace_counts()["flight_cost_demo"] == 1
-    snap = REGISTRY.snapshot()
-    flops = {s["labels"]["fn"]: s["value"]
-             for s in snap["xla_cost_flops"]["series"]}
-    nbytes = {s["labels"]["fn"]: s["value"]
-              for s in snap["xla_cost_bytes_accessed"]["series"]}
-    assert flops["flight_cost_demo"] > 0
-    assert nbytes["flight_cost_demo"] > 0

@@ -108,7 +108,7 @@ def test_train_trace_covers_pipeline_phases(tmp_path, monkeypatch):
     events = trace.load_trace(str(out))
     names = {e["name"] for e in events if e.get("ph") == "X"}
     # >= 5 distinct phases across sketch / hist / update / eval
-    assert {"sketch", "quantize", "grow_tree", "update", "eval"} <= names
+    assert {"sketch", "bins", "upload", "grow_tree", "update", "eval"} <= names
     assert len(names) >= 5
 
 

@@ -24,15 +24,9 @@ the hot entry points (``tree/grow_fused.py``, ``tree/hist_kernel.py``,
 The env var is re-read on every retrace *event* (not every call), so
 tests and operators can flip enforcement without reimporting anything.
 
-``XGBTPU_COST_ANALYSIS=1`` additionally exports each guarded program's
-XLA cost analysis — ``xla_cost_flops{fn=}`` / ``xla_cost_bytes_accessed
-{fn=}`` gauges, once per (function, trace-count) — so bench can report
-arithmetic intensity for the compiled grow/predict programs (ISSUE 7).
-The numbers come from an AOT ``lower().compile()`` of the same call
-signature, which re-traces the Python body: that bookkeeping pass is
-excluded from retrace counting (it is analysis, not a new program
-reaching the dispatch path), and the flag is off by default because the
-AOT compile is real compile work.
+What a trace, a lowering and a compile of *any* program cost (guarded or
+not) is in the set-up ledger, from JAX's own events
+(``observability/compile_ledger.py``).
 """
 
 from __future__ import annotations
@@ -48,22 +42,9 @@ __all__ = [
 ]
 
 _ENV_BUDGET = "XGBTPU_RETRACE_BUDGET"
-_ENV_COST = "XGBTPU_COST_ANALYSIS"
 
 _counts: Dict[str, int] = {}
 _lock = threading.Lock()
-_cost_done: set = set()  # (fn label, trace count) pairs already analyzed
-_tls = threading.local()  # .cost_pass: inside the AOT bookkeeping compile
-
-
-def _read_cost_env() -> bool:
-    return os.environ.get(_ENV_COST, "") not in ("", "0")
-
-
-# snapshot of the env flag, refreshed on every retrace EVENT (same
-# re-read-on-event pattern as the budget): the steady-state dispatch
-# path pays one global read instead of an os.environ lookup per call
-_cost_enabled = _read_cost_env()
 
 
 class RetraceBudgetExceeded(RuntimeError):
@@ -102,15 +83,10 @@ def note_retrace(name: str) -> None:
     surfaces at the jit call site — which also makes it the ``compile``
     chaos-injection site: ``XGBTPU_CHAOS="compile:..."`` scripts a failing
     guarded compile (resilience tentpole)."""
-    global _cost_enabled
-
-    if getattr(_tls, "cost_pass", False):
-        return  # the cost-analysis AOT re-trace is not a new program
     from ..resilience import chaos
 
     chaos.hit("compile")
     with _lock:
-        _cost_enabled = _read_cost_env()
         count = _counts.get(name, 0) + 1
         _counts[name] = count
     from ..observability.metrics import REGISTRY
@@ -155,13 +131,11 @@ def guard_jit(fun: Optional[Callable] = None, *, name: Optional[str] = None,
     (``@guard_jit(name="grow_tree_fused", static_argnames=("cfg",))``) or
     called directly (``guard_jit(run, name="predict_serving")``).
 
-    The counting shim runs only while JAX traces ``fun``. Steady-state
-    dispatch pays one thin forwarding frame plus a module-global check
-    (the cost-analysis hook, ~100ns — small against jit dispatch); the
-    AOT cost pass itself only runs under ``XGBTPU_COST_ANALYSIS``.
-    ``functools.wraps`` preserves the signature, so ``static_argnames``
-    resolve exactly as on the undecorated function. The underlying jit
-    object is reachable as ``<wrapper>._guarded_jit`` for AOT callers."""
+    The counting shim runs only while JAX traces ``fun``; steady-state
+    dispatch pays one thin forwarding frame. ``functools.wraps`` preserves
+    the signature, so ``static_argnames`` resolve exactly as on the
+    undecorated function. The underlying jit object is reachable as
+    ``<wrapper>._guarded_jit`` for AOT callers."""
     if fun is None:
         return functools.partial(guard_jit, name=name, **jit_kwargs)
     import jax
@@ -177,45 +151,8 @@ def guard_jit(fun: Optional[Callable] = None, *, name: Optional[str] = None,
 
     @functools.wraps(fun)
     def dispatch(*args, **kwargs):
-        out = jitted(*args, **kwargs)
-        if _cost_enabled:
-            _maybe_cost_analysis(label, jitted, args, kwargs)
-        return out
+        return jitted(*args, **kwargs)
 
     dispatch._guarded_jit = jitted  # escape hatch for AOT callers
     return dispatch
 
-
-def _maybe_cost_analysis(label: str, jitted, args, kwargs) -> None:
-    """Export the compiled program's FLOPs / bytes-accessed for the call
-    signature just dispatched — once per (label, trace count), so a
-    retrace (new signature) refreshes the gauges and steady-state calls
-    pay one set lookup. Never raises into the dispatch path."""
-    with _lock:
-        key = (label, _counts.get(label, 0))
-        if key in _cost_done:
-            return
-        _cost_done.add(key)
-    from ..observability.metrics import REGISTRY
-
-    try:
-        _tls.cost_pass = True
-        compiled = jitted.lower(*args, **kwargs).compile()
-        ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
-        flops = float(ca.get("flops", 0.0) or 0.0)
-        nbytes = float(ca.get("bytes accessed", 0.0) or 0.0)
-    except Exception:
-        return
-    finally:
-        _tls.cost_pass = False
-    REGISTRY.gauge(
-        "xla_cost_flops",
-        "XLA cost-analysis FLOPs of the last-compiled guarded program",
-    ).labels(fn=label).set(flops)
-    REGISTRY.gauge(
-        "xla_cost_bytes_accessed",
-        "XLA cost-analysis bytes accessed of the last-compiled guarded "
-        "program",
-    ).labels(fn=label).set(nbytes)

@@ -433,8 +433,10 @@ class DMatrix:
             if mesh is not None and mesh.devices.size > 1:
                 # distributed sketch: per-shard summaries merged by
                 # all_gather (the quantile.cc:270 AllReduce site)
+                import jax
                 import jax.numpy as jnp
 
+                from ..observability import trace
                 from ..parallel.mesh import (global_pad_rows,
                                              local_device_count, shard_rows)
                 from ..parallel.sketch import distributed_compute_cuts
@@ -455,10 +457,14 @@ class DMatrix:
                          np.zeros(n_pad - len(w), np.float32)]
                     )
                     w = shard_rows(jnp.asarray(w), mesh)
+                with trace.stage("upload", cols=int(X.shape[1]),
+                                 what="cuts", sharded=True):
+                    Xs = jax.block_until_ready(
+                        shard_rows(jnp.asarray(X), mesh))
                 cuts = distributed_compute_cuts(
-                    mesh, shard_rows(jnp.asarray(X), mesh), max_bin=max_bin,
-                    weights=w,
+                    mesh, Xs, max_bin=max_bin, weights=w,
                 )
+                del Xs  # the float32 shards go before the bins are made
                 if cat:
                     from .quantile import apply_categorical_identity
 

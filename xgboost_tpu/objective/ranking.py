@@ -193,7 +193,7 @@ def _build_layout(label, group_ptr, weight) -> _LayoutEntry:
     n = len(label_np)
     gptr = (np.array([0, n], np.int64) if group_ptr is None
             else np.asarray(group_ptr, np.int64))
-    with _trace.span("rank_layout", rows=n, groups=len(gptr) - 1):
+    with _trace.stage("rank_layout", rows=n, groups=len(gptr) - 1):
         sizes = np.diff(gptr)
         n_groups = len(sizes)
         max_size = int(sizes.max(initial=1))

@@ -12,6 +12,11 @@ accumulators, NVTX ranges, ``TrainingObserver`` dumps):
   (``utils.timer.Monitor`` feeds it as a thin adapter);
 - ``comms`` — collective ops/bytes accounting for ``collective.py`` and
   the mesh psum / all_gather paths;
+- ``compile_ledger`` — the always-on set-up ledger: every program's
+  trace, lowering, compile and cache load from ``jax.monitoring``'s own
+  events, beside the data plane's stages (``trace.stage``: upload, sketch,
+  bins, one-hot, each closed on its result with the HBM mark);
+  ``setup_ledger()`` returns the table;
 - ``flight`` — the always-on per-round flight recorder (ring buffer,
   durable ``run_dir/obs/rank<k>/`` sink, black-box dumps, profiling
   window) — ISSUE 7;
@@ -32,7 +37,8 @@ is also open on the profiler's clock as ``xgb.<name>``.
 """
 
 from . import comms, metrics, trace  # noqa: F401
-from . import flight  # noqa: F401  (after trace/metrics: it builds on both)
+from . import compile_ledger, flight  # noqa: F401  (they build on both)
+from .compile_ledger import setup_ledger  # noqa: F401
 from .flight import RECORDER  # noqa: F401
 from .metrics import REGISTRY, MetricsRegistry, get_registry  # noqa: F401
 from .trace import (  # noqa: F401
@@ -42,12 +48,17 @@ from .trace import (  # noqa: F401
     instant,
     load_trace,
     span,
+    stage,
     trace_path,
 )
 
 __all__ = [
-    "trace", "metrics", "comms", "flight",
-    "span", "instant", "emit", "enabled", "flush", "trace_path",
-    "load_trace",
+    "trace", "metrics", "comms", "flight", "compile_ledger",
+    "span", "stage", "instant", "emit", "enabled", "flush", "trace_path",
+    "load_trace", "setup_ledger",
     "REGISTRY", "MetricsRegistry", "get_registry", "RECORDER",
 ]
+
+# the listeners of the set-up ledger, from the package's import on: the
+# first program a process builds is in it
+compile_ledger.install()

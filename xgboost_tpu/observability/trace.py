@@ -60,9 +60,9 @@ import time
 from typing import Any, Dict, List, Optional
 
 __all__ = [
-    "span", "instant", "emit", "emit_async", "emit_async_track",
+    "span", "stage", "instant", "emit", "emit_async", "emit_async_track",
     "enabled", "trace_path", "flush", "reset", "load_trace",
-    "clock_base", "set_sink",
+    "clock_base", "from_unix_s", "set_sink",
 ]
 
 _ENV_PATH = "XGBTPU_TRACE"
@@ -92,6 +92,14 @@ def clock_base() -> Dict[str, Any]:
     Persisted per rank (``obs/rank<k>/clock.json``) so ``obs-report``
     can merge ranks onto one clock-aligned timeline."""
     return {"unix_ns": _EPOCH_UNIX_NS, "ts_unit": "us"}
+
+
+def from_unix_s(seconds: float) -> int:
+    """A ``time.time()`` reading as a ``perf_counter_ns`` value of this
+    process (the inverse of ``clock_base``), so an interval somebody else
+    measured on the wall clock can go to ``emit()``: JAX stamps its compile
+    events so (``observability/compile_ledger.py``)."""
+    return int(seconds * 1e9) - _EPOCH_UNIX_NS + _EPOCH_NS
 
 
 def set_sink(path: Optional[str]) -> None:
@@ -249,6 +257,90 @@ def span(name: str, **args: Any):
     note = (jax.profiler.TraceAnnotation(_ANNOTATION_PREFIX + name, **args)
             if profiled else None)
     return _Span(name, args, note) if traced else note
+
+
+_stage_tls = threading.local()  # .open: the innermost open _Stage
+
+
+def _hbm_peak() -> Optional[int]:
+    """``peak_bytes_in_use`` of the fullest local device: the allocator's
+    high-water mark since the process started. None where the backend
+    keeps no statistics (the CPU)."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    peak = None
+    for d in jax.local_devices():
+        stats = d.memory_stats()
+        if stats and "peak_bytes_in_use" in stats:
+            peak = max(peak or 0, int(stats["peak_bytes_in_use"]))
+    return peak
+
+
+class _Stage:
+    """An open set-up stage (``stage()``). ``seconds``, set at the close,
+    is the stage's own time: its extent less the stages that ran inside
+    it."""
+
+    __slots__ = ("name", "seconds", "_span", "_parent", "_inner", "_t0",
+                 "_peak0")
+
+    def __init__(self, name: str, args: Dict[str, Any]):
+        self.name = name
+        self.seconds = 0.0
+        self._span = span(name, **args)
+        self._inner = 0.0
+
+    def __enter__(self) -> "_Stage":
+        self._parent = getattr(_stage_tls, "open", None)
+        _stage_tls.open = self
+        self._peak0 = _hbm_peak()
+        self._span.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        wall = time.perf_counter() - self._t0
+        self._span.__exit__(exc_type, exc, tb)
+        _stage_tls.open = self._parent
+        if self._parent is not None:
+            self._parent._inner += wall
+        self.seconds = max(wall - self._inner, 0.0)
+        from .metrics import REGISTRY
+
+        REGISTRY.counter(
+            "setup_stage_seconds_total",
+            "Own seconds of the data plane's set-up stages, each closed on "
+            "its result (stages inside a stage are taken out of it)",
+        ).labels(stage=self.name).inc(self.seconds)
+        REGISTRY.counter(
+            "setup_stage_events_total", "Set-up stages closed",
+        ).labels(stage=self.name).inc()
+        peak = _hbm_peak()
+        if peak is not None:
+            mark = REGISTRY.gauge(
+                "hbm_peak_bytes",
+                "The device's peak_bytes_in_use at the close of the stage's "
+                "last run that raised it (fullest local device)",
+            ).labels(stage=self.name)
+            if not mark.value or peak > (self._peak0 or 0):
+                mark.set(peak)
+        return False
+
+
+def stage(name: str, **args: Any) -> _Stage:
+    """Context manager round one stage of the data plane's set-up (upload,
+    sketch, bins, one-hot, rank layout: once a ``DMatrix`` or once a fit,
+    never a round). Always on: it adds the stage's own seconds to
+    ``setup_stage_seconds_total{stage}``, counts it in
+    ``setup_stage_events_total{stage}`` and, where the backend keeps memory
+    statistics, sets ``hbm_peak_bytes{stage}`` to the device's high-water
+    mark if this run of the stage raised it (so a later, smaller run of the
+    stage does not take over a mark another program set). It opens the
+    ``span`` of the same name for its extent. The call site ends the block
+    on ``jax.block_until_ready`` of the stage's result, which is what makes
+    the seconds the work's and not the enqueue's."""
+    return _Stage(name, args)
 
 
 def emit(name: str, start_ns: int, end_ns: int, cat: Optional[str] = None,

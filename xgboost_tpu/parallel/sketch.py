@@ -119,8 +119,9 @@ def distributed_compute_cuts(
 
         return bcast0(cuts), bcast0(mins)
 
-    with trace.span("sketch", distributed=True, rows=n, features=F,
-                    max_bin=max_bin):
+    # the set-up stage, closed on the cuts on the host
+    with trace.stage("sketch", distributed=True, rows=n, features=F,
+                     max_bin=max_bin):
         cuts, min_vals = jax.shard_map(
             shard_fn,
             mesh=mesh,
