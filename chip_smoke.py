@@ -271,6 +271,7 @@ def stage_kernels(sz: Sizes, X, routes: dict) -> None:
         bins = binned.bins  # narrow storage dtype
         bins_np = np.asarray(bins)
         bins32 = bins.astype(jnp.int32)
+        binsT = hk._feature_major(bins32, hk._SUBLANES, B)  # as a tree has
         plan = hk.hoist_plan(n_anchor, F, B, DEPTH)
         say(f"  bin{B}: anchor hoist plan {plan}/{F} features "
             f"({n_anchor} rows)")
@@ -330,7 +331,7 @@ def stage_kernels(sz: Sizes, X, routes: dict) -> None:
                 Kc, tag = (Kp, "_sub") if sub else (K, "")
                 cands["construct" + tag] = (
                     lambda sub=sub: hk._fused_level_pallas(
-                        bins32, pos, gh, ptab, sub=sub, **kw))
+                        binsT, pos, gh, ptab, F=F, sub=sub, **kw))
                 for fh in widths:
                     tr = hk._hoist_tr(fh * B, Kc, F, B)
                     check(tr > 0 and n % tr == 0, f"bin{B} d={d}: no "
@@ -339,7 +340,7 @@ def stage_kernels(sz: Sizes, X, routes: dict) -> None:
                             else f"hoisted_partial{fh}") + tag
                     cands[name] = (lambda oh=onehots[fh], tr=tr, sub=sub:
                                    hk._hoisted_level_pallas(
-                                       bins32, oh, pos, gh, ptab, tr=tr,
+                                       binsT, oh, pos, gh, ptab, F=F, tr=tr,
                                        sub=sub, **kw))
             for name, fn in cands.items():
                 (pos_p, hist_p), cold = _timed(fn)
@@ -371,7 +372,7 @@ def stage_kernels(sz: Sizes, X, routes: dict) -> None:
                       f"bin{B} d={d} route_rows: pos differs from XLA")
                 say(f"  bin{B} d={d} route_rows: cold {cold:.2f}s warm "
                     f"{warm:.4f}s  pos identical")
-        del binned, bins, bins32, onehots
+        del binned, bins, bins32, binsT, onehots
     _check_routes("kernels", before, routes,
                   must_see=("onehot_build", "level_partition",
                             "sketch_cuts", "bin_matrix"))

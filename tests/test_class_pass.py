@@ -75,16 +75,17 @@ LEVELS = [(0, False)] + [(d, sub) for d in (1, 2, 3) for sub in (False, True)]
 def test_level_call_of_T_trees_equals_T_calls(mosaic_route, T, d, sub, hoist):
     bins, pos, gh, ptab = _level_inputs(T, d)
     K = 1 << d
-    kw = dict(K=K, Kp=K >> 1, B=B, d=d, tr=TR, sub=sub)
+    kw = dict(F=F, K=K, Kp=K >> 1, B=B, d=d, tr=TR, sub=sub)
     Fh = {"full": F, "partial": 4, "construct": 0}[hoist]
+    binsT = hk._feature_major(bins, hk._SUBLANES, B)
     if Fh:
         onehot = hk._build_onehot_xla(bins[:, :Fh].astype(jnp.uint8), B=B)
 
         def call(*a):
-            return hk._hoisted_level_pallas(bins, onehot, *a, **kw)
+            return hk._hoisted_level_pallas(binsT, onehot, *a, **kw)
     else:
         def call(*a):
-            return hk._fused_level_pallas(bins, *a, **kw)
+            return hk._fused_level_pallas(binsT, *a, **kw)
 
     pos_T, hist_T = call(pos, gh, ptab)
     ones = [call(pos[t:t + 1], gh[2 * t:2 * t + 2], ptab[t])

@@ -215,6 +215,14 @@ def _mosaic_calls(fn, *shapes, **static):
     return _calls_of(_mosaic_lines(fn, *shapes, **static))
 
 
+def _feature_major(bins, B):
+    """The tree program's widened bins as every Mosaic call of an untiled
+    tree reads them (ISSUE 38): ``[Fp, n]`` i32, whole sublanes."""
+    import jax.numpy as jnp
+
+    return hk._feature_major(bins.astype(jnp.int32), hk._SUBLANES, B)
+
+
 @pytest.mark.parametrize("kernel", ["_hoisted_level_pallas",
                                     "_build_onehot_pallas",
                                     "_predict_margin_pallas",
@@ -236,8 +244,8 @@ def test_scope_leaves_the_kernels_instruction_name(one_v5e_chip, kernel):
         def level(bins, onehot, pos, gh, ptab):
             with jax.named_scope("xgb.level_hist"):  # as grow_fused has it
                 return hk._hoisted_level_pallas(
-                    bins.astype(jnp.int32), onehot, pos, gh, ptab, K=K,
-                    Kp=K >> 1, B=B, d=2, tr=hk._hoist_tr(F * B, K, F, B))
+                    _feature_major(bins, B), onehot, pos, gh, ptab, F=F,
+                    K=K, Kp=K >> 1, B=B, d=2, tr=hk._hoist_tr(F * B, K, F, B))
 
         calls = _mosaic_calls(
             jax.jit(level), S((n, F), jnp.uint8), S((n, F * B), jnp.int8),
@@ -254,8 +262,8 @@ def test_scope_leaves_the_kernels_instruction_name(one_v5e_chip, kernel):
 
         def route(bins, pos, ptab):
             with jax.named_scope("xgb.partition"):  # as grow_fused has it
-                return hk._route_rows_pallas(bins, pos, ptab, Kp=Kp, B=B,
-                                             d=6)
+                return hk._route_rows_pallas(_feature_major(bins, B), pos,
+                                             ptab, Kp=Kp, B=B, d=6)
 
         lines = _mosaic_lines(
             jax.jit(route), S((n, 50), jnp.int32), S((1, n), jnp.int32),
@@ -327,8 +335,8 @@ def test_kernels_compile_at_136_features_under_their_names(one_v5e_chip,
         def level(bins, onehot, pos, gh, ptab):
             with jax.named_scope("xgb.level_hist"):
                 return hk._hoisted_level_pallas(
-                    bins.astype(jnp.int32), onehot, pos, gh, ptab, K=K,
-                    Kp=Kp, B=B, d=d, tr=tr)
+                    _feature_major(bins, B), onehot, pos, gh, ptab, F=F,
+                    K=K, Kp=Kp, B=B, d=d, tr=tr)
 
         calls = _mosaic_calls(
             jax.jit(level), S((n, F), jnp.uint8), S((n, Fh * B), jnp.int8),
@@ -338,8 +346,8 @@ def test_kernels_compile_at_136_features_under_their_names(one_v5e_chip,
     else:
         def route(bins, pos, ptab):
             with jax.named_scope("xgb.partition"):
-                return hk._route_rows_pallas(bins, pos, ptab, Kp=Kp, B=B,
-                                             d=d)
+                return hk._route_rows_pallas(_feature_major(bins, B), pos,
+                                             ptab, Kp=Kp, B=B, d=d)
 
         calls = _mosaic_calls(jax.jit(route), S((n, F), jnp.int32),
                               S((1, n), jnp.int32), S((Kp, 4), jnp.float32))
@@ -374,8 +382,8 @@ def test_sibling_sub_kernels_keep_their_names_and_halve_the_output_rows(
         def level(bins, pos, gh, ptab, onehot):
             with jax.named_scope("xgb.level_hist"):
                 return hk._hoisted_level_pallas(
-                    bins.astype(jnp.int32), onehot, pos, gh, ptab, K=K,
-                    Kp=Kp, B=B, d=d, tr=tr, sub=True)
+                    _feature_major(bins, B), onehot, pos, gh, ptab, F=F,
+                    K=K, Kp=Kp, B=B, d=d, tr=tr, sub=True)
 
         shapes.append(S((n, Fh * B), jnp.int8))
         out = f"f32[{2 * Kp},{F * B}]"
@@ -383,8 +391,8 @@ def test_sibling_sub_kernels_keep_their_names_and_halve_the_output_rows(
         def level(bins, pos, gh, ptab):
             with jax.named_scope("xgb.level_hist"):
                 return hk._fused_level_pallas(
-                    bins.astype(jnp.int32), pos, gh, ptab, K=K, Kp=Kp, B=B,
-                    d=d, sub=True)
+                    _feature_major(bins, B), pos, gh, ptab, F=F, K=K, Kp=Kp,
+                    B=B, d=d, sub=True)
 
         out = f"f32[{F},{2 * Kp},{B}]"
     lines = _mosaic_lines(jax.jit(level), *shapes)
@@ -471,10 +479,12 @@ def test_row_arrays_reach_the_kernels_lane_dense(one_v5e_chip, kernel, F, Kp,
         del shapes[2]
 
         def fn(bins, pos, ptab):
-            return hk._route_rows_pallas(bins, pos, ptab, Kp=Kp, B=B, d=d)
+            return hk._route_rows_pallas(_feature_major(bins, B), pos, ptab,
+                                         Kp=Kp, B=B, d=d)
     elif kernel == "_fused_level_pallas":
         def fn(bins, pos, gh, ptab):
-            return hk._fused_level_pallas(bins, pos, gh, ptab, K=2 * Kp,
+            return hk._fused_level_pallas(_feature_major(bins, B), pos, gh,
+                                          ptab, F=F, K=2 * Kp,
                                           Kp=Kp, B=B, d=d, sub=True)
     else:
         Fh = 34
@@ -482,7 +492,8 @@ def test_row_arrays_reach_the_kernels_lane_dense(one_v5e_chip, kernel, F, Kp,
 
         def fn(bins, pos, gh, ptab, onehot):
             return hk._hoisted_level_pallas(
-                bins, onehot, pos, gh, ptab, K=2 * Kp, Kp=Kp, B=B, d=d,
+                _feature_major(bins, B), onehot, pos, gh, ptab, F=F,
+                K=2 * Kp, Kp=Kp, B=B, d=d,
                 tr=hk._hoist_tr(Fh * B, Kp, F, B), sub=True)
 
     line, = _mosaic_lines(jax.jit(fn), *shapes)
@@ -696,3 +707,54 @@ def test_wide_tree_compiles_on_the_tiled_kernels_under_their_names(
         assert re.match(r"(?:ROOT )?%_route_rows_pallas[.\d]* = ", ln), ln[:80]
         assert "xgb.partition/jit(_route_rows_pallas)/" in ln
         assert summary.kind_of(ln.removeprefix("ROOT ")) == "mosaic"
+
+
+# the untiled kernels on the feature-major bins (ISSUE 38): the older cells'
+# widths, resident columns and depths
+@pytest.mark.parametrize("F,Fh,depth", [(50, 34, 6), (28, 7, 8), (136, 12, 6)])
+def test_untiled_tree_reads_one_feature_major_array(one_v5e_chip,
+                                                    monkeypatch, F, Fh,
+                                                    depth):
+    """A whole tree program at an older cell's width, resident one-hot and
+    depth, compiled for a described v5e (8,192 rows): every level is one
+    ``_hoisted_level_pallas`` and the tree's last routing one
+    ``_route_rows_pallas``; every one of them reads the feature-major
+    ``s32[Fp, n]``, padded to whole sublanes, which the program makes ONCE
+    a tree (one fusion carries the transpose's name: XLA folds it into the
+    widening and the pad), and no Mosaic call reads the bins row-major; a
+    level call holds them in HBM."""
+    import jax.numpy as jnp
+
+    from xgboost_tpu.tree import grow, grow_fused
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
+
+    monkeypatch.setattr(hk, "use_pallas", lambda: True)
+    n, B = 8192, 256
+    hlo = _compiled_text(
+        grow_fused._grow_tree_fused_impl._guarded_jit,
+        S((n, F), jnp.uint16), S((n,), jnp.float32), S((n,), jnp.float32),
+        S((F, B), jnp.float32), S((2,), jnp.uint32), S((), jnp.float32),
+        S((), jnp.float32), cfg=grow.GrowParams(max_depth=depth),
+        onehot=S((n, Fh * B), jnp.int8))
+    lines = _mosaic_lines_of(hlo)
+    summary = _benchmark_summary()
+    levels = [ln for ln in lines
+              if summary.is_level_kernel(ln.removeprefix("ROOT "))]
+    routes = [ln for ln in lines if ln not in levels]
+    assert len(levels) == depth and len(routes) == 1
+    Fp = hk._up(F, hk._SUBLANES)
+    made = [ln for ln in hlo.splitlines() if " fusion(" in ln
+            and '/xgb.level_hist/transpose"' in ln]
+    assert len(made) == 1 and f" = s32[{Fp},{n}]" in made[0], made
+    for ln in lines:
+        assert f"operand_layout_constraints={{s32[{Fp},{n}]{{1,0}}, " in ln
+        assert f"s32[{n},{F}]" not in ln
+    for ln in levels:
+        assert re.match(r"(?:ROOT )?%_hoisted_level_pallas[.\d]* = ", ln)
+        # the bins held in HBM (``hist_kernel._in_hbm``): XLA may not keep
+        # them in VMEM across the round
+        assert ('"input_memory_space_colors":[{"operand_index":"0",'
+                '"color":"0"') in ln
+    assert re.match(r"(?:ROOT )?%_route_rows_pallas[.\d]* = ", routes[0])

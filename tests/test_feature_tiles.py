@@ -19,11 +19,13 @@ kernel bodies interpreted, at small sizes:
 (e) the feature-major bins (ISSUE 36): the tiled kernel and the routing
     beside it read ``[Fp, n]`` (a column's one-hot is ``[B, tr]``, rows on
     the lanes); a tiled tree asks for that array as one expression of its
-    one widened matrix, and the four older cells' programs hold none.
+    one widened matrix; since ISSUE 38 the four older cells' untiled
+    programs read it too, padded to whole sublanes
+    (``tests/test_feature_major.py`` holds those kernels to the parent's
+    row-major layout bit for bit).
 """
 
 import contextlib
-import re
 
 import jax
 import jax.numpy as jnp
@@ -87,15 +89,17 @@ LEVELS = [(0, False)] + [(d, sub) for d in (1, 3) for sub in (False, True)]
 @pytest.mark.parametrize("T", [None, 3])
 def test_tiles_add_up_to_the_untiled_kernel(mosaic_route, T, d, sub, ft, tr):
     """50 columns in tiles of 16 or 32 (the last padded with the missing
-    bin; the ``(ft, tr)`` blocks of the feature-major bins, a column's
-    one-hot ``[B, tr]``) against the untiled construction (``(tr, F)``,
-    ``[tr, B]``) at the same row tile: the same positions, and every
-    histogram cell the same bits."""
+    bin; the ``(ft, tr)`` blocks of the feature-major bins) against the
+    untiled construction (one ``(56, tr)`` block, whole sublanes) at the
+    same row tile: the same positions, and every histogram cell the same
+    bits."""
     F, B = 50, 16
     bins, pos, gh, ptab = _level_inputs(F, B, d, T)
     K = 1 << d
     kw = dict(K=K, Kp=K >> 1, B=B, d=d, sub=sub)
-    pos_u, hist_u = hk._fused_level_pallas(bins, pos, gh, ptab, tr=tr, **kw)
+    pos_u, hist_u = hk._fused_level_pallas(
+        hk._feature_major(bins, hk._SUBLANES, B), pos, gh, ptab, F=F, tr=tr,
+        **kw)
     plan = hk.LevelPlan("tiled", tr, -(-F // ft), ft)
     pos_t, hist_t = hk._tiled_level(bins, pos, gh, ptab, plan=plan, vma=(),
                                     **kw)
@@ -237,15 +241,13 @@ def test_routing_kernel_at_the_width(mosaic_route, F):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     assert bool((got != pos).any())
     # what the dispatcher ran: the kernel on the tree's feature-major bins,
-    # a ``(Fp, tr)`` block a step; the row-major form gives the same rows
+    # a ``(Fp, tr)`` block a step
     binsT = hk._feature_major(bins, 128, B)
     Fp = hk._up(F, 128)
     assert binsT.shape == (Fp, n) and bool((binsT[F:] == B).all())
     assert hk._route_tr(n, Fp, 8, 4) == tr
-    for operand, major in ((binsT, True), (bins, False)):
-        got = hk._route_rows_pallas(operand, pos, ptab, Kp=8, B=B, d=d, tr=tr,
-                                    feature_major=major)
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    got = hk._route_rows_pallas(binsT, pos, ptab, Kp=8, B=B, d=d, tr=tr)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 # ---------------------------------------------------------------------------
@@ -452,16 +454,36 @@ OLDER_CELLS = {
 }
 
 
+def _kernel_calls(jaxpr):
+    """``(name, first operand's aval)`` of every jitted kernel wrapper
+    (``_*_pallas``) in a jaxpr, its sub-jaxprs (scan, ``shard_map``, jitted
+    steps) included, in program order."""
+    out = []
+    for eqn in jaxpr.eqns:
+        name = eqn.params.get("name")
+        if eqn.primitive.name in ("jit", "pjit") \
+                and str(name).endswith("_pallas"):
+            out.append((name, eqn.invars[0].aval))
+            continue
+        for v in eqn.params.values():
+            for j in (v if isinstance(v, (tuple, list)) else (v,)):
+                inner = getattr(j, "jaxpr", j)
+                if hasattr(inner, "eqns"):
+                    out += _kernel_calls(inner)
+    return out
+
+
 @pytest.mark.parametrize("cell", OLDER_CELLS)
-def test_the_older_cells_programs_hold_no_feature_major_bins(
+def test_the_older_cells_programs_read_feature_major_bins(
         mosaic_route, monkeypatch, cell):
     """The program each of the four older cells boosts with (the scan
     chunk's, on one chip and under a mesh of four; MSLR's objective is not
     scan-safe, so the per-round tree program), traced at the cell's
     columns, 256 bins, depth, objective and hoist plan on 4,096 rows a chip:
-    every level call is the streaming kernel, none is tiled, and the
-    widened bins are never transposed: ISSUE 36 changed nothing they
-    run."""
+    every level call is the streaming kernel, none is tiled, and every
+    level call and routing reads the widened bins FEATURE-MAJOR, ``s32[Fp,
+    rows]`` padded to whole sublanes, never ``[rows, F]`` (ISSUE 38); the
+    resident one-hot's builder alone reads them row-major."""
     from xgboost_tpu.gbm import gbtree
     from xgboost_tpu.parallel import grow as pgrow
     from xgboost_tpu.parallel import make_mesh, mesh_context
@@ -502,13 +524,19 @@ def test_the_older_cells_programs_hold_no_feature_major_bins(
     with pytest.raises(Traced), (mesh_context(make_mesh(chips)) if chips > 1
                                  else contextlib.nullcontext()):
         boost()
-    text = str(traced[0])
-    assert "tiled_level" not in text
-    assert text.count("_hoisted_level_pallas") >= depth
-    assert "_fused_level_pallas" not in text
-    assert re.search(r"i32\[%d,%d\]" % (rows, F), text)  # the widened bins
-    for cols in (F, hk._up(F, 128)):  # and never the rows minor
-        assert not re.search(r"i32\[%d,(%d|%d)\]" % (cols, rows, n), text)
+    calls = _kernel_calls(traced[0].jaxpr)
+    names = [name for name, _ in calls]
+    levels = [name for name in names if "level" in name]
+    assert set(levels) == {"_hoisted_level_pallas"}
+    assert len(levels) >= depth
+    assert "_route_rows_pallas" in names
+    Fp = hk._up(F, hk._SUBLANES)
+    for name, aval in calls:
+        if name == "_build_onehot_pallas":  # the one-hot stays rows-major
+            assert aval.shape[0] == rows, aval
+            continue
+        assert aval.shape == (Fp, rows) and aval.dtype == jnp.int32, (
+            name, aval)
 
 
 # ---------------------------------------------------------------------------

@@ -112,11 +112,12 @@ def test_hoisted_kernel_interpret_mode():
     ptab = jnp.zeros((1, 4), jnp.float32)
     kern = functools.partial(hk._hoisted_kernel, K=1, Kp=0, F=4, Fh=4, B=16,
                              prev_offset=0, offset=0)
+    binsT = hk._feature_major(bins, hk._SUBLANES, 16)  # the [8, n] tiles
     pos_new, hist2 = pl.pallas_call(
         kern,
         grid=(2,),
         in_specs=[
-            pl.BlockSpec((256, 4), lambda c: (c, 0)),
+            pl.BlockSpec((8, 256), lambda c: (0, c)),
             pl.BlockSpec((256, 64), lambda c: (c, 0)),
             pl.BlockSpec((1, 256), lambda c: (0, c)),
             pl.BlockSpec((2, 256), lambda c: (0, c)),
@@ -131,7 +132,7 @@ def test_hoisted_kernel_interpret_mode():
             jax.ShapeDtypeStruct((2, 64), jnp.float32),
         ],
         interpret=True,
-    )(bins, onehot, pos, gh, ptab)
+    )(binsT, onehot, pos, gh, ptab)
     hist = jnp.transpose(hist2.reshape(2, 4, 16), (1, 0, 2))
     _, want = fused_level_xla(bins, pos, gh, ptab, K=1, Kp=0, B=16, d=0)
     np.testing.assert_allclose(np.asarray(hist), np.asarray(want),
@@ -190,11 +191,12 @@ def test_partial_hoist_kernel_interpret_mode():
     ptab = jnp.zeros((1, 4), jnp.float32)
     kern = functools.partial(hk._hoisted_kernel, K=1, Kp=0, F=4, Fh=Fh,
                              B=16, prev_offset=0, offset=0)
+    binsT = hk._feature_major(bins, hk._SUBLANES, 16)  # the [8, n] tiles
     pos_new, hist2 = pl.pallas_call(
         kern,
         grid=(2,),
         in_specs=[
-            pl.BlockSpec((256, 4), lambda c: (c, 0)),
+            pl.BlockSpec((8, 256), lambda c: (0, c)),
             pl.BlockSpec((256, 32), lambda c: (c, 0)),
             pl.BlockSpec((1, 256), lambda c: (0, c)),
             pl.BlockSpec((2, 256), lambda c: (0, c)),
@@ -209,7 +211,7 @@ def test_partial_hoist_kernel_interpret_mode():
             jax.ShapeDtypeStruct((2, 64), jnp.float32),
         ],
         interpret=True,
-    )(bins, onehot, pos, gh, ptab)
+    )(binsT, onehot, pos, gh, ptab)
     hist = jnp.transpose(hist2.reshape(2, 4, 16), (1, 0, 2))
     _, want = fused_level_xla(bins, pos, gh, ptab, K=1, Kp=0, B=16, d=0)
     np.testing.assert_allclose(np.asarray(hist), np.asarray(want),
@@ -300,11 +302,12 @@ def test_kernel_categorical_partition_interpret_mode():
 
     kern = functools.partial(hk._level_kernel, K=K, Kp=Kp, F=F, B=B,
                              prev_offset=prev_off, offset=(1 << d) - 1)
+    binsT = hk._feature_major(bins, hk._SUBLANES, B)  # the [8, n] tiles
     pos_new, _ = pl.pallas_call(
         kern,
         grid=(2,),
         in_specs=[
-            pl.BlockSpec((256, F), lambda c: (c, 0)),
+            pl.BlockSpec((binsT.shape[0], 256), lambda c: (0, c)),
             pl.BlockSpec((1, 256), lambda c: (0, c)),
             pl.BlockSpec((2, 256), lambda c: (0, c)),
             pl.BlockSpec((Kp, 5 + B), lambda c: (0, 0)),
@@ -318,7 +321,7 @@ def test_kernel_categorical_partition_interpret_mode():
             jax.ShapeDtypeStruct((F, 2 * K, B), jnp.float32),
         ],
         interpret=True,
-    )(bins, pos, gh, ptab_j)
+    )(binsT, pos, gh, ptab_j)
     np.testing.assert_array_equal(np.asarray(pos_new), np.asarray(want))
 
 

@@ -158,12 +158,13 @@ def test_level_kernels_equal_the_xla_level(interpreted, kernel, sub, d, cat,
     assert pos.shape == (1, n) and gh.shape == (2, n)
     want_pos, want = hk.fused_level_xla(bins, pos, gh, ptab, K=K, Kp=Kp, B=B,
                                         d=d)
-    kw = dict(K=K, Kp=Kp, B=B, d=d, tr=256, sub=sub)
+    kw = dict(F=F, K=K, Kp=Kp, B=B, d=d, tr=256, sub=sub)
+    binsT = hk._feature_major(bins, hk._SUBLANES, B)
     if kernel == "hoisted":
         got_pos, hist = hk._hoisted_level_pallas(
-            bins, hk.build_onehot(bins[:, :2], B=B), pos, gh, ptab, **kw)
+            binsT, hk.build_onehot(bins[:, :2], B=B), pos, gh, ptab, **kw)
     else:
-        got_pos, hist = hk._fused_level_pallas(bins, pos, gh, ptab, **kw)
+        got_pos, hist = hk._fused_level_pallas(binsT, pos, gh, ptab, **kw)
     assert got_pos.shape == (1, n) and got_pos.dtype == jnp.int32
     np.testing.assert_array_equal(np.asarray(got_pos), np.asarray(want_pos))
     if sub:

@@ -81,14 +81,14 @@ def test_built_half_and_derivation_match_the_direct_build(interpreted, kernel,
                                    d=d - 1)
     want_pos, want = hk.fused_level_xla(bins, pos, gh, ptab, K=K, Kp=Kp, B=B,
                                         d=d)
+    binsT = hk._feature_major(bins, hk._SUBLANES, B)
+    kw = dict(F=F, K=K, Kp=Kp, B=B, d=d, tr=256, sub=True)
     if kernel == "construct":
-        got_pos, built = hk._fused_level_pallas(
-            bins, pos, gh, ptab, K=K, Kp=Kp, B=B, d=d, tr=256, sub=True)
+        got_pos, built = hk._fused_level_pallas(binsT, pos, gh, ptab, **kw)
     else:
         Fh = F if kernel == "full_hoist" else 2
         got_pos, built = hk._hoisted_level_pallas(
-            bins, hk.build_onehot(bins[:, :Fh], B=B), pos, gh, ptab, K=K,
-            Kp=Kp, B=B, d=d, tr=256, sub=True)
+            binsT, hk.build_onehot(bins[:, :Fh], B=B), pos, gh, ptab, **kw)
     assert built.shape == (F, 2 * Kp, B)
     np.testing.assert_array_equal(np.asarray(got_pos), np.asarray(want_pos))
     got = np.asarray(hk.derive_siblings(parent, built, ptab))
@@ -283,7 +283,8 @@ def test_fused_level_dispatches_the_built_widths_tile(monkeypatch, cell,
     n, F, depth, Fh = CELLS[cell]
     calls = []
 
-    def record(bins, onehot, pos, gh, ptab, *, K, Kp, B, d, tr, vma, sub):
+    def record(binsT, onehot, pos, gh, ptab, *, F, K, Kp, B, d, tr, vma,
+               sub):
         calls.append((d, tr, sub))
         Kc = Kp if sub else K
         return pos, jnp.zeros((F, 2 * Kc, B), jnp.float32)

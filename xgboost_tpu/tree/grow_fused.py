@@ -396,8 +396,10 @@ def _grow_tree_fused_impl(
     # "Reading a device profile"). They change HLO metadata only.
     pallas = _pallas_flag(cfg)
     if pallas:
-        # transient in-program widening for the Mosaic kernels; the XLA
-        # and native paths read the NARROW storage dtype directly (the
+        # transient in-program widening for the Mosaic kernels, which read
+        # it feature-major (``hist_kernel._feature_major``: XLA folds the
+        # pad and the transpose into this widening, one array a tree); the
+        # XLA and native paths read the NARROW storage dtype directly (the
         # int8-packing half of the ISSUE 13 tentpole: no 4x int32 copy of
         # the bin matrix on the CPU path)
         with jax.named_scope("xgb.level_hist"):
@@ -655,7 +657,8 @@ def grow_trees_one_pass(
     NT = len(keys)
     assert NT > 1 and cfg.axis_name is None and not cfg.has_categorical
     with jax.named_scope("xgb.level_hist"):
-        bins = bins.astype(jnp.int32)  # once a round, for every tree
+        # once a round, for every tree (read feature-major: as above)
+        bins = bins.astype(jnp.int32)
     n, F = bins.shape
     B = cut_values.shape[1]
     ghs, masks, k_levels, _, _, sts = map(list, zip(*[

@@ -30,9 +30,12 @@ lane-dense form is laid out one and two sublanes deep in HBM (``T(1,128)``,
 ``T(2,128)``: 4 and 8 bytes a row), a ``(k, tr)`` block of it takes at most
 8 sublanes of VMEM (32 bytes a row) and is whole vregs. The XLA and native impls take the
 same form and reshape at their own edge (the C ABI keeps rows major).
-The bins are ``[n, F]`` for the untiled kernels (a column's one-hot is
-``[tr, B]``); a tree whose levels run the TILED kernel reads them
-feature-major, ``[Fp, n]``, rows on the lanes too (``_feature_major``).
+Every Mosaic call of a tree reads the bins FEATURE-MAJOR, ``[Fp, n]`` i32
+with the rows on the lanes too (``_feature_major``: padded with the missing
+bin to whole sublanes for the untiled kernels, to whole feature tiles for
+the tiled one), and builds a column's one-hot ``[B, tr]`` by a sublane
+broadcast of its row (``_construct_columns``). The XLA and native impls
+read the narrow ``[n, F]`` storage.
 
 Missing values: the quantized matrix encodes missing as bin id ``B``; the
 one-hot over ``[0, B)`` is then all-zero, so missing rows simply drop out of
@@ -88,6 +91,11 @@ _MAX_KERNEL_FEATURES = 512
 # the leading dimension of the accumulator block. The bins are padded to
 # whole tiles with the missing bin, whose one-hot is all zero.
 _FEATURE_TILE = 128
+
+# The untiled kernels' feature-major bins are padded to whole sublanes of
+# an i32 vreg (the ``(Fp, tr)`` block is then whole tiles); their loops and
+# accumulators stop at the real ``F``.
+_SUBLANES = 8
 
 # test hook: a feature tile forced on the tiled kernel, and every level sent
 # to it, so that the CPU suite can hold a narrow matrix's tiles against the
@@ -233,19 +241,15 @@ def partition_apply(bins, pos, ptab, *, Kp: int, B: int, d: int,
             # ``fused_level``
             vma = (axis_name,)
             ptab = jax.lax.pcast(ptab, vma, to="varying")
-        ft = _tile_at(F, B, 1)
-        if ft:
-            # even the root ran the tiled kernel, so every level read the
-            # feature-major bins: route on that array, and the tree keeps
-            # one widened copy and not two. (A matrix whose deep levels
-            # alone are tiled keeps the row-major array its shallow levels
-            # and this routing read, the feature-major one beside.)
-            bins = _feature_major(bins, ft, B)
-            F = bins.shape[0]
+        # the feature-major array the tree's root level read (padded to
+        # whole feature tiles where even the root ran the tiled kernel, to
+        # whole sublanes where an untiled kernel took it): the same
+        # expression, so the tree keeps one widened copy. (A matrix whose
+        # deep levels alone are tiled keeps the tiles' array beside it.)
+        bins = _feature_major(bins, _tile_at(F, B, 1) or _SUBLANES, B)
         return _route_rows_pallas(
             bins, pos, ptab, Kp=Kp, B=B, d=d, vma=vma,
-            feature_major=bool(ft),
-            tr=_route_tr(n, F, Kp, ptab.shape[-1]) or TR)
+            tr=_route_tr(n, bins.shape[0], Kp, ptab.shape[-1]) or TR)
     if dec.impl == "native":
         from ..native import boundary
 
@@ -468,15 +472,14 @@ def _bins_operand(binsb, B: int):
         jnp.bfloat16 if B <= 256 else jnp.float32)
 
 
-def _partition_tile(pos, binsb, ptab_ref, *, Kp: int, F: int, B: int,
-                    prev_offset: int, tree=None, bins_op=None,
-                    feature_major: bool = False):
+def _partition_tile(pos, binsT, ptab_ref, *, Kp: int, B: int,
+                    prev_offset: int, tree=None, bins_op=None):
     """Route a tile's rows through the previous level's decision table
     (shared by the level kernels and the routing kernel). ``pos`` is
-    ``[1, Tr]`` i32 (rows on the lanes) and so is the result; ``binsb`` is
-    the ``[Tr, F]`` i32 bins tile (rows on the sublanes, as the untiled
-    kernels' one-hot wants it), or ``[F, Tr]`` out of a tiled tree's
-    ``feature_major`` array; both are values in VMEM. Table layout: ``[Kp, 4]``
+    ``[1, Tr]`` i32 (rows on the lanes) and so is the result; ``binsT`` is
+    the ``[F, Tr]`` i32 tile of the tree's feature-major bins
+    (``_feature_major``; ``F`` counts the padding), a value in VMEM.
+    Table layout: ``[Kp, 4]``
     numerical (is_split, feature, bin, default_left), or ``[Kp, 5 + B]``
     when categorical features exist — column 4 flags a categorical node and
     columns 5: carry its RIGHT-going category set (evaluate_splits.h
@@ -486,12 +489,10 @@ def _partition_tile(pos, binsb, ptab_ref, *, Kp: int, F: int, B: int,
 
     Every per-row quantity is a ``[1, Tr]`` row or a ``[k, Tr]`` stack of
     them: the node one-hot is ``[Kp, Tr]``, the decisions ``ptab^T [W, Kp]
-    @ [Kp, Tr]``. The row's bin of its node's split feature comes without
-    a transpose of the bins tile: ``[Kp, F] @ binsb^T`` (contraction on
-    both minor dimensions, the attention ``q @ k^T`` form) gives every
-    node's split feature for every row, ``[Kp, Tr]``, and the node one-hot
-    picks the row's own. A ``feature_major`` tile is that transpose
-    already: a plain ``[Kp, F] @ [F, Tr]``.
+    @ [Kp, Tr]``. The row's bin of its node's split feature: a plain
+    ``[Kp, F] @ [F, Tr]`` gives every node's split feature for every row,
+    ``[Kp, Tr]`` (the padded columns are no node's feature), and the node
+    one-hot picks the row's own.
 
     ``tree`` (a level call that carries several trees): ``ptab_ref`` is
     ``[T, Kp, W]`` and this tree's table its ``tree``-th; ``bins_op`` is
@@ -512,15 +513,15 @@ def _partition_tile(pos, binsb, ptab_ref, *, Kp: int, F: int, B: int,
     isp_of = dec[0:1, :]
     b_of = dec[2:3, :]
     dl_of = dec[3:4, :]
-    iota_f = jax.lax.broadcasted_iota(jnp.int32, (Kp, F), 1)
+    iota_f = jax.lax.broadcasted_iota(jnp.int32, (Kp, binsT.shape[0]), 1)
     ohf = ptab[:, 1:2].astype(jnp.int32) == iota_f  # [Kp, F]
     narrow = B <= 256  # see _bins_operand
     ohf = ohf.astype(jnp.float32).astype(
         jnp.bfloat16 if narrow else jnp.float32)
     if bins_op is None:
-        bins_op = _bins_operand(binsb, B)
+        bins_op = _bins_operand(binsT, B)
     node_bv = jax.lax.dot_general(
-        ohf, bins_op, (((1,), (0 if feature_major else 1,)), ((), ())),
+        ohf, bins_op, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
         precision=None if narrow else jax.lax.Precision.HIGHEST,
     )  # [Kp, Tr]: bins[row, feature of node]
@@ -561,8 +562,8 @@ def _grad_terms(node, ids, gh_ref, row: int = 0):
     return [ohseg * g_hi, ohseg * h_hi, ohseg * g_lo, ohseg * h_lo]
 
 
-def _route_and_terms(pos, binsb, gh_ref, ptab_ref, built_ref, *, K: int,
-                     Kp: int, F: int, B: int, prev_offset: int, offset: int,
+def _route_and_terms(pos, binsT, gh_ref, ptab_ref, built_ref, *, K: int,
+                     Kp: int, B: int, prev_offset: int, offset: int,
                      tree=None, bins_op=None):
     """The level kernels' shared head, for one tree: route the tile's rows
     (``pos`` ``[1, Tr]``) through the previous level's decisions, then form
@@ -577,7 +578,7 @@ def _route_and_terms(pos, binsb, gh_ref, ptab_ref, built_ref, *, K: int,
     and h at rows ``2 tree``, ``2 tree + 1``)."""
     row = 0 if tree is None else 2 * tree
     if Kp > 0:
-        pos = _partition_tile(pos, binsb, ptab_ref, Kp=Kp, F=F, B=B,
+        pos = _partition_tile(pos, binsT, ptab_ref, Kp=Kp, B=B,
                               prev_offset=prev_offset, tree=tree,
                               bins_op=bins_op)
     if built_ref is None:
@@ -587,7 +588,7 @@ def _route_and_terms(pos, binsb, gh_ref, ptab_ref, built_ref, *, K: int,
     return pos, _grad_terms(pos, built, gh_ref, row)
 
 
-def _route_and_channels(pos_ref, binsb, gh_ref, ptab_ref, built_ref, pos_out,
+def _route_and_channels(pos_ref, binsT, gh_ref, ptab_ref, built_ref, pos_out,
                         *, T, Kp: int, B: int, **kw):
     """Route the block's rows and return the gradient channels the one-hot
     meets, bf16. One tree (``T`` None; ``pos_ref`` ``(1, Tr)``): ``[4Kc,
@@ -601,17 +602,17 @@ def _route_and_channels(pos_ref, binsb, gh_ref, ptab_ref, built_ref, pos_out,
     row. Writes the routed positions to ``pos_out``; None where the rows
     came routed already (the tiled kernel: ``Kp`` 0, no bins, no table)."""
     if T is None:
-        pos, terms = _route_and_terms(pos_ref[:, :], binsb, gh_ref, ptab_ref,
+        pos, terms = _route_and_terms(pos_ref[:, :], binsT, gh_ref, ptab_ref,
                                       built_ref, Kp=Kp, B=B, **kw)
         chans = jnp.concatenate(terms, axis=0).astype(jnp.bfloat16)
         if pos_out is not None:
             pos_out[:, :] = pos
         return chans
-    bins_op = _bins_operand(binsb, B) if Kp > 0 else None
+    bins_op = _bins_operand(binsT, B) if Kp > 0 else None
     hi, lo = [], []
     for t in range(T):
         pos, terms = _route_and_terms(
-            pos_ref[t:t + 1, :], binsb, gh_ref, ptab_ref, built_ref, Kp=Kp,
+            pos_ref[t:t + 1, :], binsT, gh_ref, ptab_ref, built_ref, Kp=Kp,
             B=B, tree=t, bins_op=bins_op, **kw)
         if pos_out is not None:
             pos_out[t:t + 1, :] = pos
@@ -625,13 +626,14 @@ def _level_kernel(bins_ref, pos_ref, gh_ref, ptab_ref, *rest,
                   prev_offset: int, offset: int, T=None):
     """One grid step: partition `Tr` rows through the previous level's
     decisions, then accumulate their (g, h) into this level's histogram.
-    ``pos_ref`` and ``gh_ref`` are the ``(1, Tr)`` and ``(2, Tr)`` blocks of
+    ``bins_ref`` is the ``(Fp, Tr)`` block of the feature-major bins,
+    ``pos_ref`` and ``gh_ref`` the ``(1, Tr)`` and ``(2, Tr)`` blocks of
     the lane-dense arrays. ``rest``: the outputs ``pos_out, hist_ref``,
     behind ``built_ref`` where siblings are subtracted (the histogram is
     then the built children's, ``Kc = Kp`` nodes wide). ``T`` trees of one
     round (``_route_and_channels``): blocks ``(T, Tr)`` and ``(2T, Tr)``,
-    the histogram ``2 T Kc`` rows, and the feature's ``col == iota``
-    compare is built once for all of them."""
+    the histogram ``2 T Kc`` rows, and a column's one-hot is built once
+    for all of them."""
     from jax.experimental import pallas as pl
 
     *built_ref, pos_out, hist_ref = rest
@@ -643,29 +645,51 @@ def _level_kernel(bins_ref, pos_ref, gh_ref, ptab_ref, *rest,
     def _():
         hist_ref[...] = jnp.zeros_like(hist_ref)
 
-    binsb = bins_ref[:, :]  # [Tr, F] i32
-    M = 2 * (K if built_ref is None else Kp) * (T or 1)  # histogram rows
+    binsT = bins_ref[:, :]  # [Fp, Tr] i32
     ghs4 = _route_and_channels(  # [2M, Tr]
-        pos_ref, binsb, gh_ref, ptab_ref, built_ref, pos_out, T=T, K=K,
-        Kp=Kp, F=F, B=B, prev_offset=prev_offset, offset=offset)
+        pos_ref, binsT, gh_ref, ptab_ref, built_ref, pos_out, T=T, K=K,
+        Kp=Kp, B=B, prev_offset=prev_offset, offset=offset)
 
-    _construct_columns(hist_ref, binsb, ghs4, M, B)
+    _construct_columns(hist_ref, binsT, ghs4, B, range(F))
 
 
-def _construct_columns(hist_ref, binsb, ghs4, M: int, B: int):
-    """The construct loop of the kernels whose accumulator is ``[columns,
-    M, B]``: for every column of the ``[Tr, columns]`` bins tile, its
-    one-hot built in VMEM and met with the channels ``[2M, Tr]``."""
-    Tr, F = binsb.shape
-    for f in range(F):
-        col = binsb[:, f:f + 1]
-        iota_b = jax.lax.broadcasted_iota(jnp.int32, (Tr, B), 1)
-        oh = (col == iota_b).astype(jnp.bfloat16)  # missing (==B) -> zero row
+def _construct_columns(hist_ref, binsT, ghs4, B: int, cols):
+    """The construct loop of every level kernel: for each column ``f`` of
+    ``cols`` (rows of the ``[Fp, Tr]`` feature-major bins tile), its
+    one-hot ``[B, Tr]`` built in VMEM by a SUBLANE broadcast of the row
+    ``binsT[f]`` against an iota over the sublanes (one broadcast a
+    128-row lane group, shared by its ``B / 8`` vregs: PERF.md section 6,
+    PR 36), met with the channels ``[2M, Tr]`` with both minor dimensions
+    contracted (the MXU latches the weights transposed), and added into
+    the accumulator: ``hist_ref[f]`` where it is ``[columns, M, B]``, lanes
+    ``f B .. (f + 1) B`` of the streaming kernel's ``[M, F B]``."""
+    M = ghs4.shape[0] // 2
+    Tr = binsT.shape[1]
+    iota_b = jax.lax.broadcasted_iota(jnp.int32, (B, Tr), 0)
+    for f in cols:
+        # missing (== B) -> a zero column
+        oh_t = (binsT[f:f + 1, :] == iota_b).astype(jnp.bfloat16)
         out = jax.lax.dot_general(
-            ghs4, oh, (((1,), (0,)), ((), ())),
+            ghs4, oh_t, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )  # [2M, Tr] @ [Tr, B]
-        hist_ref[f, :, :] += out[:M] + out[M:]
+        )  # [2M, Tr] x [B, Tr] -> [2M, B]
+        if len(hist_ref.shape) == 3:
+            hist_ref[f, :, :] += out[:M] + out[M:]
+        else:
+            hist_ref[:, f * B:(f + 1) * B] += out[:M] + out[M:]
+
+
+def _in_hbm(binsT):
+    """The untiled kernels' feature-major bins, held in HBM for the call.
+    Without the constraint XLA may keep an array that fits the chip's
+    VMEM there for a whole round (Cover Type's ``s32[56, 436224]``, 98
+    MB), and the split evaluation's loops, spilled to HBM, lose more than
+    the kernels gain."""
+    if _INTERPRET:  # a placement for the chip's compiler alone
+        return binsT
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.with_memory_space_constraint(binsT, pltpu.HBM)
 
 
 def _vma_struct(shape, dtype, axes):
@@ -715,13 +739,17 @@ def _tree_axis(ptab, Kp: int):
 
 
 @guard_jit(name="fused_level_pallas",
-           static_argnames=("K", "Kp", "B", "d", "tr", "vma", "sub"))
-def _fused_level_pallas(bins, pos, gh, ptab, *, K, Kp, B, d, tr=TR,
+           static_argnames=("F", "K", "Kp", "B", "d", "tr", "vma", "sub"))
+def _fused_level_pallas(binsT, pos, gh, ptab, *, F, K, Kp, B, d, tr=TR,
                         vma=(), sub=False):
+    """The in-kernel construction of a level: the feature-major i32 bins
+    ``[Fp, n]`` (``_feature_major``; ``F`` of its rows real) by ``(Fp,
+    tr)`` blocks, positions ``[R, n]`` and gradients ``[2R, n]`` in; the
+    routed positions and ``[F, 2Kc, B]`` out."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    n, F = bins.shape
+    Fp, n = binsT.shape
     assert n % tr == 0, f"rows {n} not padded to {tr}"
     prev_offset = (1 << (d - 1)) - 1 if d > 0 else 0
     offset = (1 << d) - 1
@@ -736,7 +764,7 @@ def _fused_level_pallas(bins, pos, gh, ptab, *, K, Kp, B, d, tr=TR,
         kern,
         grid=(n // tr,),
         in_specs=[
-            pl.BlockSpec((tr, F), lambda c: (c, 0), memory_space=pltpu.VMEM),
+            pl.BlockSpec((Fp, tr), lambda c: (0, c), memory_space=pltpu.VMEM),
             pl.BlockSpec((R, tr), lambda c: (0, c), memory_space=pltpu.VMEM),
             pl.BlockSpec((2 * R, tr), lambda c: (0, c),
                          memory_space=pltpu.VMEM),
@@ -752,7 +780,7 @@ def _fused_level_pallas(bins, pos, gh, ptab, *, K, Kp, B, d, tr=TR,
             _vma_struct((F, 2 * R * Kc, B), jnp.float32, vma),
         ],
         interpret=_INTERPRET,
-    )(bins, pos, gh, ptab, *built)
+    )(_in_hbm(binsT), pos, gh, ptab, *built)
     if T is None:
         return pos_new, hist
     # [F, T * 2Kc, B] -> a tree's [F, 2Kc, B] each
@@ -764,8 +792,9 @@ def _hoisted_kernel(bins_ref, oh_ref, pos_ref, gh_ref, ptab_ref, *rest,
                     prev_offset: int, offset: int, T=None):
     """Hoisted-one-hot grid step: partition + grad channels (cheap VPU, on
     whole vregs: rows on the lanes), ONE [4Kc, Tr] x [Tr, Fh*B] MXU matmul
-    streaming the resident one-hot for the first ``Fh`` features, and an
-    in-kernel construct loop for the remaining ``F - Fh`` (empty when the
+    streaming the resident one-hot for the first ``Fh`` features, and the
+    construct loop (``_construct_columns``, on the ``(Fp, Tr)`` block of
+    the feature-major bins) for the remaining ``F - Fh`` (empty when the
     full expansion fit HBM). ``pos_ref``, ``gh_ref``, ``rest``, ``Kc`` and
     ``T`` as in ``_level_kernel``: with T trees the one-hot tile is read
     once and meets all their channels, ``[4 T Kc, Tr]``, in that one
@@ -781,12 +810,11 @@ def _hoisted_kernel(bins_ref, oh_ref, pos_ref, gh_ref, ptab_ref, *rest,
     def _():
         hist_ref[...] = jnp.zeros_like(hist_ref)
 
-    binsb = bins_ref[:, :]
-    Tr = binsb.shape[0]
+    binsT = bins_ref[:, :]  # [Fp, Tr] i32
     M = 2 * (K if built_ref is None else Kp) * (T or 1)  # accumulator rows
     ghs4 = _route_and_channels(  # [2M, Tr]
-        pos_ref, binsb, gh_ref, ptab_ref, built_ref, pos_out, T=T, K=K,
-        Kp=Kp, F=F, B=B, prev_offset=prev_offset, offset=offset)
+        pos_ref, binsT, gh_ref, ptab_ref, built_ref, pos_out, T=T, K=K,
+        Kp=Kp, B=B, prev_offset=prev_offset, offset=offset)
 
     oh = oh_ref[:, :].astype(jnp.bfloat16)  # [Tr, Fh*B] int8 -> bf16
     out = jax.lax.dot_general(
@@ -794,30 +822,27 @@ def _hoisted_kernel(bins_ref, oh_ref, pos_ref, gh_ref, ptab_ref, *rest,
         preferred_element_type=jnp.float32,
     )  # [2M, Fh*B]
     hist_ref[:, : Fh * B] += out[:M] + out[M:]
-    for f in range(Fh, F):
-        col = binsb[:, f:f + 1]
-        iota_b = jax.lax.broadcasted_iota(jnp.int32, (Tr, B), 1)
-        ohf = (col == iota_b).astype(jnp.bfloat16)
-        outf = jax.lax.dot_general(
-            ghs4, ohf, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [2M, B]
-        hist_ref[:, f * B:(f + 1) * B] += outf[:M] + outf[M:]
+    _construct_columns(hist_ref, binsT, ghs4, B, range(Fh, F))
 
 
 @guard_jit(name="hoisted_level_pallas",
-           static_argnames=("K", "Kp", "B", "d", "tr", "vma", "sub"))
-def _hoisted_level_pallas(bins, onehot, pos, gh, ptab, *, K, Kp, B, d,
+           static_argnames=("F", "K", "Kp", "B", "d", "tr", "vma", "sub"))
+def _hoisted_level_pallas(binsT, onehot, pos, gh, ptab, *, F, K, Kp, B, d,
                           tr=TR_HOIST, vma=(), sub=False):
+    """A level on the resident one-hot ``[n, Fh*B]`` (rows-major, as the
+    streaming matmul takes it) and the feature-major i32 bins ``[Fp, n]``
+    (``F`` of its rows real: the routing and the construct loop's), by
+    ``(tr, Fh*B)`` and ``(Fp, tr)`` blocks; the contract of
+    ``_fused_level_pallas``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    n, F = bins.shape
+    Fp, n = binsT.shape
     Q = F * B
     Qh = onehot.shape[1]
     Fh = Qh // B  # the onehot's width IS the partial-hoist plan
-    assert onehot.shape == (n, Qh) and Qh == Fh * B and Fh <= F, (
-        onehot.shape, F, B)
+    assert onehot.shape == (n, Qh) and Qh == Fh * B and Fh <= F <= Fp, (
+        onehot.shape, F, Fp, B)
     assert n % tr == 0, f"rows {n} not padded to {tr}"
     prev_offset = (1 << (d - 1)) - 1 if d > 0 else 0
     offset = (1 << d) - 1
@@ -832,7 +857,7 @@ def _hoisted_level_pallas(bins, onehot, pos, gh, ptab, *, K, Kp, B, d,
         kern,
         grid=(n // tr,),
         in_specs=[
-            pl.BlockSpec((tr, F), lambda c: (c, 0), memory_space=pltpu.VMEM),
+            pl.BlockSpec((Fp, tr), lambda c: (0, c), memory_space=pltpu.VMEM),
             pl.BlockSpec((tr, Qh), lambda c: (c, 0), memory_space=pltpu.VMEM),
             pl.BlockSpec((R, tr), lambda c: (0, c), memory_space=pltpu.VMEM),
             pl.BlockSpec((2 * R, tr), lambda c: (0, c),
@@ -849,7 +874,7 @@ def _hoisted_level_pallas(bins, onehot, pos, gh, ptab, *, K, Kp, B, d,
             _vma_struct((2 * R * Kc, Q), jnp.float32, vma),
         ],
         interpret=_INTERPRET,
-    )(bins, onehot, pos, gh, ptab, *built)
+    )(_in_hbm(binsT), onehot, pos, gh, ptab, *built)
     if T is None:
         # [2Kc, F*B] -> the dispatcher contract [F, 2Kc, B]
         hist = jnp.transpose(hist2.reshape(2 * Kc, F, B), (1, 0, 2))
@@ -875,19 +900,12 @@ def _hoisted_level_pallas(bins, onehot, pos, gh, ptab, *, K, Kp, B, d,
 # columns. Every column's one-hot is built in VMEM (nothing resident is
 # streamed: ``hoist_plan``), and a tile's step stays inside the budget the
 # untiled streaming step has (``_tile_tr``).
-# A tiled tree's bins are FEATURE-MAJOR, ``[Fp, n]`` i32 (``_feature_major``,
-# made once a tree by the widening): a tile's block is ``(ft, tr)``, a
-# column a ``[1, tr]`` row of it, and its one-hot ``[B, tr]`` a SUBLANE
-# broadcast against an iota over the sublanes: one broadcast a 128-row lane
-# group, shared by the group's ``B / 8`` vregs (out of a ``(tr, ft)`` block
-# every vreg of eight rows took a lane broadcast of its own: 0.241 us a
-# (1,024-row tile, feature) against 0.093, PERF.md section 6, PR 36). It
-# meets the channels with both minor dimensions contracted, ``[2M, tr] x
-# [B, tr] -> [2M, B]``: the MXU latches the weights transposed. The routing
-# beside the tiles reads the same array (``feature_major``).
-# Per histogram cell the sums are the untiled kernel's, row tile by row tile
-# in the same order: at the same row tile the result is the untiled kernel's
-# bit for bit (tests/test_feature_tiles.py).
+# The tiles read the tree's feature-major bins padded to whole tiles
+# (``_feature_major``), a tile's block ``(ft, tr)``, by the construct loop
+# every level kernel shares (``_construct_columns``); the routing beside
+# them reads the same array. Per histogram cell the sums are the untiled
+# kernel's, row tile by row tile in the same order: at the same row tile the
+# result is the untiled kernel's bit for bit (tests/test_feature_tiles.py).
 # ---------------------------------------------------------------------------
 
 
@@ -911,20 +929,10 @@ def _tiled_level_kernel(bins_ref, pos_ref, gh_ref, *rest, K: int, B: int,
     # rows ALREADY at this level: the untiled kernels' channel stack, in
     # the same row order, with no routing and nothing written
     ghs4 = _route_and_channels(pos_ref, None, gh_ref, None, built_ref, None,
-                               T=T, K=K, Kp=0, F=0, B=0, prev_offset=0,
+                               T=T, K=K, Kp=0, B=0, prev_offset=0,
                                offset=offset)
-    M = ghs4.shape[0] // 2
     binsT = bins_ref[:, :]  # [ft, Tr] i32
-    ft, Tr = binsT.shape
-    iota_b = jax.lax.broadcasted_iota(jnp.int32, (B, Tr), 0)
-    for f in range(ft):
-        # missing (== B) -> a zero column
-        oh_t = (binsT[f:f + 1, :] == iota_b).astype(jnp.bfloat16)
-        out = jax.lax.dot_general(
-            ghs4, oh_t, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [2M, Tr] x [B, Tr] -> [2M, B]
-        hist_ref[f, :, :] += out[:M] + out[M:]
+    _construct_columns(hist_ref, binsT, ghs4, B, range(binsT.shape[0]))
 
 
 # "level" in the name: the benchmark books a Mosaic call so named to the
@@ -969,13 +977,15 @@ def _tiled_level_pallas(binsT, pos, gh, ptab, *, K, Kp, B, d, tr, ft, vma=(),
 
 
 def _feature_major(bins, ft: int, B: int):
-    """A tiled tree's i32 bins ``[n, F]`` as its Mosaic calls read them:
-    FEATURE-MAJOR ``[Fp, n]`` (rows on the lanes), padded to whole feature
-    tiles with the missing bin (an all-zero one-hot: a padded column's
-    histogram is zero and is cut off). The same expression wherever a
-    tree's program asks for it, so XLA keeps one such array a tree (it
-    folds the pad and the transpose into the widening), read by every
-    level's tiles and routing."""
+    """A tree's i32 bins ``[n, F]`` as its Mosaic calls read them:
+    FEATURE-MAJOR ``[Fp, n]`` (rows on the lanes), padded to a multiple of
+    ``ft`` columns (whole feature tiles for the tiled kernel, whole
+    sublanes, ``_SUBLANES``, for the untiled ones) with the missing bin
+    (an all-zero one-hot: a padded column is no node's feature, and the
+    tiled kernel's histogram of it is zero and cut off). The same
+    expression wherever a tree's program asks for it, so XLA keeps one
+    such array a tree (it folds the pad and the transpose into the
+    widening), read by every level call and routing."""
     pad = -bins.shape[1] % ft
     if pad:
         bins = jnp.pad(bins, ((0, 0), (0, pad)), constant_values=B)
@@ -996,12 +1006,11 @@ def _tiled_level(bins, pos, gh, ptab, *, K, Kp, B, d, plan, vma, sub):
         with jax.named_scope("xgb.partition"):
             if T is None:
                 pos = _route_rows_pallas(binsT, pos, ptab, Kp=Kp, B=B, d=d,
-                                         tr=tr_r, vma=vma, feature_major=True)
+                                         tr=tr_r, vma=vma)
             else:
                 pos = jnp.concatenate([
                     _route_rows_pallas(binsT, pos[t:t + 1], ptab[t], Kp=Kp,
-                                       B=B, d=d, tr=tr_r, vma=vma,
-                                       feature_major=True)
+                                       B=B, d=d, tr=tr_r, vma=vma)
                     for t in range(T)])
     hist = _tiled_level_pallas(binsT, pos, gh, ptab, K=K, Kp=Kp, B=B, d=d,
                                tr=plan.tr, ft=plan.ft, vma=vma, sub=sub)[:F]
@@ -1011,40 +1020,35 @@ def _tiled_level(bins, pos, gh, ptab, *, K, Kp, B, d, plan, vma, sub):
     return pos, jnp.transpose(hist.reshape(F, T, -1, B), (1, 0, 2, 3))
 
 
-def _route_kernel(bins_ref, pos_ref, ptab_ref, pos_out, *, Kp: int, F: int,
-                  B: int, prev_offset: int, feature_major: bool):
+def _route_kernel(bins_ref, pos_ref, ptab_ref, pos_out, *, Kp: int, B: int,
+                  prev_offset: int):
     """One grid step of a routing: ``Tr`` rows (a ``(1, Tr)`` block of
     positions in and out) through a level's decisions, and nothing else."""
     pos_out[:, :] = _partition_tile(pos_ref[:, :], bins_ref[:, :], ptab_ref,
-                                    Kp=Kp, F=F, B=B, prev_offset=prev_offset,
-                                    feature_major=feature_major)
+                                    Kp=Kp, B=B, prev_offset=prev_offset)
 
 
 # no "level" in this name: the TPU compiler names the Mosaic call after the
 # function, and the benchmark books calls so named to the level histogram
 @guard_jit(name="route_rows_pallas",
-           static_argnames=("Kp", "B", "d", "tr", "vma", "feature_major"))
-def _route_rows_pallas(bins, pos, ptab, *, Kp, B, d, tr=TR, vma=(),
-                       feature_major=False):
-    """The routing kernel over ``bins`` ``[n, F]`` i32, block ``(tr, F)``;
-    a tiled tree's ``feature_major`` ``[Fp, n]``, block ``(Fp, tr)``."""
+           static_argnames=("Kp", "B", "d", "tr", "vma"))
+def _route_rows_pallas(binsT, pos, ptab, *, Kp, B, d, tr=TR, vma=()):
+    """The routing kernel over a tree's feature-major i32 bins ``[Fp, n]``
+    (``_feature_major``), block ``(Fp, tr)``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    n, F = bins.shape[::-1] if feature_major else bins.shape
+    Fp, n = binsT.shape
     assert n % tr == 0, f"rows {n} not padded to {tr}"
     prev_offset = (1 << (d - 1)) - 1 if d > 0 else 0
     W = ptab.shape[1]
-    kern = functools.partial(_route_kernel, Kp=Kp, F=F, B=B,
-                             prev_offset=prev_offset,
-                             feature_major=feature_major)
-    block, at = ((F, tr), lambda c: (0, c)) if feature_major else (
-        (tr, F), lambda c: (c, 0))
+    kern = functools.partial(_route_kernel, Kp=Kp, B=B,
+                             prev_offset=prev_offset)
     return pl.pallas_call(
         kern,
         grid=(n // tr,),
         in_specs=[
-            pl.BlockSpec(block, at, memory_space=pltpu.VMEM),
+            pl.BlockSpec((Fp, tr), lambda c: (0, c), memory_space=pltpu.VMEM),
             pl.BlockSpec((1, tr), lambda c: (0, c), memory_space=pltpu.VMEM),
             pl.BlockSpec((Kp, W), lambda c: (0, 0), memory_space=pltpu.VMEM),
         ],
@@ -1052,7 +1056,7 @@ def _route_rows_pallas(bins, pos, ptab, *, Kp, B, d, tr=TR, vma=(),
                                memory_space=pltpu.VMEM),
         out_shape=_vma_struct((1, n), jnp.int32, vma),
         interpret=_INTERPRET,
-    )(bins, pos, ptab)
+    )(binsT, pos, ptab)
 
 
 def partition_apply_xla(bins, pos, ptab, *, Kp: int, B: int, d: int,
@@ -1153,6 +1157,10 @@ def _hoist_vmem_bytes(tr: int, Qh: int, K: int, F: int,
     this kernel: the construct loop for unhoisted features writes into it;
     the tiled kernel keeps ``_FEATURE_TILE`` columns of it,
     ``_tile_vmem_bytes``) + the bins tile + per-feature construct scratch.
+    The bins tile is counted ``tr x F`` i32, as the parent's plans had it:
+    the feature-major ``(Fp, tr)`` block (ISSUE 38) is whole sublanes of
+    ``tr`` lanes, under the lane-padded ``(tr, F)`` block it replaced, and
+    the VMEM that frees is not spent (every plan stays the parent's).
     ``B=None`` (legacy 3-arg callers) means full hoist: Qh==F*B."""
     if B is None:
         B = Qh // F
@@ -1282,11 +1290,12 @@ def feature_tile(F: int, B: int, max_depth: int) -> int:
 
 def _route_vmem_bytes(tr: int, F: int, Kp: int, W: int) -> int:
     """One grid step of the routing kernel, counted at the tiles it
-    occupies (8 sublanes, 128 lanes). Blocks, double-buffered: the
-    ``(tr, F)`` i32 bins tile (``tr`` sublanes of ``_up(F, 128)`` lanes;
-    a tiled tree's feature-major ``(F, tr)`` tile is ``_up(F, 8)`` sublanes
-    of ``tr`` lanes: never more, and the same at whole feature tiles of
-    128), positions in and out as ``(1, tr)`` rows
+    occupies (8 sublanes, 128 lanes). Blocks, double-buffered: the i32
+    bins tile, counted as the ``(tr, F)`` block it was before ISSUE 38
+    (``tr`` sublanes of ``_up(F, 128)`` lanes; the feature-major ``(F,
+    tr)`` block every routing reads is ``_up(F, 8)`` sublanes of ``tr``
+    lanes: never more, and the same at whole feature tiles of 128, so the
+    tiles stay the parent's), positions in and out as ``(1, tr)`` rows
     (8 sublanes each, 32 bytes a row of data where the ``(tr, 1)`` columns
     took 512), the ``(Kp, W)`` decision table. Values of
     ``_partition_tile``: the bins tile as loaded with its f32 and bf16
@@ -1352,7 +1361,8 @@ def _level_call(bins, onehot, pos, gh, ptab, *, K, Kp, B, d, vma, sub):
     nodes of every tree ``ptab`` carries: the streaming kernel, the
     in-kernel construction, or the tiled kernel behind one routing
     (``_tiled_level``; the printed routes then say how many feature tiles
-    the call swept)."""
+    the call swept). Each reads the tree's widened ``bins`` feature-major
+    (``_feature_major``)."""
     n, F = bins.shape
     trees = ptab.shape[0] if ptab.ndim == 3 else 1
     nodes = trees * (Kp if sub else K)
@@ -1363,19 +1373,21 @@ def _level_call(bins, onehot, pos, gh, ptab, *, K, Kp, B, d, vma, sub):
         plan = level_plan(n, F, nodes, B, 0, W)
     else:
         plan = level_plan(n, F, nodes, B, onehot.shape[1], W)
-    if plan is not None and plan.kernel == "hoisted":
-        return _hoisted_level_pallas(bins, onehot, pos, gh, ptab, K=K, Kp=Kp,
-                                     B=B, d=d, tr=plan.tr, vma=vma, sub=sub)
     if plan is not None and plan.kernel == "tiled":
         from ..dispatch import note
 
         note("feature_tiles", plan.tiles)
         return _tiled_level(bins, pos, gh, ptab, K=K, Kp=Kp, B=B, d=d,
                             plan=plan, vma=vma, sub=sub)
+    binsT = _feature_major(bins, _SUBLANES, B)
+    if plan is not None and plan.kernel == "hoisted":
+        return _hoisted_level_pallas(binsT, onehot, pos, gh, ptab, F=F, K=K,
+                                     Kp=Kp, B=B, d=d, tr=plan.tr, vma=vma,
+                                     sub=sub)
     # the in-kernel construction; also what a pin to ``pallas`` gets where
     # the model says nothing fits
-    return _fused_level_pallas(bins, pos, gh, ptab, K=K, Kp=Kp, B=B, d=d,
-                               vma=vma, sub=sub)
+    return _fused_level_pallas(binsT, pos, gh, ptab, F=F, K=K, Kp=Kp, B=B,
+                               d=d, vma=vma, sub=sub)
 
 
 def fused_level(bins, pos, gh, ptab, *, K, Kp, B, d, pallas: bool,
