@@ -16,8 +16,8 @@ path that runs off the chip. The last line of stdout is one JSON object
     JAX_PLATFORMS=cpu python chip_smoke.py --rehearse   # CPU rehearsal
 
 ``--stages leafwise`` runs the leaf-wise chip path alone (the node
-kernel, the partition and a 255-leaf tree at ``higgs_leafwise_train``'s
-10.5M x 28, bin256, and at 8,192 rows).
+kernel, the arena partition and a 255-leaf tree at
+``higgs_leafwise_train``'s 10.5M x 28, bin256, and at 8,192 rows).
 
 ``--rehearse`` runs the same stages at a tiny size with the Pallas
 kernels in interpret mode, so the command can be debugged before chip
@@ -519,12 +519,13 @@ def stage_wide(sz: Sizes, routes: dict) -> None:
 
 def stage_leafwise(sz: Sizes) -> None:
     """The leaf-wise chip path at the ``higgs_leafwise_train`` cell's shape and a small
-    one: the node kernel over a gathered range against ``np.bincount``
-    (g and h in the hi/lo class, the row counts exact), the stable
-    partition of a range against numpy's, and a whole tree of the
-    configured leaves through ``grow_tree_lossguide_rows``, timed cold and
-    warm; at the small shape the tree is the plain reference's, node for
-    node (gradients two bf16 terms carry exactly)."""
+    one: the node kernel over an arena range against ``np.bincount`` (g
+    and h in the hi/lo class, the row counts exact), the arena partition
+    of a range against numpy's, and a whole tree of the configured leaves
+    through ``grow_tree_lossguide_rows``, timed cold and warm, with the rows
+    its node histograms built and its partitions streamed; at the small
+    shape the tree is the plain reference's, node for node (gradients two
+    bf16 terms carry exactly)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -535,6 +536,7 @@ def stage_leafwise(sz: Sizes) -> None:
         chip_bins,
         grow_tree_lossguide,
         grow_tree_lossguide_rows,
+        node_work,
     )
     from xgboost_tpu.tree.param import SplitParams
 
@@ -553,16 +555,17 @@ def stage_leafwise(sz: Sizes) -> None:
         binsT, packed = _sync(chip_bins(bins, B))
         Fp, n_pad = binsT.shape
         ch = nh.chunk_rows(n_pad)
+        # the arena with its rows in a random order, as a tree leaves it
         perm = rng.permutation(n).astype(np.int32)
-        order = jnp.concatenate([jnp.asarray(perm),
-                                 jnp.zeros((n_pad + ch - n,), jnp.int32)])
-        rows = nh.node_rows(packed, g, h)
+        arena = _sync(jax.jit(lambda p, g, h, o: nh.build_arena(
+            p[:, o], g[o], h[o], n_pad + ch).at[0, -(-F // 3) + 2, :n].set(o))(
+                packed, g, h, jnp.asarray(perm)))
         start, count = n // 7, min(n // 2, 200_000)
-        hist_fn = jax.jit(lambda r, o, s, c: nh.node_hist(
-            r, o, s, c, F=F, B=B, Fp=Fp, ch=ch))
-        hist, cold = _timed(lambda: hist_fn(rows, order, jnp.int32(start),
+        hist_fn = jax.jit(lambda a, s, c: nh.node_hist(
+            a, jnp.int32(0), s, c, F=F, B=B, Fp=Fp, ch=ch))
+        hist, cold = _timed(lambda: hist_fn(arena, jnp.int32(start),
                                             jnp.int32(count)))
-        _, warm = _timed(lambda: hist_fn(rows, order, jnp.int32(start),
+        _, warm = _timed(lambda: hist_fn(arena, jnp.int32(start),
                                          jnp.int32(count)))
         hist = np.asarray(hist)
         ids = perm[start:start + count]
@@ -581,22 +584,29 @@ def stage_leafwise(sz: Sizes) -> None:
         say(f"  leafwise {n}x{F} bin{B}: node histogram of {count} rows in "
             f"chunks of {ch}: cold {cold:.2f}s warm {warm:.4f}s, counts "
             "exact, sums in the hi/lo class")
-        f_s, b_s = 3, B // 2
-        seg = perm[:min(n, 3_000_000)]
+        # a range from a mid-tile lane: the arena's rows [s0, s0 + m) moved
+        f_s, b_s, s0 = 3, B // 2, 77
+        m = min(n - s0, 3_000_000)
+        part_fn = jax.jit(lambda a, c: nh.partition_node(
+            a, jnp.int32(0), jnp.int32(s0), c, jnp.int32(f_s),
+            jnp.int32(b_s), jnp.bool_(True), B=B), donate_argnums=0)
+        # each call moves a copy made before its clock starts
+        copies = [_sync(jnp.copy(arena)) for _ in range(2)]
+        (new, n_left), cold = _timed(lambda: part_fn(copies[0],
+                                                     jnp.int32(m)))
+        _, warm = _timed(lambda: part_fn(copies[1], jnp.int32(m)))
+        seg = perm[s0:s0 + m]
         bv = bins_np[seg, f_s]
         go = np.where(bv == B, True, bv <= b_s)
-        part_fn = jax.jit(lambda o, sp, c: nh.partition_node(
-            binsT, o, sp, jnp.int32(0), c, jnp.int32(f_s), jnp.int32(b_s),
-            jnp.bool_(True), B=B, ch=ch)[::2])
-        args = (order, jnp.zeros((2 * order.shape[0],), jnp.int32),
-                jnp.int32(len(seg)))
-        (new, n_left), cold = _timed(lambda: part_fn(*args))
-        _, warm = _timed(lambda: part_fn(*args))
+        ids_new = np.asarray(new[1, -(-F // 3) + 2])
         check(int(n_left) == int(go.sum()) and np.array_equal(
-            np.asarray(new)[:len(seg)], np.concatenate([seg[go], seg[~go]])),
+            ids_new[s0:s0 + m], np.concatenate([seg[go], seg[~go]])),
               f"leafwise {n}x{F}: the partition is not numpy's")
-        say(f"  leafwise {n}x{F}: stable partition of {len(seg)} rows: "
-            f"cold {cold:.2f}s warm {warm:.4f}s, numpy's order")
+        check(np.array_equal(np.asarray(new[0]), np.asarray(arena[0])),
+              f"leafwise {n}x{F}: the partition moved its source half")
+        say(f"  leafwise {n}x{F}: arena partition of {m} rows: cold "
+            f"{cold:.2f}s warm {warm:.4f}s ({warm / m * 1e9:.2f} ns a row), "
+            "numpy's order")
         cfg = GrowParams(max_depth=0, split=SplitParams(min_child_weight=1.0))
         cuts = jnp.asarray(np.tile(np.arange(1, B + 1, dtype=np.float32),
                                    (F, 1)))
@@ -606,15 +616,18 @@ def stage_leafwise(sz: Sizes) -> None:
             return grow_tree_lossguide_rows(binsT, packed, g, h, cuts, key,
                                             cfg, leaves, n)
 
-        (alloc, built), cold = _timed(tree)
+        (alloc, rows), cold = _timed(tree)
         _, warm = _timed(tree)
         got = int(np.sum(np.asarray(alloc.left) >= 0)) + 1
         check(got == leaves, f"leafwise {n}x{F}: {got} leaves, not {leaves}")
-        check(0 < int(built) < 10 * n,
-              f"leafwise {n}x{F}: node histograms built {int(built)} rows")
+        streamed, built = node_work(alloc.left, alloc.right, rows)
+        check(0 < built < streamed,
+              f"leafwise {n}x{F}: node histograms built {built} rows, "
+              f"partitions streamed {streamed}")
         say(f"  leafwise {n}x{F}: a tree of {leaves} leaves: cold {cold:.2f}s "
-            f"warm {warm:.3f}s, node histograms over {int(built)} rows "
-            f"({int(built) / n:.2f} n)")
+            f"warm {warm:.3f}s, node histograms over {built} rows "
+            f"({built / n:.2f} n), node_partition_rows_total {streamed} "
+            f"({streamed / n:.2f} n)")
         if n <= 8192:
             ref = grow_tree_lossguide(bins, g, h, cuts, key, cfg, leaves)
             m = int(ref.n_nodes)
@@ -622,7 +635,8 @@ def stage_leafwise(sz: Sizes) -> None:
                 np.array_equal(np.asarray(getattr(ref, k))[:m],
                                np.asarray(getattr(alloc, k))[:m])
                 for k in ("left", "right", "feature", "split_bin",
-                          "default_left"))
+                          "default_left")) and np.array_equal(
+                np.asarray(ref.positions), np.asarray(alloc.positions))
             check(same, f"leafwise {n}x{F}: not the reference's tree")
             say(f"  leafwise {n}x{F}: the plain reference's tree, node for "
                 "node")

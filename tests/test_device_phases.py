@@ -758,3 +758,53 @@ def test_untiled_tree_reads_one_feature_major_array(one_v5e_chip,
         assert ('"input_memory_space_colors":[{"operand_index":"0",'
                 '"color":"0"') in ln
     assert re.match(r"(?:ROOT )?%_route_rows_pallas[.\d]* = ", routes[0])
+
+
+def test_leafwise_tree_moves_its_arena_in_named_kernels(one_v5e_chip):
+    """The leaf-wise tree program (``grow_tree_lossguide_rows``), compiled
+    for a described v5e at HIGGS's 28 columns and 255 leaves (65,536 rows:
+    the kernels' blocks do not depend on the row count): a step's partition is two Mosaic calls, ``_node_count_pallas``
+    and ``_node_move_pallas``, each with ``xgb.node_partition`` its
+    innermost ``xgb.`` scope and no ``level`` in its name (the benchmark's
+    reduction books neither to the level histogram), beside the node
+    kernel under ``xgb.node_hist``; the move updates the tree's arena in
+    place: the program copies the ``[2, C, L]`` arena nowhere."""
+    import jax.numpy as jnp
+
+    from xgboost_tpu.tree import node_hist as nh
+    from xgboost_tpu.tree.grow import GrowParams
+    from xgboost_tpu.tree.grow_lossguide import grow_tree_lossguide_rows
+    from xgboost_tpu.tree.param import SplitParams
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
+
+    n, F, B = 65536, 28, 256
+    Fp, W = hk._up(F, hk._SUBLANES), -(-F // 3)
+    hlo = _compiled_text(
+        grow_tree_lossguide_rows, S((Fp, n), jnp.int32),
+        S((W, n), jnp.int32), S((n,), jnp.float32), S((n,), jnp.float32),
+        S((F, B), jnp.float32), S((2,), jnp.uint32),
+        cfg=GrowParams(max_depth=0,
+                       split=SplitParams(min_child_weight=100.0)),
+        max_leaves=255, n=n)
+    lines = _mosaic_lines_of(hlo)
+    calls = {}
+    for ln in lines:
+        name = re.match(r"(?:ROOT )?%([\w\-]+?)(?:\.\d+)? = ", ln).group(1)
+        calls.setdefault(name, []).append(
+            re.search(r'op_name="([^"]+)"', ln).group(1))
+    assert sorted(calls) == ["_node_count_pallas", "_node_hist_pallas",
+                             "_node_move_pallas"]
+    summary = _benchmark_summary()
+    for ln in lines:
+        assert not summary.is_level_kernel(ln.removeprefix("ROOT "))
+    for kernel in ("_node_count_pallas", "_node_move_pallas"):
+        (op,) = calls[kernel]
+        assert "level" not in kernel
+        scopes = [c for c in op.split("/") if c.startswith("xgb.")]
+        assert scopes[-1] == "xgb.node_partition", op
+    arena = f"s32[2,{hk._up(W + 3, hk._SUBLANES)},{n + nh.chunk_rows(n)}]"
+    copies = [ln for ln in hlo.splitlines()
+              if f"= {arena}" in ln and re.search(r"\bcopy(-start)?\(", ln)]
+    assert not copies, copies[:2]

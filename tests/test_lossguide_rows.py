@@ -23,6 +23,7 @@ from xgboost_tpu.tree.grow_lossguide import (
     finalize_alloc,
     grow_tree_lossguide,
     grow_tree_lossguide_rows,
+    node_work,
 )
 from xgboost_tpu.tree.param import SplitParams
 
@@ -98,13 +99,14 @@ def _grow(bins, g, h, cuts, kw, rows_path):
             kw["max_leaves"])
     if rows_path:
         binsT, packed = chip_bins(jnp.asarray(bins), cuts.shape[1])
-        tree, built = grow_tree_lossguide_rows(binsT, packed, *args,
-                                               n=len(g))
+        tree, rows = grow_tree_lossguide_rows(binsT, packed, *args,
+                                              n=len(g))
+        work = node_work(tree.left, tree.right, rows)
     else:
-        tree, built = grow_tree_lossguide(jnp.asarray(bins), *args), None
+        tree, work = grow_tree_lossguide(jnp.asarray(bins), *args), None
     keep, lv, delta = finalize_alloc(tree, jnp.float32(0.3),
                                      jnp.float32(kw["gamma"]))
-    return tree, built, np.asarray(keep), np.asarray(lv), np.asarray(delta)
+    return tree, work, np.asarray(keep), np.asarray(lv), np.asarray(delta)
 
 
 def _same_tree(a, b):
@@ -127,7 +129,7 @@ CASES = ["chain", "one_row", "max_depth", "mcw_stop", "gamma",
 def test_chip_path_grows_the_references_tree(mosaic_route, case):
     bins, g, h, cuts, kw = _data(case)
     ref, _, keep_r, lv_r, delta_r = _grow(bins, g, h, cuts, kw, False)
-    got, built, keep_c, lv_c, delta_c = _grow(bins, g, h, cuts, kw, True)
+    got, work, keep_c, lv_c, delta_c = _grow(bins, g, h, cuts, kw, True)
     _same_tree(ref, got)
     np.testing.assert_array_equal(keep_r, keep_c)
     np.testing.assert_allclose(delta_c, delta_r, atol=1e-5)
@@ -147,7 +149,8 @@ def test_chip_path_grows_the_references_tree(mosaic_route, case):
     if case.startswith("missing_"):
         dl = np.asarray(ref.default_left)[np.asarray(ref.left) >= 0]
         assert dl.any() and not dl.all()
-    # the rows the node histograms built: the smaller child's a split
+    # the rows the partitions streamed, the split nodes' own, and the rows
+    # the node histograms built, the smaller child's a split
     pos = np.asarray(ref.positions)
     left = np.asarray(ref.left)
     right = np.asarray(ref.right)
@@ -155,9 +158,9 @@ def test_chip_path_grows_the_references_tree(mosaic_route, case):
     for node in range(int(ref.n_nodes) - 1, -1, -1):
         size[node] = (np.sum(pos == node) if left[node] < 0
                       else size[left[node]] + size[right[node]])
-    want = sum(min(size[left[k]], size[right[k]])
-               for k in np.flatnonzero(left >= 0))
-    assert int(built) == want
+    split = np.flatnonzero(left >= 0)
+    want = sum(min(size[left[k]], size[right[k]]) for k in split)
+    assert work == (int(size[split].sum()), want)
 
 
 def test_chain_is_deep(mosaic_route):
@@ -209,26 +212,36 @@ def test_equal_gains_pop_the_lower_node_id():
     assert left[0] == 1 and left[1] == 3 and left[2] == -1
 
 
+def _arena(bins, g, h, B, perm=None):
+    """A job's arena as the chip path builds it (half 0 in row order, or
+    its rows in the order ``perm``), and the padded row count."""
+    binsT, packed = chip_bins(jnp.asarray(bins), B)
+    n_pad = binsT.shape[1]
+    arena = nh.build_arena(packed, jnp.asarray(g), jnp.asarray(h),
+                           n_pad + nh.chunk_rows(n_pad))
+    if perm is not None:
+        a = np.array(arena)
+        a[0, :, :len(perm)] = a[0][:, perm]
+        arena = jnp.asarray(a)
+    return arena, binsT
+
+
 def test_node_kernel_counts_and_sums(mosaic_route):
-    """The node kernel over gathered chunks: g and h within the hi/lo class,
-    the row count of every (feature, bin) exact, rows past the count left
-    out."""
+    """The node kernel over chunks of an arena range: g and h within the
+    hi/lo class, the row count of every (feature, bin) exact, rows past the
+    count left out."""
     rng = np.random.RandomState(2)
     n, F, B = 5000, 7, 16
     bins = rng.randint(0, B + 1, size=(n, F)).astype(np.uint8)
     g = rng.randn(n).astype(np.float32)
     h = rng.rand(n).astype(np.float32)
-    binsT, packed = chip_bins(jnp.asarray(bins), B)
-    rows = nh.node_rows(packed, jnp.asarray(g), jnp.asarray(h))
+    arena, binsT = _arena(bins, g, h, B, rng.permutation(n))
     ch = 2 * nh.NODE_TR  # a count over one chunk: the loop and its mask
-    order = jnp.concatenate([jnp.asarray(rng.permutation(n), jnp.int32),
-                             jnp.zeros((binsT.shape[1] + ch - n,),
-                                       jnp.int32)])
     start, count = 1234, 2777
-    hist = np.asarray(nh.node_hist(rows, order, jnp.int32(start),
+    hist = np.asarray(nh.node_hist(arena, jnp.int32(0), jnp.int32(start),
                                    jnp.int32(count), F=F, B=B,
                                    Fp=binsT.shape[0], ch=ch))
-    ids = np.asarray(order)[start:start + count]
+    ids = np.asarray(arena)[0, -(-F // 3) + 2, start:start + count]  # id
     tol_g, tol_h = U * np.abs(g[ids]).sum(), U * h[ids].sum()
     for f in range(F):
         b = bins[ids, f]
@@ -241,65 +254,109 @@ def test_node_kernel_counts_and_sums(mosaic_route):
                                                               minlength=B))
 
 
-def test_partition_is_stable_and_in_place():
-    """A leaf's range splits into its left rows then its right rows, each
-    in their order; the rows outside the range do not move."""
-    rng = np.random.RandomState(4)
-    n, F, B = 3000, 5, 16
+def _partitioned(arena, half, start, count, f, b, dl, B):
+    """numpy's stable partition of the range into the other half."""
+    a = np.asarray(arena).copy()
+    seg = a[half, :, start:start + count]
+    field = (seg[f // 3] >> (10 * (f % 3))) & 1023
+    go = np.where(field >= B, dl, field <= b)
+    a[1 - half, :, start:start + count] = np.concatenate(
+        [seg[:, go], seg[:, ~go]], axis=1)
+    return a, int(go.sum())
+
+
+def _junk_arena(rng, n, F, B):
+    """An arena of ``n`` rows of random bins, and random words everywhere
+    else (the other half's neighbours of a range among them)."""
     bins = rng.randint(0, B + 1, size=(n, F)).astype(np.uint8)
-    binsT, _ = chip_bins(jnp.asarray(bins), B)
-    perm = rng.permutation(n).astype(np.int32)
-    ch = nh.NODE_TR  # a range of several chunks
-    order = jnp.concatenate([jnp.asarray(perm),
-                             jnp.zeros((binsT.shape[1] + ch - n,),
-                                       jnp.int32)])
-    start, count, f, b = 500, 1700, 3, 7
-    for dl in (True, False):
-        seg = perm[start:start + count]
-        bv = bins[seg, f]
-        go = np.where(bv == B, dl, bv <= b)
-        new, _, n_left = nh.partition_node(
-            binsT, order, jnp.zeros((2 * order.shape[0],), jnp.int32),
-            jnp.int32(start), jnp.int32(count), jnp.int32(f), jnp.int32(b),
-            jnp.bool_(dl), B=B, ch=ch)
-        new = np.asarray(new)
-        want = np.concatenate([seg[go], seg[~go]])
-        assert int(n_left) == go.sum()
-        np.testing.assert_array_equal(new[start:start + count], want)
-        np.testing.assert_array_equal(new[:start], perm[:start])
-        np.testing.assert_array_equal(new[start + count:n],
-                                      perm[start + count:])
+    arena, _ = _arena(bins, rng.randn(n), rng.rand(n), B,
+                      rng.permutation(n))
+    a = np.array(arena)
+    a[1] = rng.randint(-2 ** 31, 2 ** 31 - 1, size=a[1].shape,
+                       dtype=np.int64)
+    return jnp.asarray(a)
 
 
-def test_partition_counts_its_rows_exactly_past_float32():
+@pytest.mark.parametrize("half", [0, 1])
+def test_partition_is_stable_and_in_place(mosaic_route, half):
+    """A leaf's range moves into the arena's other half, its left rows then
+    its right rows, each in their order, over several of the kernel's tiles
+    (and of its count's), from and to a mid-tile lane, either default
+    direction and either half; the rows outside the range do not move in
+    either half."""
+    rng = np.random.RandomState(4)
+    n, F, B = 6000, 5, 16
+    arena = _junk_arena(rng, n, F, B)
+    a = np.array(arena)
+    if half == 1:
+        a[[0, 1]] = a[[1, 0]]
+        arena = jnp.asarray(a)
+    # 4,900 rows: 20 move tiles, 3 count tiles, both ends mid-tile
+    for start, count, f, b, dl in ((300, 4900, 3, 7, True),
+                                   (300, 4900, 3, 7, False),
+                                   (2049, 130, 4, 2, True),
+                                   (5, 3, 1, 4, False)):
+        want, n_left = _partitioned(arena, half, start, count, f, b, dl, B)
+        got, got_left = nh.partition_node(
+            arena, jnp.int32(half), jnp.int32(start), jnp.int32(count),
+            jnp.int32(f), jnp.int32(b), jnp.bool_(dl), B=B)
+        assert 0 < n_left < count and int(got_left) == n_left
+        np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("rows", [1, nh.PART_TR, 2048])
+@pytest.mark.parametrize("side", ["left", "right"])
+def test_partition_of_one_side_only(mosaic_route, rows, side):
+    """Every row of the range goes one way (a range of one row, of one of
+    the kernel's tiles and of one of its count's, each from a mid-tile
+    lane): the one run is the range in its order, the other run empty."""
+    rng = np.random.RandomState(rows)
+    n, F, B = 3000, 4, 16
+    arena = _junk_arena(rng, n, F, B)
+    start = 77
+    # feature 0's bins in the range all <= 8, or all over 8 (missing, bin
+    # B, to the right)
+    a = np.array(arena)
+    lo, hi = (0, 9) if side == "left" else (9, B + 1)
+    a[0, 0, start:start + rows] = (a[0, 0, start:start + rows] & ~1023) \
+        | rng.randint(lo, hi, size=rows)
+    arena = jnp.asarray(a)
+    want, n_left = _partitioned(arena, 0, start, rows, 0, 8, False, B)
+    assert n_left == (rows if side == "left" else 0)
+    got, got_left = nh.partition_node(
+        arena, jnp.int32(0), jnp.int32(start), jnp.int32(rows), jnp.int32(0),
+        jnp.int32(8), jnp.bool_(False), B=B)
+    assert int(got_left) == n_left
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+def test_partition_counts_its_rows_exactly_past_float32(mosaic_route):
     """A range of 2**24 + 3 rows, 2**24 + 1 of them going left: a count
-    float32 cannot hold. The partition counts the rows in int32 and places
-    every row once."""
+    float32 cannot hold. The partition's count of the left rows is int32,
+    and exact."""
     n = (1 << 24) + 3
     right = np.array([7, n - 1])  # the two rows that go right
-    col = np.zeros((1, n), np.int32)
-    col[0, right] = 5
-    ch = 1 << 20
-    L = n + ch
-    order = jnp.concatenate([jnp.arange(n, dtype=jnp.int32),
-                             jnp.zeros((ch,), jnp.int32)])
-    new, _, n_left = jax.jit(lambda c, o: nh.partition_node(
-        c, o, jnp.zeros((2 * L,), jnp.int32), jnp.int32(0), jnp.int32(n),
-        jnp.int32(0), jnp.int32(0), jnp.bool_(False), B=8, ch=ch))(
-            jnp.asarray(col), order)
+    words = np.zeros((1, 8, n + 4096), np.int32)  # one half, one sublane group
+    words[0, 0, right] = 5
+    sc = nh._split_scalars(0, 0, n, 0, 0, False)  # feature 0, bin 0
+    n_left = nh._node_count_pallas(jnp.asarray(words), sc, B=8)[0]
     assert int(n_left) == n - 2 and float(np.float32(n - 2)) != n - 2
-    new = np.asarray(new)[:n]
-    np.testing.assert_array_equal(new[n - 2:], right)
-    np.testing.assert_array_equal(new[:n - 2],
-                                  np.delete(np.arange(n), right))
+
+
+def _counted(name):
+    from xgboost_tpu.observability import REGISTRY
+
+    fam = REGISTRY.get(name)
+    return sum(c.value for _, c in fam.series()) if fam else 0.0
 
 
 def test_a_job_takes_the_chip_path_and_counts_its_rows(mosaic_route):
     """Through ``Booster.update_many``: the scan chunk grows on the chip
-    path (the route says so), feeds ``node_hist_rows_total`` once a chunk,
-    and its forest predicts what the reference's does."""
+    path (the route says so), feeds ``node_hist_rows_total`` and
+    ``node_partition_rows_total`` once a chunk (every split's smaller
+    child's rows, and its own: the training rows walked through the
+    forest), and its forest predicts what the reference's does."""
     from xgboost_tpu.dispatch import last_decisions
-    from xgboost_tpu.observability import REGISTRY
 
     rng = np.random.RandomState(0)
     X = rng.randn(3000, 6).astype(np.float32)
@@ -309,15 +366,24 @@ def test_a_job_takes_the_chip_path_and_counts_its_rows(mosaic_route):
     params = {"objective": "binary:logistic", "grow_policy": "lossguide",
               "max_leaves": 31, "max_depth": 0, "eta": 0.3,
               "min_child_weight": 2}
-    fam = REGISTRY.get("node_hist_rows_total")
-    before = sum(c.value for _, c in fam.series()) if fam else 0.0
+    names = ("node_partition_rows_total", "node_hist_rows_total")
+    before = [_counted(k) for k in names]
     d = xgb.DMatrix(X, label=y)
     bst = xgb.Booster(params, [d])
     bst.update_many(d, 0, 4, chunk=2)
     assert last_decisions()["lossguide_step"] == "pallas"
-    fam = REGISTRY.get("node_hist_rows_total")
-    counted = sum(c.value for _, c in fam.series()) - before
-    assert 0 < counted < 4 * 3000 * 10
+    counted = [_counted(k) - b for k, b in zip(names, before)]
+    leaves = bst.predict(d, pred_leaf=True).astype(np.int64)
+    want = [0, 0]
+    for t, tree in enumerate(bst._gbm.model.trees):
+        left, right = tree.left_children, tree.right_children
+        size = np.bincount(leaves[:, t], minlength=len(left))
+        for k in range(len(left) - 1, -1, -1):  # children after parents
+            if left[k] >= 0:
+                size[k] = size[left[k]] + size[right[k]]
+                want[0] += size[k]
+                want[1] += min(size[left[k]], size[right[k]])
+    assert counted == want and 0 < want[1] < want[0]
     p_chip = bst.predict(d, output_margin=True)
     hk.use_pallas = lambda: False  # the plain reference, for comparison
     d2 = xgb.DMatrix(X, label=y)

@@ -632,8 +632,9 @@ def _round_in_one_pass(cfg: GrowParams, trees: int) -> bool:
 
 def _lossguide_on_chip(cfg: GrowParams, F: int, B: int) -> bool:
     """Whether a lossguide job's trees take the chip path
-    (``grow_tree_lossguide_rows``: rows grouped by leaf, the smaller
-    child's histogram by the Mosaic node kernel), resolved through the
+    (``grow_tree_lossguide_rows``: the rows' data grouped by leaf and
+    moved by a Mosaic partition, the smaller child's histogram by the
+    Mosaic node kernel), resolved through the
     ``lossguide_step`` row of the dispatch table. A TPU job that cannot
     (categorical splits, interaction constraints, a mesh, a width the node
     kernel does not take) stays on the plain reference, which passes over
@@ -774,7 +775,7 @@ def _scan_rounds_lossguide_impl(bins, label, weight, m_cur, iters, cut_vals,
     the stacked outputs (only the delta uses them). ``rows_path``: the
     trees grow on the chip path (``grow_tree_lossguide_rows``, its bins
     widened and packed once a chunk, here), and the stacked outputs end
-    with each tree's node-histogram rows; else on the plain reference
+    with each tree's node row counts; else on the plain reference
     (``grow_tree_lossguide``)."""
     from ..tree.grow_lossguide import (
         chip_bins,
@@ -1731,11 +1732,12 @@ class GBTree:
     def _scan_lossguide(self, binned, obj, label, weight, margin,
                         start_iteration, num_rounds, feature_weights):
         """A lossguide chunk, in the three host steps of the depthwise
-        chunk's. The chip path's trees report the rows their node
-        histograms were asked to build: ``node_hist_rows_total`` is fed
-        from that output once a chunk, after ``chunk.commit`` (a read that
-        waits for the chunk, so the next chunk's dispatch does not overlap
-        this one's run)."""
+        chunk's. The chip path's trees report every node's row count:
+        ``node_hist_rows_total`` (the rows their node histograms were asked
+        to build) and ``node_partition_rows_total`` (the rows their
+        partitions streamed) are fed from it once a chunk, after
+        ``chunk.commit`` (a read that waits for the chunk, so the next
+        chunk's dispatch does not overlap this one's run)."""
         tp = self.train_param
         with _trace.span("chunk.prepare"):
             cfg = self._grow_params()
@@ -1766,13 +1768,21 @@ class GBTree:
                 tp.eta, tp.gamma, tp.max_depth, cat_mask=None,
             )
         if rows_path:
+            from ..tree.grow_lossguide import node_work
+
             # waits for the chunk, outside the commit span: its time is the
             # device's, not the commit's
+            streamed, built = node_work(stacked[0].left, stacked[0].right,
+                                        stacked[3])
             _REGISTRY.counter(
                 "node_hist_rows_total",
                 "Rows the leaf-wise node histograms were asked to build "
                 "(the smaller children's)",
-            ).inc(int(np.asarray(stacked[3]).sum(dtype=np.int64)))
+            ).inc(built)
+            _REGISTRY.counter(
+                "node_partition_rows_total",
+                "Rows the leaf-wise partitions streamed (the split leaves')",
+            ).inc(streamed)
         return m_cur
 
     # ------------------------------------------------------------------

@@ -31,11 +31,12 @@ Two growers share steps 1, 2 and 4 and differ in step 3:
   one ``segment_sum`` pass over all n rows, float32, rows outside the split
   leaf masked. It departs from ``driver.h`` in nothing.
 - ``grow_tree_lossguide_rows``, the chip path (numerical, one device):
-  the rows are kept grouped by open leaf (``node_hist``), the split leaf's
-  range is partitioned in place, the SMALLER child's histogram is built
-  over its own rows by a Mosaic kernel, and the sibling's is parent minus
-  built. The partition and the histogram follow the split leaf's rows, not
-  n. Besides, a step rewrites every row's leaf id (``positions``, which
+  the rows' data (packed bins, g, h, id) are kept grouped by open leaf in
+  an arena (``node_hist``), a Mosaic kernel moves the split leaf's range
+  into its two children's, the SMALLER child's histogram is built over its
+  own rows by a second, and the sibling's is parent minus built. The
+  partition and the histogram follow the split leaf's rows, not n.
+  Besides, a step rewrites every row's leaf id (``positions``, which
   ``leaf_delta`` reads) by one elementwise pass over n rows that reads the
   split feature's row of the bins: 12 bytes a row, no gather and no
   scatter, where deriving the ids once a tree from the row order would
@@ -67,7 +68,7 @@ from .grow import (
 from .param import RT_EPS, calc_weight
 
 __all__ = ["AllocTree", "chip_bins", "grow_tree_lossguide",
-           "grow_tree_lossguide_rows"]
+           "grow_tree_lossguide_rows", "node_work"]
 
 _INF = float(np.inf)
 
@@ -479,7 +480,7 @@ def _eval_form(hist, G, H, count):
 @partial(jax.jit, static_argnames=("cfg", "max_leaves", "n"))
 def grow_tree_lossguide_rows(
     binsT: jax.Array,  # [Fp, n_pad] int32 feature-major, missing == B
-    packed: jax.Array,  # [n, ceil(F / 3)] int32 (node_hist.pack_bins)
+    packed: jax.Array,  # [ceil(F / 3), n] int32 (node_hist.pack_bins)
     grad: jax.Array,  # [n]
     hess: jax.Array,
     cut_values: jax.Array,  # [F, B]
@@ -490,10 +491,10 @@ def grow_tree_lossguide_rows(
     feature_weights: Optional[jax.Array] = None,
 ):
     """The chip path's tree (see the module's docstring): the same
-    ``AllocTree`` the reference grows, and the rows its node histograms
-    were asked to build (the smaller children's, summed over the splits;
-    int32). ``binsT`` and ``packed`` come from ``chip_bins``, once a
-    chunk."""
+    ``AllocTree`` the reference grows, and every node's row count ``[M]``
+    int32 (``node_work`` makes the rows the partitions streamed and the node
+    histograms built of it). ``binsT`` and ``packed`` come from
+    ``chip_bins``, once a chunk."""
     from . import node_hist as nh
 
     Fp, n_pad = binsT.shape
@@ -506,41 +507,41 @@ def grow_tree_lossguide_rows(
                      ((0, 0), (0, n_pad - n)))
         h_root = nh._node_hist_pallas(binsT, gh, F=F, B=B)  # every row
         G0, H0 = jnp.sum(grad), jnp.sum(hess)
-    rows = nh.node_rows(packed, grad, hess)
     q = tr.root(tr.empty_queue(M), _eval_form(h_root, G0, H0, n)[None], G0,
                 H0)
-    # every leaf's rows: order[start : start + count]; the tail of ``order``
-    # is room for a chunk that runs past the last row
+    # every leaf's rows: arena[half, :, start : start + count]; the lanes
+    # run a chunk past the rows, room for a histogram's last chunk
     ch = nh.chunk_rows(n_pad)
-    order = jnp.concatenate([jnp.arange(n, dtype=jnp.int32),
-                             jnp.zeros((n_pad + ch - n,), jnp.int32)])
-    spare = jnp.zeros((2 * order.shape[0],), jnp.int32)
+    with jax.named_scope("xgb.node_partition"):
+        arena = nh.build_arena(packed, grad, hess, n_pad + ch)
+    half = jnp.zeros((M,), jnp.int32)
     start = jnp.zeros((M,), jnp.int32)
     count = jnp.zeros((M,), jnp.int32).at[0].set(n)
     hists = jnp.zeros((M, F, 3, B), jnp.float32).at[0].set(h_root)
 
     def body(state):
-        t, q, pos, order, spare, start, count, hists, built_rows = state
+        t, q, pos, arena, half, start, count, hists = state
         q, s = tr.pop(q, cut_values)
-        s0, c = start[s.node], count[s.node]
+        s0, c, src = start[s.node], count[s.node], half[s.node]
         with jax.named_scope("xgb.node_partition"):
             # every row's leaf, in row order: one elementwise pass over the
-            # split feature's row of the bins (no gather, no scatter)
-            bv = jax.lax.dynamic_index_in_dim(binsT, s.f, 0, False)[:n]
+            # split feature's row of the bins (no gather, no scatter; both
+            # [1, n], so that the pass is one fusion)
+            bv = jax.lax.dynamic_slice_in_dim(binsT, s.f, 1)[:, :n]
             goleft = jnp.where(bv >= B, s.default_left, bv <= s.b)
             pos = jnp.where(pos == s.node,
                             jnp.where(goleft, s.l_id, s.r_id), pos)
-            order, spare, n_left = nh.partition_node(
-                binsT, order, spare, s0, c, s.f, s.b, s.default_left, B=B,
-                ch=ch)
+            arena, n_left = nh.partition_node(
+                arena, src, s0, c, s.f, s.b, s.default_left, B=B)
         n_right = c - n_left
         kids = jnp.stack([s.l_id, s.r_id])
+        half = half.at[kids].set(1 - src)
         start = start.at[kids].set(jnp.stack([s0, s0 + n_left]))
         count = count.at[kids].set(jnp.stack([n_left, n_right]))
         small_left = n_left <= n_right
-        small = jnp.where(small_left, n_left, n_right)
-        built = nh.node_hist(rows, order,
-                             jnp.where(small_left, s0, s0 + n_left), small,
+        built = nh.node_hist(arena, 1 - src,
+                             jnp.where(small_left, s0, s0 + n_left),
+                             jnp.where(small_left, n_left, n_right),
                              F=F, B=B, Fp=Fp, ch=ch)
         with jax.named_scope("xgb.node_hist"):
             derived = hists[s.node] - built
@@ -552,23 +553,38 @@ def grow_tree_lossguide_rows(
                 _eval_form(h_r, q.node_g[s.r_id], q.node_h[s.r_id],
                            n_right)])
         q = tr.push_children(q, s, hist2)
-        return (t + 1, q, pos, order, spare, start, count, hists,
-                built_rows + small)
+        return t + 1, q, pos, arena, half, start, count, hists
 
-    _, q, pos, *_, built_rows = jax.lax.while_loop(
+    _, q, pos, _, _, _, count, _ = jax.lax.while_loop(
         lambda st: tr.more(st[1], st[0], max_leaves), body,
-        (jnp.int32(0), q, jnp.zeros((n,), jnp.int32), order, spare, start,
-         count, hists, jnp.int32(0)))
-    return tr.tree(q, pos), built_rows
+        (jnp.int32(0), q, jnp.zeros((1, n), jnp.int32), arena, half, start,
+         count, hists))
+    return tr.tree(q, pos[0]), count
+
+
+def node_work(left, right, rows):
+    """(rows the partitions streamed, rows the node histograms built) of
+    allocation-ordered trees: ``left``, ``right`` and every node's row
+    count ``rows`` (``grow_tree_lossguide_rows``'s), any leading shape. A
+    split streams its node's rows and builds its smaller child's; the
+    root's histogram over all n is in neither. Summed in int64 on the
+    host."""
+    left, right = np.asarray(left), np.asarray(right)
+    rows = np.asarray(rows).astype(np.int64)
+    split = left >= 0
+    at = lambda ids: np.take_along_axis(  # noqa: E731
+        rows, np.maximum(ids, 0), axis=-1)
+    smaller = np.minimum(at(left), at(right))
+    return int(rows[split].sum()), int(smaller[split].sum())
 
 
 def chip_bins(bins, B: int):
     """A job's narrow bins ``[n, F]`` as the chip path reads them: the
-    root's and the partition's feature-major int32 ``[Fp, n_pad]`` (the
-    columns padded to whole sublanes and the rows to the node kernel's tile
-    with the missing bin: ``hist_kernel._feature_major``'s form), and the
-    node gathers' rows packed three bins to a word (``node_hist.pack_bins``).
-    """
+    root's histogram's and the rows' leaves' feature-major int32 ``[Fp,
+    n_pad]`` (the columns padded to whole sublanes and the rows to the node
+    kernel's tile with the missing bin: ``hist_kernel._feature_major``'s
+    form), and the arena's bin channels, three bins packed to a word
+    (``node_hist.pack_bins``, ``[ceil(F / 3), n]``)."""
     from . import hist_kernel as hk
     from . import node_hist as nh
 
@@ -576,4 +592,4 @@ def chip_bins(bins, B: int):
     b32 = jnp.pad(bins.astype(jnp.int32),
                   ((0, nh.padded_rows(n) - n), (0, 0)), constant_values=B)
     binsT = hk._feature_major(b32, hk._SUBLANES, B)
-    return binsT, nh.pack_bins(binsT, F)[:n]
+    return binsT, nh.pack_bins(binsT, F)[:, :n]
