@@ -18,6 +18,9 @@ path that runs off the chip. The last line of stdout is one JSON object
 ``--stages leafwise`` runs the leaf-wise chip path alone (the node
 kernel, the arena partition and a 255-leaf tree at
 ``higgs_leafwise_train``'s 10.5M x 28, bin256, and at 8,192 rows).
+``--stages categorical`` runs categorical splits alone: the level kernels
+and the routing with a categorical decision table against
+``fused_level_xla``, and a categorical job's scan chunks.
 
 ``--rehearse`` runs the same stages at a tiny size with the Pallas
 kernels in interpret mode, so the command can be debugged before chip
@@ -42,8 +45,8 @@ import threading
 import time
 from dataclasses import dataclass
 
-STAGES = ("kernels", "wide", "leafwise", "train", "predict", "serve",
-          "four_chips", "reference")
+STAGES = ("kernels", "wide", "leafwise", "categorical", "train", "predict",
+          "serve", "four_chips", "reference")
 
 _TAG = ""  # set to the rehearsal label by main()
 
@@ -79,6 +82,9 @@ class Sizes:
     auc_floor256: float
     # (rows, columns, bins, leaves) of the leaf-wise step's shapes
     leafwise: tuple = ()
+    # (kernel rows, training rows, bins, rounds a chunk) of the
+    # categorical stage
+    categorical: tuple = ()
 
 
 FULL = Sizes(rows=1_000_000, cols=50, kernel_rows=65_536, rounds=20,
@@ -91,12 +97,14 @@ FULL = Sizes(rows=1_000_000, cols=50, kernel_rows=65_536, rounds=20,
              # 0.8612 and 0.7687 on the chip (CHANGES.md, PR 28)
              auc_floor64=0.84, auc_floor256=0.76,
              # the higgs_leafwise_train cell's, and a small one
-             leafwise=((10_500_000, 28, 256, 255), (8192, 28, 256, 255)))
+             leafwise=((10_500_000, 28, 256, 255), (8192, 28, 256, 255)),
+             categorical=(65_536, 262_144, 256, 5))
 TINY = Sizes(rows=4096, cols=8, kernel_rows=1024, rounds=3, rounds256=2,
              sub_rows=2048, forest_rows=1024, forest_trees=12,
              predict_rows=1500, serve_sizes=(1, 16, 256), serve_requests=3,
              mesh_rounds=2, auc_floor64=0.75, auc_floor256=0.70,
-             leafwise=((4096, 8, 32, 31), (2048, 8, 32, 63)))
+             leafwise=((4096, 8, 32, 31), (2048, 8, 32, 63)),
+             categorical=(2048, 4096, 32, 2))
 
 DEPTH = 6
 SEED = 42
@@ -643,6 +651,161 @@ def stage_leafwise(sz: Sizes) -> None:
 
 
 # ---------------------------------------------------------------------------
+# stage: categorical splits (the acs_employment_cat_train cell's columns)
+# ---------------------------------------------------------------------------
+
+# the categories of the 15 coded columns of folktables' ACSEmployment task;
+# the 16th column is a numerical age
+CAT_CARDS = (24, 18, 9, 9, 5, 5, 5, 5, 3, 3, 2, 2, 2, 2, 2)
+
+
+def cat_data(rows: int, seed: int):
+    """(X, y): 15 coded columns at ``CAT_CARDS``, an age of 16-90, and a
+    label of per-category effects that are not monotone in the code; 2%
+    of the third column missing."""
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    cols = [rng.integers(0, c, rows) for c in CAT_CARDS]
+    age = rng.integers(16, 91, rows)
+    logit = sum(0.6 * rng.standard_normal(c)[x]
+                for c, x in zip(CAT_CARDS, cols))
+    logit = logit + 0.05 * (age - 40) - 0.0012 * (age - 40) ** 2
+    y = (rng.random(rows) < 1 / (1 + np.exp(-logit))).astype(np.float32)
+    X = np.column_stack(cols + [age]).astype(np.float32)
+    X[rng.random(rows) < 0.02, 2] = np.nan
+    return X, y
+
+
+def stage_categorical(sz: Sizes, xgb, routes: dict) -> None:
+    """Categorical splits on the chip path. The level kernels (the
+    in-kernel construction and the streaming kernel) and the routing
+    kernel, each with a ``[Kp, 5 + B]`` decision table of category sets,
+    compiled, against ``fused_level_xla``: identical positions, histograms
+    in the hi/lo class. Then a categorical job through
+    ``Booster.update_many``: its chunks must run as scan programs, keep
+    one-hot and partition splits, and leave the margin a walk of the
+    saved trees gives (the rows went where the category sets send them),
+    also after the model's JSON round trip."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from xgboost_tpu.data.quantile import BinnedMatrix
+    from xgboost_tpu.observability import REGISTRY
+    from xgboost_tpu.tree import hist_kernel as hk
+
+    kn, rows, B, chunk = sz.categorical
+    F = len(CAT_CARDS) + 1
+    X, y = cat_data(rows, SEED)
+    oracle = _xla_oracle()
+    rng = np.random.RandomState(SEED)
+    binned = BinnedMatrix.from_dense(X[:kn], max_bin=B,
+                                     categorical=range(len(CAT_CARDS)))
+    bins = binned.bins
+    bins_np = np.asarray(bins)
+    bins32 = bins.astype(jnp.int32)
+    binsT = hk._feature_major(bins32, hk._SUBLANES, B)
+    onehot = hk.build_onehot(bins, B=B)
+    gh_np = rng.randn(2, kn).astype(np.float32)
+    gh_np[1] = np.abs(gh_np[1]) + 0.05
+    gh = jnp.asarray(gh_np)
+    before = _decisions()
+    for d in (1, 4):
+        K, Kp = 1 << d, (1 << d) >> 1
+        prev = (1 << (d - 1)) - 1
+        pos_np = (prev + rng.randint(0, Kp, size=(1, kn))).astype(np.int32)
+        feat = rng.randint(0, F, Kp)
+        is_cat = feat < len(CAT_CARDS)
+        ptab_np = np.concatenate([np.stack([
+            (rng.rand(Kp) < 0.85).astype(np.float32),
+            feat.astype(np.float32),
+            rng.randint(0, B - 1, Kp).astype(np.float32),
+            rng.randint(0, 2, Kp).astype(np.float32),
+            is_cat.astype(np.float32)], axis=1),
+            ((rng.rand(Kp, B) < 0.4) & is_cat[:, None]).astype(np.float32)],
+            axis=1)  # [Kp, 5 + B]
+        pos, ptab = jnp.asarray(pos_np), jnp.asarray(ptab_np)
+        kw = dict(K=K, Kp=Kp, B=B, d=d)
+        pos_x, hist_x = oracle(bins_np, pos_np, gh_np, ptab_np, **kw)
+        _, habs = oracle(bins_np, pos_np, np.abs(gh_np), ptab_np, **kw)
+        tol = habs * 2.0 ** -15 + 1e-6
+        tr = hk._hoist_tr(F * B, K, F, B, ptab_np.shape[1])
+        check(tr > 0 and kn % tr == 0,
+              f"categorical d={d}: no hoisted row tile at {K} nodes")
+        cands = {
+            "construct": lambda: hk._fused_level_pallas(
+                binsT, pos, gh, ptab, F=F, **kw),
+            "hoisted": lambda: hk._hoisted_level_pallas(
+                binsT, onehot, pos, gh, ptab, F=F, tr=tr, **kw),
+        }
+        for name, fn in cands.items():
+            (pos_p, hist_p), cold = _timed(fn)
+            check(np.array_equal(np.asarray(pos_p), pos_x),
+                  f"categorical d={d} {name}: pos differs from XLA")
+            err = np.abs(np.asarray(hist_p) - hist_x)
+            check(bool((err <= tol).all()),
+                  f"categorical d={d} {name}: histogram off by "
+                  f"{float((err - tol).max()):.3e} beyond tolerance")
+            say(f"  categorical d={d} {name}: {cold:.2f}s  pos identical, "
+                f"max|dhist| {float(err.max()):.2e}")
+        pos_r = hk.partition_apply(bins32, pos, ptab, Kp=Kp, B=B, d=d,
+                                   pallas=True)
+        check(np.array_equal(np.asarray(pos_r), pos_x),
+              f"categorical d={d} route_rows: pos differs from XLA")
+        say(f"  categorical d={d} route_rows: pos identical")
+    del binned, bins, bins32, binsT, onehot
+
+    ft = ["c"] * len(CAT_CARDS) + ["q"]
+    params = {"objective": "binary:logistic", "tree_method": "tpu_hist",
+              "max_depth": DEPTH, "max_bin": B, "eta": 0.3,
+              "max_cat_to_onehot": 4, "eval_metric": "auc", "seed": SEED}
+    dtrain = xgb.DMatrix(X, label=y, feature_types=ft)
+    bst = xgb.Booster(params, [dtrain])
+
+    def count(name, **labels):
+        fam = REGISTRY.get(name)
+        if fam is None:
+            return 0
+        return sum(getattr(c, "value", None) or getattr(c, "count", 0)
+                   for lab, c in fam.series()
+                   if all(lab.get(k) == v for k, v in labels.items()))
+
+    chunks0 = count("scan_chunk_seconds")
+    kinds0 = {k: count("cat_splits_total", kind=k)
+              for k in ("onehot", "partition")}
+    (_, cold) = _timed(lambda: bst.update_many(dtrain, 0, chunk,
+                                               chunk=chunk))
+    (_, warm) = _timed(lambda: bst.update_many(dtrain, chunk, chunk,
+                                               chunk=chunk))
+    check(count("scan_chunk_seconds") - chunks0 == 2,
+          "categorical: the chunks did not run as scan programs")
+    _check_routes("categorical", before, routes,
+                  must_see=("level_hist", "level_partition", "onehot_build"))
+    kinds = {k: count("cat_splits_total", kind=k) - v
+             for k, v in kinds0.items()}
+    check(kinds["onehot"] > 0 and kinds["partition"] > 0,
+          f"categorical: split kinds {kinds}")
+    cached = np.asarray(bst.predict(dtrain, output_margin=True))
+    dfresh = xgb.DMatrix(X, feature_types=ft)
+    walked = np.asarray(bst.predict(dfresh, output_margin=True))
+    err = float(np.abs(cached - walked).max())
+    check(err <= 1e-4, f"categorical: the chunks' margin is {err:.3e} "
+                       "from a walk of the saved trees")
+    loaded = xgb.Booster(model_file=bytearray(bst.save_raw("json")))
+    err_l = float(np.abs(np.asarray(loaded.predict(dfresh,
+                                                   output_margin=True))
+                         - walked).max())
+    check(err_l <= 1e-6, f"categorical: the JSON round trip moved the "
+                         f"margin by {err_l:.3e}")
+    auc = _auc(bst, dtrain)
+    check(auc > 0.7, f"categorical: training AUC {auc:.4f}")
+    say(f"  categorical {rows}x{F} bin{B}: two {chunk}-round scan chunks "
+        f"cold {cold:.2f}s warm {warm:.2f}s, splits {kinds}, margin "
+        f"{err:.2e} from the walk, JSON round trip {err_l:.2e}, training "
+        f"AUC {auc:.4f}")
+
+
+# ---------------------------------------------------------------------------
 # stage: train at full width
 # ---------------------------------------------------------------------------
 
@@ -1118,6 +1281,8 @@ def main(argv=None) -> int:
             stage_wide(sz, routes)
         elif stage == "leafwise":
             stage_leafwise(sz)
+        elif stage == "categorical":
+            stage_categorical(sz, xgb, routes)
         elif stage == "train":
             with _pinned(walk_pin):
                 stage_train(sz, xgb, X, y, routes, args.rehearse)

@@ -48,13 +48,16 @@ def test_default_preference_order():
         platform="cpu", pallas=False, has_cats=False, sharded=False,
         depth=6))
     assert (dec.impl, dec.reason) == ("scanned", "preferred")
-    # categorical / sharded / pallas contexts keep the unrolled loop
-    for veto in (dict(has_cats=True), dict(sharded=True),
-                 dict(pallas=True)):
+    # sharded / pallas contexts keep the unrolled loop; a categorical
+    # table is carried at the deepest level's width like a numerical one
+    for veto in (dict(sharded=True), dict(pallas=True)):
         base = dict(platform="cpu", pallas=False, has_cats=False,
                     sharded=False, depth=6)
         base.update(veto)
         assert dispatch.resolve("depth_scan", Ctx(**base)).impl == "unrolled"
+    assert dispatch.resolve("depth_scan", Ctx(
+        platform="cpu", pallas=False, has_cats=True, sharded=False,
+        depth=6)).impl == "scanned"
     # level_hist on cpu: native when the FFI library builds, else xla
     dec = dispatch.resolve("level_hist", _lh_ctx())
     assert dec.impl in ("native", "xla")

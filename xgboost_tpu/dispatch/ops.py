@@ -192,10 +192,11 @@ set_report_ctx("level_update", lambda: Ctx(platform=_platform()))
 def _scanned_applicable(ctx: Ctx) -> bool:
     """The fused depth scan runs where its fixed-width trick is sound:
     off the pallas path (Mosaic kernels specialize per level width by
-    design), no categorical tables (level-shaped widening), in-process
-    (the unrolled loop is the proven shard_map path), depth >= 1."""
+    design), in-process (the unrolled loop is the proven shard_map path),
+    depth >= 1. A categorical job's table is carried at the deepest
+    level's width like a numerical one's, its category sets beside the
+    decisions (``grow_fused._init_state``)."""
     return (not ctx.get("pallas", False)
-            and not ctx.get("has_cats", False)
             and not ctx.get("sharded", False)
             and int(ctx.get("depth", 0)) >= 1)
 

@@ -106,16 +106,22 @@ class _PendingChunk:
     as-is and trees are carved out lazily, on host, one bulk transfer per
     field per chunk."""
 
-    __slots__ = ("fields", "R", "K", "eta", "max_depth", "_host")
+    __slots__ = ("fields", "R", "K", "eta", "max_depth", "cat_mask",
+                 "_host")
 
     FIELDS = ("keep", "feature", "split_bin", "split_cond", "default_left",
               "node_weight", "loss_chg", "node_h", "leaf_value")
 
     def __init__(self, stacked: GrownTree, R: int, K: int, eta: float,
-                 max_depth: int):
+                 max_depth: int, cat_mask=None):
         self.fields = {f: getattr(stacked, f) for f in self.FIELDS}
+        # a categorical job's chunk also carries each split's right-going
+        # category set, [R, K, N, B]
+        if cat_mask is not None:
+            self.fields["cat_set"] = stacked.cat_set
         self.R, self.K = R, K
         self.eta, self.max_depth = eta, max_depth
+        self.cat_mask = cat_mask
         self._host = None
 
     @property
@@ -157,6 +163,10 @@ class _ChunkRef:
     @property
     def n_nodes(self) -> int:
         return self.chunk.n_nodes
+
+    @property
+    def cat_mask(self):
+        return self.chunk.cat_mask
 
 
 class _PendingAllocChunk:
@@ -467,19 +477,22 @@ class GBTreeModel:
 
     def add_device_chunk(self, stacked: GrownTree, R: int,
                          groups_per_round, eta: float,
-                         max_depth: int) -> None:
+                         max_depth: int, cat_mask=None) -> _PendingChunk:
         """Append a whole scan-chunk ([R, T, N] stacked heap arrays, T
         trees per round) as R*T trees WITHOUT slicing per-tree device
         arrays (see _PendingChunk). ``groups_per_round`` lists each tree
         slot's output group in the per-round order (group-major, parallel
-        trees inner — matching boost_one_round / BoostNewTrees)."""
+        trees inner — matching boost_one_round / BoostNewTrees).
+        ``cat_mask`` ([F] bool) where the job has categorical columns: the
+        trees then keep their category sets. Returns the chunk."""
         T = len(groups_per_round)
-        chunk = _PendingChunk(stacked, R, T, eta, max_depth)
+        chunk = _PendingChunk(stacked, R, T, eta, max_depth, cat_mask)
         for r in range(R):
             for idx, grp in enumerate(groups_per_round):
                 self._entries.append(_ChunkRef(chunk, r, idx))
                 self.tree_info.append(int(grp))
         self._stacked = None
+        return chunk
 
     def add_device_alloc_chunk(self, alloc_stacked, keep, leaf_value,
                                R: int, K: int, eta: float, gamma: float,
@@ -602,6 +615,30 @@ def _cat_cfg(cfg: GrowParams, binned, tp) -> Tuple[GrowParams, Any]:
     return cfg, cfg.cat_mask_np(binned.n_features)
 
 
+def _count_splits(chunk: _PendingChunk, cfg: GrowParams, F: int) -> None:
+    """A categorical chunk's splits, by kind, summed on the host from the
+    program's output once a chunk: ``splits_total`` (every split the
+    chunk's trees keep) and ``cat_splits_total{kind}``, ``onehot`` for a
+    column below ``max_cat_to_onehot`` categories, ``partition`` for one
+    at or above it. The read waits for the chunk and fetches the arrays
+    its trees are materialized from (``_PendingChunk.host``)."""
+    h = chunk.host()
+    kind = np.zeros(F, np.int8)
+    kind[list(cfg.categorical)] = 1
+    kind[list(cfg.cat_partition)] = 2
+    split_kind = kind[h["feature"][h["keep"]]]
+    _REGISTRY.counter(
+        "splits_total",
+        "Splits the trees of a categorical job's scan chunks keep",
+    ).inc(int(split_kind.size))
+    fam = _REGISTRY.counter(
+        "cat_splits_total",
+        "Categorical splits of a job's scan chunks, by kind: onehot (one "
+        "category right) or partition (a sorted prefix of categories right)")
+    fam.labels(kind="onehot").inc(int((split_kind == 1).sum()))
+    fam.labels(kind="partition").inc(int((split_kind == 2).sum()))
+
+
 def round_seed_py(seed: int, iteration: int, k: int = 0,
                   ptree: int = 0) -> int:
     """Per-tree RNG seed (python-int path). The traced twin
@@ -626,8 +663,9 @@ def _round_in_one_pass(cfg: GrowParams, trees: int) -> bool:
     the rows (``grow_fused.grow_trees_one_pass``), in place of one after
     another: where there is more than one and the Mosaic level kernels
     run. Observed in the job, chosen by nobody; a job that grows one tree
-    a round traces the class loop's program and nothing else."""
-    return trees > 1 and _pallas_flag(cfg)
+    a round traces the class loop's program and nothing else, and so does
+    a categorical job (its trees' routing tables carry category sets)."""
+    return trees > 1 and _pallas_flag(cfg) and not cfg.has_categorical
 
 
 def _lossguide_on_chip(cfg: GrowParams, F: int, B: int) -> bool:
@@ -937,7 +975,8 @@ def _materialize_chunk_refs(entries: List[Any]) -> None:
             h["keep"][r, k], h["feature"][r, k], h["split_cond"][r, k],
             h["default_left"][r, k], h["node_weight"][r, k],
             h["loss_chg"][r, k], h["node_h"][r, k], eta=e.chunk.eta,
-            split_bin=h["split_bin"][r, k],
+            split_bin=h["split_bin"][r, k], cat_features=e.cat_mask,
+            cat_set=h["cat_set"][r, k] if e.cat_mask is not None else None,
         )
 
 
@@ -1575,9 +1614,14 @@ class GBTree:
     def scan_rounds_supported(self, binned, obj, n_groups: int) -> bool:
         """Whether ``boost_rounds_scan`` can run: the fused depthwise
         path with a scan-safe (jax-traceable, groupless-state) objective;
-        one tree per output group per round."""
+        one tree per output group per round. A matrix with categorical
+        columns is admitted depthwise on one device; lossguide or a mesh
+        keeps it on the per-round path."""
         tp = self.train_param
         npt_ok = self.gbtree_param.num_parallel_tree == 1 or (
+            tp.grow_policy != "lossguide" and not _mesh_active()
+        )
+        cats_ok = not tuple(getattr(binned, "categorical", ())) or (
             tp.grow_policy != "lossguide" and not _mesh_active()
         )
         return (
@@ -1585,7 +1629,7 @@ class GBTree:
             and npt_ok
             and not self._is_update_process
             and getattr(obj, "scan_safe", False)
-            and not tuple(getattr(binned, "categorical", ()))
+            and cats_ok
             and not getattr(binned, "is_paged", False)
             and (tp.grow_policy != "lossguide" or not _mesh_active())
         )
@@ -1645,7 +1689,9 @@ class GBTree:
         from ..parallel.mesh import current_mesh, shard_rows
 
         tp = self.train_param
-        cfg = self._grow_params()
+        # a categorical job's one-hot / partition columns (the same rule
+        # as the per-round path's); a numerical job's config is unchanged
+        cfg, cat_mask = _cat_cfg(self._grow_params(), binned, tp)
         mesh = current_mesh()
         use_mesh = mesh is not None and mesh.devices.size > 1
         n = binned.n_rows
@@ -1725,9 +1771,14 @@ class GBTree:
                 # back to THIS process's rows (identity single-process):
                 # the margin cache, evals, and predictions are process-local
                 m_pad = local_rows(m_pad)
-            self.model.add_device_chunk(stacked, num_rounds, groups,
-                                        tp.eta, tp.max_depth)
-            return m_pad[:n]
+            chunk = self.model.add_device_chunk(stacked, num_rounds, groups,
+                                                tp.eta, tp.max_depth,
+                                                cat_mask)
+        if cat_mask is not None:
+            # waits for the chunk, outside the commit span, as the
+            # lossguide chunk's row counters do
+            _count_splits(chunk, cfg, binned.n_features)
+        return m_pad[:n]
 
     def _scan_lossguide(self, binned, obj, label, weight, margin,
                         start_iteration, num_rounds, feature_weights):

@@ -272,9 +272,10 @@ class Booster:
         ``gbm/gbtree.py:boost_rounds_scan``) — same trees as calling
         ``update`` per round (identical RNG keys). Falls back to the per-round path whenever the
         configuration is outside the scan-safe envelope (ranking/survival
-        objectives, DART, lossguide under a mesh, categorical, external
-        memory, custom objective); multiclass (one tree per group per
-        scanned round), lossguide on one device and depthwise mesh
+        objectives, DART, lossguide under a mesh, categorical under
+        lossguide or a mesh, external memory, custom objective); multiclass
+        (one tree per group per scanned round), lossguide on one device,
+        categorical columns depthwise on one device and depthwise mesh
         training (the chunk scan runs inside one shard_map) are
         supported."""
         self._configure()

@@ -139,7 +139,20 @@ def _build_key(src: str, flags: list) -> str:
     return h.hexdigest()
 
 
-def _compile(src: str, lib_path: str, extra: list, timeout: int = 120) -> bool:
+def _built(lib_path: str, stamp: str, key: str) -> bool:
+    """Whether ``lib_path`` exists and its stamp carries ``key``."""
+    import json
+
+    if not os.path.exists(lib_path):
+        return False
+    try:
+        with open(stamp, encoding="utf-8") as f:
+            return json.load(f).get("key") == key
+    except (OSError, ValueError):
+        return False
+
+
+def _compile(src: str, lib_path: str, extra: list, timeout: int = 600) -> bool:
     """Build ``lib_path`` from ``src`` when stale (single-sourced
     staleness + existence logic for all the on-demand libraries).
     True when a usable library exists afterwards. Stale means the
@@ -148,7 +161,18 @@ def _compile(src: str, lib_path: str, extra: list, timeout: int = 120) -> bool:
     from another machine, or predates a source or flag change, is rebuilt
     here rather than trusted by mtime. Under a sanitizer lane the caller
     passes a ``.san.so``/``.tsan.so`` path (via ``_lib_variant``) and the
-    lane's flags are appended here."""
+    lane's flags are appended here.
+
+    Processes that share a checkout (a test run's workers) build one at a
+    time, under an exclusive lock on ``<lib>.build.lock``, and the one that
+    builds writes a file of its own and renames it onto ``lib_path``: no
+    process, and no canary child, ever loads a library that another
+    process's linker is still writing. (Written in place, a second
+    worker's build truncated the library under the first worker's canary,
+    which refused it as "file too short" and left that worker without the
+    native kernels for the rest of its run.) The one waiting finds the
+    stamp current and builds nothing."""
+    import fcntl
     import json
 
     if not os.path.exists(src):
@@ -160,31 +184,39 @@ def _compile(src: str, lib_path: str, extra: list, timeout: int = 120) -> bool:
         extra = list(extra) + list(_TSAN_FLAGS)
     key = _build_key(src, extra)
     stamp = lib_path + ".build.json"
-    if os.path.exists(lib_path):
-        try:
-            with open(stamp, encoding="utf-8") as f:
-                if json.load(f).get("key") == key:
-                    return True
-        except (OSError, ValueError):
-            pass
-    cmd = ["g++", "-shared", "-fPIC", "-o", lib_path, src] + extra
+    if _built(lib_path, stamp, key):
+        return True
     try:
-        # ``native_load`` chaos site: a scripted fault here exercises the
-        # graceful every-caller-falls-back-to-None contract of the
-        # on-demand native builds (resilience tentpole)
-        from ..resilience import chaos
-
-        chaos.hit("native_load")
-        subprocess.run(cmd, check=True, capture_output=True, timeout=timeout)
-    except Exception:
-        return False
-    tmp = stamp + ".tmp"
-    try:
-        with open(tmp, "w", encoding="utf-8") as f:
-            json.dump({"key": key}, f)
-        os.replace(tmp, stamp)
+        lock = open(lib_path + ".build.lock", "a")
     except OSError:
-        pass  # an unwritable stamp just means rebuilding next process
+        return False
+    with lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if _built(lib_path, stamp, key):
+            return True  # another process built it while this one waited
+        part = f"{lib_path[:-3]}.{os.getpid()}.part.so"
+        cmd = ["g++", "-shared", "-fPIC", "-o", part, src] + extra
+        try:
+            # ``native_load`` chaos site: a scripted fault here exercises
+            # the graceful every-caller-falls-back-to-None contract of the
+            # on-demand native builds (resilience tentpole)
+            from ..resilience import chaos
+
+            chaos.hit("native_load")
+            subprocess.run(cmd, check=True, capture_output=True,
+                           timeout=timeout)
+            os.replace(part, lib_path)
+        except Exception:
+            if os.path.exists(part):
+                os.unlink(part)
+            return False
+        tmp = stamp + ".tmp"
+        try:
+            with open(tmp, "w", encoding="utf-8") as f:
+                json.dump({"key": key}, f)
+            os.replace(tmp, stamp)
+        except OSError:
+            pass  # an unwritable stamp just means rebuilding next process
     return True
 
 

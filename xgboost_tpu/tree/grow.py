@@ -361,30 +361,35 @@ def eval_splits(
     GLd = jnp.stack([GL, GL + g_miss[..., None]], axis=1)  # [K, 2, F, B]
     HLd = jnp.stack([HL, HL + h_miss[..., None]], axis=1)
     Gp, Hp = GL[..., -1:], HL[..., -1:]  # present-value totals
+    # the categorical candidates under their own scope: a profile books
+    # the sort and the prefix scan apart from the numerical evaluation
     if cat_feats is not None:
-        # one-hot: left = all-but-category-b (+ missing when default-left)
-        GLc = jnp.stack([Gp - g_b, Gp - g_b + g_miss[..., None]], axis=1)
-        HLc = jnp.stack([Hp - h_b, Hp - h_b + h_miss[..., None]], axis=1)
-        sel = cat_feats[None, None, :, None]
-        GLd = jnp.where(sel, GLc, GLd)
-        HLd = jnp.where(sel, HLc, HLd)
+        with jax.named_scope("xgb.cat_split"):
+            # one-hot: left = all-but-category-b (+ missing when
+            # default-left)
+            GLc = jnp.stack([Gp - g_b, Gp - g_b + g_miss[..., None]], axis=1)
+            HLc = jnp.stack([Hp - h_b, Hp - h_b + h_miss[..., None]], axis=1)
+            sel = cat_feats[None, None, :, None]
+            GLd = jnp.where(sel, GLc, GLd)
+            HLd = jnp.where(sel, HLc, HLd)
     inv_order = None
     if cat_part is not None:
-        # partition: sort categories by g/(h+lambda); candidate j = first
-        # j+1 sorted categories form the RIGHT side
-        present = (h_b > 0.0) | (g_b != 0.0)
-        ratio = jnp.where(present, g_b / (h_b + p.reg_lambda), jnp.inf)
-        order = jnp.argsort(ratio, axis=-1)  # [K, F, B]
-        inv_order = jnp.argsort(order, axis=-1)  # rank of each bin
-        g_s = jnp.take_along_axis(g_b, order, axis=-1)
-        h_s = jnp.take_along_axis(h_b, order, axis=-1)
-        GRs = jnp.cumsum(g_s, axis=-1)  # right side = sorted prefix
-        HRs = jnp.cumsum(h_s, axis=-1)
-        GLp = jnp.stack([Gp - GRs, Gp - GRs + g_miss[..., None]], axis=1)
-        HLp = jnp.stack([Hp - HRs, Hp - HRs + h_miss[..., None]], axis=1)
-        sel = cat_part[None, None, :, None]
-        GLd = jnp.where(sel, GLp, GLd)
-        HLd = jnp.where(sel, HLp, HLd)
+        with jax.named_scope("xgb.cat_split"):
+            # partition: sort categories by g/(h+lambda); candidate j =
+            # first j+1 sorted categories form the RIGHT side
+            present = (h_b > 0.0) | (g_b != 0.0)
+            ratio = jnp.where(present, g_b / (h_b + p.reg_lambda), jnp.inf)
+            order = jnp.argsort(ratio, axis=-1)  # [K, F, B]
+            inv_order = jnp.argsort(order, axis=-1)  # rank of each bin
+            g_s = jnp.take_along_axis(g_b, order, axis=-1)
+            h_s = jnp.take_along_axis(h_b, order, axis=-1)
+            GRs = jnp.cumsum(g_s, axis=-1)  # right side = sorted prefix
+            HRs = jnp.cumsum(h_s, axis=-1)
+            GLp = jnp.stack([Gp - GRs, Gp - GRs + g_miss[..., None]], axis=1)
+            HLp = jnp.stack([Hp - HRs, Hp - HRs + h_miss[..., None]], axis=1)
+            sel = cat_part[None, None, :, None]
+            GLd = jnp.where(sel, GLp, GLd)
+            HLd = jnp.where(sel, HLp, HLd)
     GRd = Gtot[:, None, None, None] - GLd
     HRd = Htot[:, None, None, None] - HLd
 

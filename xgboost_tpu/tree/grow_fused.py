@@ -744,10 +744,11 @@ def _use_depth_scan(cfg: GrowParams, pallas: bool, max_depth: int) -> bool:
     instead of unrolled per-level bodies — resolved through the dispatch
     registry (``depth_scan``: scanned > unrolled). The scanned driver is
     inapplicable on the pallas path (Mosaic kernels specialize per level
-    width by design), for categorical trees (the widened decision table
-    is level-shaped) and under meshes (the unrolled loop is the proven
-    shard_map path); the legacy ``XGBTPU_DEPTH_SCAN=0`` escape hatch maps
-    to a ``depth_scan=unrolled`` pin."""
+    width by design) and under meshes (the unrolled loop is the proven
+    shard_map path); a categorical tree carries its widened table at the
+    deepest level's width like a numerical one. The legacy
+    ``XGBTPU_DEPTH_SCAN=0`` escape hatch maps to a ``depth_scan=unrolled``
+    pin."""
     from ..dispatch import Ctx, resolve
 
     return resolve("depth_scan", Ctx(

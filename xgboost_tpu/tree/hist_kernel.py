@@ -1149,8 +1149,19 @@ _VMEM_ACC_BUDGET = 6 * 1024 * 1024  # bytes for the [F, 2K, B] accumulator
 _VMEM_HOIST_BUDGET = 12 * 1024 * 1024  # total working set of the hoisted step
 
 
+def _table_vmem_bytes(tr: int, W: int) -> int:
+    """What a categorical decision table (``W = 5 + B`` columns) adds to a
+    level kernel's grid step: the ``[W, tr]`` decisions and the two ``[B,
+    tr]`` values of the set lookup (``_partition_tile``), f32. Zero for
+    the numerical ``[Kp, 4]`` table, whose few rows every plan already
+    had room for."""
+    if W <= 4:
+        return 0
+    return 4 * tr * (_up(W, 8) + 2 * _up(W - 5, 8))
+
+
 def _hoist_vmem_bytes(tr: int, Qh: int, K: int, F: int,
-                      B: Optional[int] = None) -> int:
+                      B: Optional[int] = None, W: int = 4) -> int:
     """Working-set estimate for one grid step of the UNTILED hoisted
     kernel: double-buffered int8 one-hot tile + its bf16 cast + the
     [4K, Qh] dot output + the [2K, F*B] f32 accumulator (full-width in
@@ -1161,31 +1172,36 @@ def _hoist_vmem_bytes(tr: int, Qh: int, K: int, F: int,
     the feature-major ``(Fp, tr)`` block (ISSUE 38) is whole sublanes of
     ``tr`` lanes, under the lane-padded ``(tr, F)`` block it replaced, and
     the VMEM that frees is not spent (every plan stays the parent's).
-    ``B=None`` (legacy 3-arg callers) means full hoist: Qh==F*B."""
+    ``B=None`` (legacy 3-arg callers) means full hoist: Qh==F*B. ``W``:
+    the decision table's columns (``_table_vmem_bytes``)."""
     if B is None:
         B = Qh // F
     Q = F * B
     construct = (tr * B * 2 + 4 * K * B * 4) if Qh < Q else 0
     return (2 * tr * Qh + 2 * tr * Qh + 4 * K * Qh * 4
-            + 2 * K * Q * 4 + tr * F * 4 + construct)
+            + 2 * K * Q * 4 + tr * F * 4 + construct
+            + _table_vmem_bytes(tr, W))
 
 
-def _hoist_tr(Qh: int, K: int, F: int, B: Optional[int] = None) -> int:
+def _hoist_tr(Qh: int, K: int, F: int, B: Optional[int] = None,
+              W: int = 4) -> int:
     """Largest workable row tile for the untiled hoisted kernel at this
     level's node count, or 0 if no tile fits VMEM. Single source of truth
     for both the build-side gate (``hoist_plan``) and the dispatch
     (``level_plan``) so they cannot disagree."""
     for tr in (TR_HOIST, TR_HOIST // 2, TR_HOIST // 4):
-        if _hoist_vmem_bytes(tr, Qh, K, F, B) <= _VMEM_HOIST_BUDGET:
+        if _hoist_vmem_bytes(tr, Qh, K, F, B, W) <= _VMEM_HOIST_BUDGET:
             return tr
     return 0
 
 
-def _construct_fits(F: int, K: int, B: int) -> bool:
+def _construct_fits(F: int, K: int, B: int, W: int = 4) -> bool:
     """Whether the untiled construct-only kernel takes this level: a width
-    its unrolled loop was compiled at, and the whole accumulator in its
-    share of VMEM."""
-    return F <= _MAX_KERNEL_FEATURES and F * 2 * K * B * 4 <= _VMEM_ACC_BUDGET
+    its unrolled loop was compiled at, and the whole accumulator, with a
+    categorical table's values beside it, in its share of VMEM."""
+    return (F <= _MAX_KERNEL_FEATURES
+            and F * 2 * K * B * 4 + _table_vmem_bytes(TR, W)
+            <= _VMEM_ACC_BUDGET)
 
 
 def _up(x: int, m: int) -> int:
@@ -1247,10 +1263,10 @@ def level_plan(rows: int, F: int, K: int, B: int, onehot_width: int = 0,
     kernel takes never reaches the tiles."""
     if _FORCE_TILE is None:
         if onehot_width:
-            tr = _hoist_tr(onehot_width, K, F, B)
+            tr = _hoist_tr(onehot_width, K, F, B, table_width)
             if tr and rows % tr == 0:
                 return LevelPlan("hoisted", tr)
-        if _construct_fits(F, K, B):
+        if _construct_fits(F, K, B, table_width):
             return LevelPlan("construct", TR)
     ft = _FEATURE_TILE if _FORCE_TILE is None else _FORCE_TILE
     Fp = _up(F, ft)
